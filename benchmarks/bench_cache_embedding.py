@@ -1,6 +1,6 @@
 """Host-offloaded embedding cache: vocab beyond the HBM row budget.
 
-The §4.3.1 regime: the fp32 master + fp16 shadow + AdaGrad accum of a
+The §4.3.1 regime: the fp32 master + bf16 shadow + AdaGrad accum of a
 production GR vocabulary do not fit device HBM. ``CachedShadowedTable``
 trains with a device-resident window of hot row-chunks over a host-RAM
 full table; the chunk prefetch runs inside the engine's host ``unique``

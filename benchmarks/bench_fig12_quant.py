@@ -60,7 +60,7 @@ def main():
     results = {}
     bytes_step = {}
     neg_fetch_bytes = {}
-    for name, qdt in (("fp32", None), ("fp16", jnp.float16)):
+    for name, qdt in (("fp32", None), ("bf16", jnp.bfloat16)):
         loader = GRLoader(seqs, num_devices=2, users_per_device=4,
                           max_seq_len=64, num_negatives=16,
                           num_items=n_items, seed=1)
@@ -74,7 +74,7 @@ def main():
             nb = {k2: jnp.asarray(v) for k2, v in batch.items()
                   if k2 != "weights"}
             if compiled is None:
-                # qdt=None → fp32 master gathers; fp16 → persistent shadow
+                # qdt=None → fp32 master gathers; bf16 → persistent shadow
                 state = gr_train_state(b.init_dense(key), b.init_table(key),
                                        qdtype=qdt,
                                        pending_slots=gr_pending_slots(nb))
@@ -84,7 +84,7 @@ def main():
                     cost_dict(compiled).get("bytes accessed", -1.0))
                 # measured fetch traffic of this step's negative gather —
                 # the §4.3.2 quantity — compiled in isolation against the
-                # table the fused path actually reads (fp32 master vs fp16
+                # table the fused path actually reads (fp32 master vs bf16
                 # shadow). The *output*-side bytes of the gather are the
                 # row payload DMA'd per step (T·R·D·esize); the aggregate
                 # 'bytes accessed' would also count the whole resident
@@ -106,19 +106,19 @@ def main():
              f"final_loss={results[name][0]:.4f} HR@100={hr:.4f} "
              f"step_bytes_accessed={bytes_step[name]:.3e} "
              f"neg_fetch_bytes={neg_fetch_bytes[name]:.3e}")
-    dl = abs(results["fp16"][0] - results["fp32"][0]) / results["fp32"][0]
-    dh = abs(results["fp16"][1] - results["fp32"][1])
-    ratio = neg_fetch_bytes["fp32"] / max(neg_fetch_bytes["fp16"], 1.0)
+    dl = abs(results["bf16"][0] - results["fp32"][0]) / results["fp32"][0]
+    dh = abs(results["bf16"][1] - results["fp32"][1])
+    ratio = neg_fetch_bytes["fp32"] / max(neg_fetch_bytes["bf16"], 1.0)
     emit("fig12_quant.delta", 0.0,
          f"loss_delta={100 * dl:.3f}% HR_delta={dh:.4f} "
          f"(paper: <=0.05% HR delta)")
     emit("fig12_quant.bytes", 0.0,
          f"measured neg-fetch payload bytes/step "
          f"fp32={neg_fetch_bytes['fp32']:.3e} "
-         f"shadow={neg_fetch_bytes['fp16']:.3e} "
+         f"shadow={neg_fetch_bytes['bf16']:.3e} "
          f"reduction={ratio:.2f}x (paper Fig. 12: 2x on the negative "
          f"fetch); full-step bytes fp32={bytes_step['fp32']:.3e} "
-         f"shadow={bytes_step['fp16']:.3e}")
+         f"shadow={bytes_step['bf16']:.3e}")
     write_bench_json("fig12_quant", {
         "final_loss": {k: v[0] for k, v in results.items()},
         "hr_at_100": {k: v[1] for k, v in results.items()},

@@ -23,7 +23,8 @@ import json, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core.hsp import make_hsp_lookup
 from repro.launch.hlo_analysis import analyze_text
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 V, d = 65536, 256
 ids_sds = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
 tbl_sds = jax.ShapeDtypeStruct((V, d), jnp.float32)
@@ -46,6 +47,7 @@ print(json.dumps({"global": glob, "hsp": hsp}))
 def main():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"     # fake host devices, never the chip
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(BODY)],
                           env=env, capture_output=True, text=True,

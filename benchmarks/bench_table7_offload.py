@@ -58,13 +58,13 @@ def main():
 
         def segd(tbl):
             lg = NS.neg_logits_segmented(out, tbl, ids, segment=seg,
-                                         fetch_dtype=jnp.float16)
+                                         fetch_dtype=jnp.bfloat16)
             return NS.recall_loss(out, jnp.take(tbl, pos_ids, axis=0), lg)
 
         def fused(tbl):
             return NS.fused_sampled_softmax_loss(
                 out, jnp.take(tbl, pos_ids, axis=0), tbl, ids,
-                segment=seg, fetch_dtype=jnp.float16)
+                segment=seg, fetch_dtype=jnp.bfloat16)
 
         (jb, m_b, _), (js, m_s, _), (jf, m_f, txt_f) = (
             compile_once(f, table) for f in (base, segd, fused))

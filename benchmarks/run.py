@@ -106,6 +106,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_repo_compile_cache
+    use_repo_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for mod in MODULES:

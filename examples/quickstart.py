@@ -5,7 +5,7 @@ KuaiRand-style data, on whatever device this machine has (~1 min on CPU).
 
 Shows the public API end to end: config → synthetic data → Appendix-A
 preprocessing → load-balanced jagged loader → GRBundle loss (fused
-ID-driven negatives: gather + fp16 fetch + logit sharing + Eq.-2 reduce in
+ID-driven negatives: gather + bf16 fetch + logit sharing + Eq.-2 reduce in
 one pass) → the staged execution engine running §4.2.3 Algorithm 1 (host
 dataload/unique overlapped with async-dispatched device stages, τ=1
 semi-async sparse updates).
@@ -45,7 +45,7 @@ def main():
                       strategy="token_realloc")
 
     # 4. the staged engine: §4.3 fused negative path (megakernel on TPU,
-    #    remat'd scan elsewhere) + fp16 fetch + logit sharing, executed as
+    #    remat'd scan elsewhere) + bf16 fetch + logit sharing, executed as
     #    the §4.2.3 six-stage pipeline with §4.2.2 τ=1 semi-async updates
     engine = GREngine(
         bundle, loader,

@@ -4,7 +4,7 @@ the paper's end-to-end workload (Appendix A protocol) at laptop scale.
     PYTHONPATH=src python examples/recall_training_kuairand.py
 
 Trains FuXi (reduced) with the full §4.3 negative-sampling stack and
-evaluates HR@100 on each user's held-out last item, comparing the fp16
+evaluates HR@100 on each user's held-out last item, comparing the bf16
 quantized path against fp32 (Fig. 12's experiment).
 """
 import os
@@ -55,8 +55,8 @@ def main():
     key = jax.random.PRNGKey(0)
 
     for fetch_name, fetch_dtype in (("fp32", jnp.float32),
-                                    ("fp16 (paper §4.3.2)", jnp.float16)):
-        # fp16 arm: persistent shadow table (half-width negative fetches);
+                                    ("bf16 (paper §4.3.2: fp16)", jnp.bfloat16)):
+        # bf16 arm: persistent shadow table (half-width negative fetches);
         # fp32 arm: no shadow, full-precision master gathers
         qdtype = None if fetch_dtype == jnp.float32 else fetch_dtype
         state = gr_train_state(bundle.init_dense(key),
@@ -76,7 +76,7 @@ def main():
         hr = evaluate_hr(state.dense, state.table.master, cfg, seqs, test)
         print(f"{fetch_name:22s} final loss {recs[-1]['loss']:.4f}  "
               f"HR@100 {hr:.4f}")
-    print("fp16 negative fetch tracks fp32 quality (paper Fig. 12)")
+    print("bf16 negative fetch tracks fp32 quality (paper Fig. 12)")
 
 
 if __name__ == "__main__":

@@ -29,16 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map                  # jax ≥ 0.5 top-level API
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, **kw):
-        # the experimental API spells check_vma as check_rep
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _legacy_shard_map(f, **kw)
-
 
 # --------------------------------------------------------------------------
 # fixed-capacity unique + accumulate (the pipeline's "unique" stage)
@@ -139,7 +129,7 @@ def make_hsp_lookup(mesh: Mesh, *, group_axes: Tuple[str, ...] = ("model",),
                 scatter_dimension=0, tiled=True)
             return emb
 
-        return shard_map(fwd_local, mesh=mesh,
+        return jax.shard_map(fwd_local, mesh=mesh,
                          in_specs=(table_spec, ids_spec),
                          out_specs=emb_spec, check_vma=False)(table, ids)
 
@@ -209,7 +199,7 @@ def make_hsp_lookup(mesh: Mesh, *, group_axes: Tuple[str, ...] = ("model",),
                     ).astype(jnp.float32)
                 return dtbl.astype(tdtype)
 
-            dtable = shard_map(bwd_local, mesh=mesh,
+            dtable = jax.shard_map(bwd_local, mesh=mesh,
                                in_specs=(ids_spec, emb_spec),
                                out_specs=table_spec, check_vma=False)(ids, g)
             return dtable, None

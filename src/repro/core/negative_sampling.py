@@ -10,7 +10,8 @@ the paper's example sizes) — §4.3 removes it three ways:
     segment fetches (``repro.kernels.neg_logits``); the ``jax.lax.scan``
     here is the XLA-path equivalent whose peak-memory drop shows directly
     in ``compiled.memory_analysis()``.
-  * quantized lookups — §4.3.2: negatives fetched fp16/bf16 (tables.py).
+  * quantized lookups — §4.3.2: negatives fetched in half precision
+    (bf16, ``tables.SHADOW_DTYPE``).
   * :func:`share_logits` — §4.3.3: intra-batch logit sharing with a
     token-level shuffle expands the effective negative set k× without any
     additional embedding lookups (Eq. 2's Δ term).
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.embedding import tables as ET
 from repro.kernels.neg_logits import fused_recall_lse
 from repro.kernels.neg_logits.fused import NEG_POOL
 from repro.kernels.neg_logits.ops import prepare_fused_inputs
@@ -69,7 +71,7 @@ def neg_logits_baseline(out_emb: jax.Array, neg_emb: jax.Array,
 def neg_logits_segmented(out_emb: jax.Array, table: jax.Array,
                          neg_ids: jax.Array, *, segment: int = 128,
                          tau: float = 1.0,
-                         fetch_dtype=jnp.float16) -> jax.Array:
+                         fetch_dtype=ET.SHADOW_DTYPE) -> jax.Array:
     """§4.3.1 'CPU offloading + segmented fetching', XLA form.
 
     The negatives live as *ids* (T, R); embeddings are fetched from
@@ -286,7 +288,7 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
                                tau: float = 1.0,
                                valid: Optional[jax.Array] = None,
                                segment: int = 128, expansion: int = 1,
-                               fetch_dtype=jnp.float16,
+                               fetch_dtype=ET.SHADOW_DTYPE,
                                shadow: Optional[jax.Array] = None,
                                impl: Optional[str] = None,
                                rows_per_step: Optional[int] = None,

@@ -1,6 +1,6 @@
 """Host-offloaded, frequency-aware embedding cache (§4.3.1 regime).
 
-TurboGR's sparse side assumes the fp32 master + fp16 shadow fit in device
+TurboGR's sparse side assumes the fp32 master + bf16 shadow fit in device
 HBM; production GR vocabularies (hundreds of millions of users/items) do
 not. :class:`CachedShadowedTable` breaks that ceiling: the full table
 lives in host RAM and the device holds only a *window* of hot row-chunks
@@ -116,7 +116,7 @@ class CachedShadowedTable:
     """
 
     def __init__(self, master, *, capacity_chunks: int,
-                 chunk_rows: int = 1024, qdtype=jnp.float16,
+                 chunk_rows: int = 1024, qdtype=ET.SHADOW_DTYPE,
                  accum=None):
         m = np.asarray(jax.device_get(master), np.float32)
         if m.ndim != 2:
@@ -252,28 +252,21 @@ class CachedShadowedTable:
         """Slot-space τ=1 pending pairs → the exact global-space layout
         an uncached run produces.
 
-        The pending arrays follow the candidate sort: unique ids at run
-        starts, −1 / zero-rows at the duplicate positions. Translation is
-        order-preserving only *within* a chunk, so the slot-space sort
-        block-permutes the runs relative to the global-id sort; this
-        globalizes the run-start ids and re-lays the runs out in
-        global-id order (run lengths are recovered from the sentinel
-        positions), so a cached checkpoint is bitwise identical to the
-        uncached one — not merely equivalent up to permutation."""
+        The pending arrays hold the unique ids in ascending order, then
+        −1 / zero-rows. Translation is order-preserving only *within* a
+        chunk, so the slot-space order block-permutes the ids relative to
+        the global-id order; this globalizes the ids and re-sorts them, so
+        a cached checkpoint is bitwise identical to the uncached one — not
+        merely equivalent up to permutation."""
         p = np.asarray(slot_ids, np.int64).reshape(-1)
         r = np.asarray(rows)
-        starts = np.flatnonzero(p >= 0)
-        if starts.size == 0:
-            return (np.full(p.shape, -1, np.int32),
-                    np.zeros_like(r))
-        lengths = np.diff(np.append(starts, p.size))
-        gids = self.globalize_pending(p[starts])
+        live = np.flatnonzero(p >= 0)
+        gids = self.globalize_pending(p[live])
         order = np.argsort(gids, kind="stable")
         out_ids = np.full(p.shape, -1, np.int32)
         out_rows = np.zeros_like(r)
-        pos = np.concatenate([[0], np.cumsum(lengths[order])[:-1]])
-        out_ids[pos] = gids[order]
-        out_rows[pos] = r[starts][order]
+        out_ids[:live.size] = gids[order]
+        out_rows[:live.size] = r[live][order]
         return out_ids, out_rows
 
     def globalize_pending(self, slot_ids) -> np.ndarray:

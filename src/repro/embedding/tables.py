@@ -1,9 +1,12 @@
 """Sparse embedding tables — the GR system's sparse substrate.
 
 The master table is fp32 (AdaGrad-friendly); lookups return the compute
-dtype. ``lookup_quantized`` is the paper's §4.3.2 FP16 path: rows are
-*stored/fetched* in half precision for negative samples while the rest of
-the pipeline is unchanged.
+dtype. ``lookup_quantized`` is the paper's §4.3.2 half-precision path:
+rows are *stored/fetched* in half precision for negative samples while
+the rest of the pipeline is unchanged. The half type is
+:data:`SHADOW_DTYPE` (bfloat16) on every backend: the paper uses fp16, but
+bf16 is the TPU's native half type and a v5e kernel cannot load an fp16
+tile at all.
 
 Multi-table (KJT-style) batches: a dict of feature name → jagged ids; the
 table-major reorganization of §4.1.2 (group all data per table, then spread
@@ -20,6 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.jagged import JaggedBatch
+
+# the one half-width type of the shadow table and of the negative fetch
+SHADOW_DTYPE = jnp.bfloat16
 
 
 @dataclass(frozen=True)
@@ -43,9 +49,9 @@ def lookup(table: jax.Array, ids: jax.Array,
 
 
 def lookup_quantized(table: jax.Array, ids: jax.Array,
-                     qdtype=jnp.float16) -> jax.Array:
-    """§4.3.2: fetch rows in half precision (fp16 paper-faithful; bf16 is
-    the TPU-native variant). Quantization happens at the *fetch* — only
+                     qdtype=SHADOW_DTYPE) -> jax.Array:
+    """§4.3.2: fetch rows in half precision (the paper's fp16 becomes the
+    TPU-native bf16 by default). Quantization happens at the *fetch* — only
     the gathered rows are cast (casting ``table`` first would copy the
     whole (V, D) array per call), so the live negative tensor is half the
     bytes. The fused TPU hot path (``repro.kernels.neg_logits``) applies
@@ -75,11 +81,11 @@ class ShadowedTable(NamedTuple):
     :func:`strip_shadow` / :func:`rebuild_shadow`.
     """
     master: jax.Array               # (V, D) fp32
-    shadow: Optional[jax.Array]     # (V, D) fp16/bf16, or None
+    shadow: Optional[jax.Array]     # (V, D) bf16 (or fp16), or None
     accum: jax.Array                # (V, D) fp32 AdaGrad S (paper Eq. 1)
 
 
-def make_shadowed(master: jax.Array, qdtype=jnp.float16,
+def make_shadowed(master: jax.Array, qdtype=SHADOW_DTYPE,
                   accum: Optional[jax.Array] = None) -> ShadowedTable:
     """Build a ShadowedTable from an fp32 master. ``qdtype=None`` → no
     shadow (fp32-round emulation path)."""

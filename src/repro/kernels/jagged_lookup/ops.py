@@ -17,6 +17,9 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
+from repro.core.sharding import current_ctx
 from repro.kernels import autotune
 from repro.kernels.jagged_lookup import kernel as K
 
@@ -43,6 +46,18 @@ def _segment_totals(srows: jax.Array, sids: jax.Array) -> jax.Array:
     return totals[run]
 
 
+def _replicated(kernel):
+    """A Pallas kernel cannot be partitioned by the compiler: under a
+    multi-device mesh context (``core.sharding.shard_ctx``) it runs whole
+    on every device over replicated operands (a run of equal sorted ids
+    must not be cut at a shard boundary)."""
+    ctx = current_ctx()
+    if ctx is None or ctx.mesh.size == 1:
+        return kernel
+    return jax.shard_map(kernel, mesh=ctx.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
 def dedup_rows(grad_rows: jax.Array, ids: jax.Array, *,
                interpret: Optional[bool] = None):
     """Sorted-runsum deduplication of (id, row) pairs.
@@ -64,7 +79,7 @@ def dedup_rows(grad_rows: jax.Array, ids: jax.Array, *,
     if interpret:
         sums = _segment_totals(srows, sids)
     else:
-        sums = K.runsum_pallas(srows, sids, interpret=False)
+        sums = _replicated(K.runsum_pallas)(srows, sids)
     is_end = jnp.concatenate([sids[:-1] != sids[1:],
                               jnp.ones((1,), bool)])
     uids = jnp.where(is_end & (sids < _DROP_KEY), sids, -1)
@@ -149,11 +164,7 @@ def scatter_add_weighted_rows(weights: jax.Array, o: jax.Array,
           * valid[order].astype(jnp.float32))
     out = K.weighted_runsum_scatter(o.astype(jnp.float32), ws, sids, src,
                                     vocab, scale=scale, interpret=False)
-    # unvisited destination rows hold unspecified memory — mask by the
-    # touched-row set instead of pre-zeroing the whole (V, D) buffer
-    touched = jnp.zeros((vocab,), bool).at[
-        jnp.where(valid, ids, vocab)].set(True, mode="drop")
-    return jnp.where(touched[:, None], out[:vocab], 0.0)
+    return out[:vocab]
 
 
 def jagged_lookup(table: jax.Array, ids: jax.Array, *,

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # the 512 devices are fake host ones
 
 """Multi-pod dry-run: .lower().compile() every (arch × shape × mesh) cell.
 
@@ -9,9 +10,10 @@ ShapeDtypeStruct inputs (no allocation), compiles, and records
 ``memory_analysis()`` / ``cost_analysis()`` + the roofline terms parsed
 from the partitioned HLO.
 
-The two XLA_FLAGS lines above MUST stay the first statements — jax locks
-the device count at first init, and the 512 placeholder host devices are
-what lets ``make_production_mesh`` build the 16×16 / 2×16×16 grids.
+The environment lines above MUST stay the first statements — jax locks
+the platform and device count at first init, and the 512 placeholder
+host devices are what lets ``make_production_mesh`` build the 16×16 /
+2×16×16 grids.
 
 Usage:
     python -m repro.launch.dryrun --arch glm4-9b --shape train_4k --mesh single
@@ -84,7 +86,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool):
         # presize the τ=1 pending pair buffers from the batch spec: with
         # the default 0 slots the sparse-update stage would be statically
         # compiled out and the cost/memory analysis would miss it
-        n_pend = gr_pending_slots(inputs["batch"])
+        n_pend = gr_pending_slots(inputs["batch"], cfg.vocab_size)
         state_sds = jax.eval_shape(
             lambda: gr_train_state(bundle.init_dense(key),
                                    bundle.init_table(key),

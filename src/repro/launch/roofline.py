@@ -3,7 +3,7 @@
 Per (arch × shape × mesh) we derive, from the *per-device* SPMD module:
 
     compute term    = HLO_FLOPs / peak_FLOP/s          (197 TF/s bf16, v5e)
-    memory term     = HLO_bytes / HBM_bw               (819 GB/s)
+    memory term     = HLO_bytes / HBM_bw               (819 GB/s, v5e)
     collective term = collective_bytes / link_bw       (~50 GB/s/link ICI)
 
 HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()`` (already
@@ -22,14 +22,29 @@ from __future__ import annotations
 
 import re
 from dataclasses import asdict, dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict, Mapping, Optional, Tuple
 
 from repro.configs.base import (ArchConfig, count_active_params, count_params)
 from repro.configs.shapes import ShapeConfig
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
+# A device kind that is not here has no peak: nothing is divided by a
+# guess (a CPU run records no MFU).
+DEVICE_PEAKS: Mapping[str, Mapping[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9},
+}
+
+
+def device_peak(device_kind: str) -> Optional[Mapping[str, float]]:
+    """The peak-rate entry for a device kind, or None when not in the
+    table."""
+    return DEVICE_PEAKS.get(device_kind)
+
+
+# the dry-run roofline targets a v5e pod (launch/dryrun.py)
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["flops"]     # bf16
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bw"]        # bytes/s
 LINK_BW = 50e9               # bytes/s per ICI link
 
 _DTYPE_BYTES = {
@@ -67,22 +82,8 @@ def _type_bytes(type_str: str) -> int:
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jaxlib versions.
-
-    Newer jaxlibs return one flat dict; older ones return a list with one
-    dict per executable program (``dict(...)`` on that list crashes with
-    "dictionary update sequence element #0 has length N"). Merge the
-    per-program dicts (later programs win; there is one in practice).
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        out: Dict[str, float] = {}
-        for d in ca:
-            out.update(dict(d))
-        return out
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a dict (empty when unavailable)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, int]:

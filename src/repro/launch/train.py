@@ -13,8 +13,12 @@ CPU example (a ~100M-dense-param model, a few hundred steps):
         --steps 200 --users-per-device 2 --max-seq-len 512 \
         --num-items 200000 --synthetic-users 2000
 
-On a TPU pod slice the same entrypoint shards over the production mesh
-(--mesh-model N) and switches the attention backend to the Pallas kernel.
+On a TPU the same command runs the Pallas kernels (attention, fused
+negatives, scatter) in place of the XLA paths. The engine drives one
+device: on a host with several, the loader is sized for that one and the
+rest stay idle (the multi-chip HSP step is ``launch/dryrun.py``'s and
+``chip_smoke.py --chips 4``'s). JAX's compilation cache lives in
+``$JAX_COMPILATION_CACHE_DIR`` or else ``<repo>/.jax_cache``.
 """
 from __future__ import annotations
 
@@ -28,6 +32,7 @@ from repro.configs import get_arch
 from repro.data.kuairand import preprocess_log
 from repro.data.loader import GRLoader
 from repro.data.synthetic import SyntheticKuaiRand
+from repro.launch.compile_cache import use_repo_compile_cache
 from repro.models.model_zoo import GRBundle
 from repro.training import checkpoint as CKPT
 from repro.training.engine import GREngine
@@ -86,6 +91,7 @@ def main():
                     help="print measured MFU / token imbalance / step "
                          "wall time every N steps (0 = off; implies obs)")
     args = ap.parse_args()
+    use_repo_compile_cache()
 
     cfg = get_arch(args.arch)
     if not cfg.gr:
@@ -106,7 +112,12 @@ def main():
     print(f"[data] {len(train_seqs)} users, {n_items} items after 5-core "
           f"filter + leave-one-out")
 
-    ndev = jax.device_count()
+    # GREngine jits without shardings: it runs on one device, so the
+    # loader packs one device's batch
+    ndev = 1
+    if jax.device_count() > ndev:
+        print(f"[devices] {jax.device_count()} visible; the engine uses "
+              f"{ndev} ({jax.devices()[0].device_kind})")
     loader = GRLoader(train_seqs, num_devices=ndev,
                       users_per_device=args.users_per_device,
                       max_seq_len=args.max_seq_len,
@@ -147,8 +158,9 @@ def main():
                   f"{(i+1)/dt:.2f} steps/s", flush=True)
         if args.metrics_every and (i + 1) % args.metrics_every == 0:
             # per-step derived gauges ride the record when obs is live
+            mfu = rec.get("mfu")
             print(f"[obs] step {i+1:5d}  "
-                  f"mfu {100*rec.get('mfu', 0):.2f}%  "
+                  f"mfu {'not measured' if mfu is None else f'{100*mfu:.2f}%'}  "
                   f"imbalance {100*rec.get('imbalance', 0):.2f}%  "
                   f"step_wall {rec.get('step_wall_s', 0)*1e3:.1f}ms",
                   flush=True)

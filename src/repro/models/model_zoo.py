@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.configs.shapes import ShapeConfig
 from repro.core import negative_sampling as NS
+from repro.embedding import tables as ET
 from repro.models import gr as GR
 from repro.models import transformer as TF
 
@@ -134,7 +135,7 @@ class GRBundle:
     def loss(self, dense_params: Params, table: jax.Array, batch: Batch, *,
              lookup_fn: Optional[Callable] = None,
              neg_mode: str = "fused", expansion: int = 1,
-             neg_segment: int = 128, fetch_dtype=jnp.float16,
+             neg_segment: int = 128, fetch_dtype=ET.SHADOW_DTYPE,
              neg_impl: Optional[str] = None,
              neg_rows_per_step: Optional[int] = None,
              neg_scatter_impl: Optional[str] = None, attn_fn=None,

@@ -4,8 +4,9 @@ These close the loop between the static roofline estimates in
 ``launch/roofline.py`` and what a run actually did:
 
 - ``measured_mfu`` — model FLOPs per step over *measured* step wall
-  time against peak, reported next to the static roofline estimate
-  (paper's 54.71% MFU axis).
+  time against the device's peak from ``launch/roofline.DEVICE_PEAKS``
+  (paper's 54.71% MFU axis); None ("not measured") for a device kind
+  with no published peak.
 - ``token_imbalance`` — makespan-relative imbalance of per-device
   token loads (paper's 47% -> 2.4% axis), delegating to
   ``core/load_balance.imbalance_ratio``.
@@ -18,24 +19,27 @@ never divide-by-zero.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Mapping, Sequence, Tuple
+from typing import Any, Dict, Iterable, Mapping, Optional, Sequence, Tuple
 
 from repro.core import load_balance as LB
 from repro.core.pipeline import StageEvent
-from repro.launch.roofline import PEAK_FLOPS
 from repro.obs.trace import busy_from_intervals
 
 __all__ = ["measured_mfu", "token_imbalance", "pipeline_goodput"]
 
 
 def measured_mfu(model_flops: float, wall_s: float,
-                 peak_flops: float = PEAK_FLOPS) -> float:
+                 peak_flops: Optional[float]) -> Optional[float]:
     """Measured model-FLOPs utilization for one step.
 
     ``model_flops`` comes from ``roofline.model_flops_per_step`` (or
     ``6 * n_dense_params * tokens`` for GR); ``wall_s`` is the measured
-    step wall time.  Returns 0.0 when either is non-positive.
+    step wall time; ``peak_flops`` the device's entry in
+    ``roofline.DEVICE_PEAKS``. Returns None without a peak (not
+    measured) and 0.0 when a count is non-positive.
     """
+    if peak_flops is None:
+        return None
     if wall_s <= 0.0 or model_flops <= 0.0 or peak_flops <= 0.0:
         return 0.0
     return float(model_flops) / (float(wall_s) * float(peak_flops))

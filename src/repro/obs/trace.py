@@ -161,7 +161,7 @@ class Tracer:
             rec = records.get(ev.batch) if records else None
             if rec is not None:
                 for k in ("tokens", "loss", "step_wall_s", "mfu", "imbalance"):
-                    if k in rec:
+                    if rec.get(k) is not None:
                         args[k] = rec[k]
                 cache = rec.get("cache")
                 if isinstance(cache, Mapping) and "hit_rate" in cache:

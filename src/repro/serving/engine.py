@@ -101,11 +101,11 @@ class RecallEngine:
         else:
             # serving-only construction from a raw master: no (V, D) fp32
             # AdaGrad accumulator (only the training optimizer reads it),
-            # and the fp16 shadow only if retrieval will scan it — dead
+            # and the bf16 shadow only if retrieval will scan it — dead
             # state at production vocab sizes otherwise
             self.table = ET.ShadowedTable(
                 master=table,
-                shadow=table.astype(jnp.float16) if use_shadow else None,
+                shadow=table.astype(ET.SHADOW_DTYPE) if use_shadow else None,
                 accum=jnp.zeros((0, table.shape[-1]), jnp.float32))
         self.k = k
         self.num_shards = num_shards
@@ -374,7 +374,7 @@ class StreamingRecallEngine:
         else:
             self.table = ET.ShadowedTable(
                 master=table,
-                shadow=table.astype(jnp.float16) if use_shadow else None,
+                shadow=table.astype(ET.SHADOW_DTYPE) if use_shadow else None,
                 accum=jnp.zeros((0, table.shape[-1]), jnp.float32))
         self.k = k
         self.admission = admission

@@ -50,7 +50,8 @@ from repro.core.pipeline import (PipelineHooks, STAGES, SixStagePipeline,
                                  StageEvent,
                                  timeline_report as _timeline_report)
 from repro.embedding import cache as EC
-from repro.launch.roofline import gr_dense_params
+from repro.embedding import tables as ET
+from repro.launch.roofline import device_peak, gr_dense_params
 from repro.obs import Obs
 from repro.obs.derived import measured_mfu, pipeline_goodput, token_imbalance
 from repro.training import resilience as R
@@ -145,7 +146,7 @@ class GREngine:
                  seed: int = 0, loss_kwargs: Optional[Dict[str, Any]] = None,
                  lr_dense: float = 4e-3, lr_sparse: float = 4e-3,
                  semi_async: bool = True, schedule: str = "algorithm1",
-                 qdtype=jnp.float16, workers: int = 3,
+                 qdtype=ET.SHADOW_DTYPE, workers: int = 3,
                  cache: Optional[EC.CachedShadowedTable] = None,
                  step_callback: Optional[Callable] = None,
                  fault_policy: Optional[R.FaultPolicy] = None,
@@ -185,9 +186,12 @@ class GREngine:
         live = obs is not None and obs.enabled
         self._mx = obs.metrics if live else None
         self._tr = obs.tracer if live else None
-        # measured MFU: model FLOPs for GR = 6 * dense params * tokens
+        # measured MFU: model FLOPs for GR = 6 * dense params * tokens,
+        # against the device's published peak (none on a kind without one)
         self._obs_flops_per_token = (
             6.0 * gr_dense_params(bundle.cfg) if live else 0.0)
+        peak = device_peak(jax.devices()[0].device_kind)
+        self._peak_flops = peak["flops"] if peak else None
         self._last_step_end: Optional[float] = None
         self._run_t0 = 0.0
 
@@ -202,7 +206,7 @@ class GREngine:
         self._j_emb_fwd = jax.jit(stages.emb_fwd)
         self._j_dense = jax.jit(stages.dense_fwd_bwd)
         self._j_emb_bwd = jax.jit(stages.emb_bwd,
-                                  static_argnames=("apply_sparse",))
+                                  static_argnames=("apply_sparse", "slots"))
         self._j_sparse_apply = jax.jit(stages.sparse_apply)
         self._dlock = threading.Lock()
 
@@ -235,7 +239,8 @@ class GREngine:
                      else self.bundle.init_table(key))
             self.state = gr_train_state(
                 self.bundle.init_dense(key), table,
-                qdtype=self.qdtype, pending_slots=gr_pending_slots(first))
+                qdtype=self.qdtype, pending_slots=gr_pending_slots(first),
+                vocab=self.cache.vocab if self.cache is not None else None)
         if self.cache is not None:
             # the run's starting table is the latest landed window — the
             # reference dirty-chunk writebacks read from
@@ -425,7 +430,8 @@ class GREngine:
                 # the pairs land at the next step's (or run's) landing
                 dense, opt, _, p_ids, p_rows = self._j_emb_bwd(
                     st.dense, st.dense_opt, st.table, full["dout"],
-                    full["dev"], cand_s, cand_f, apply_sparse=False)
+                    full["dev"], cand_s, cand_f, apply_sparse=False,
+                    slots=st.pending_ids.shape[0] or None)
                 self.state = snapshot = GRTrainState(
                     dense, opt, st.table, p_ids, p_rows, st.step + 1)
                 if self.cache is not None:
@@ -438,7 +444,8 @@ class GREngine:
                 # pre-landing st.table reference still backs the snapshot
                 dense, opt, table, p_ids, p_rows = self._j_emb_bwd(
                     st.dense, st.dense_opt, st.table, full["dout"],
-                    full["dev"], cand_s, cand_f, apply_sparse=True)
+                    full["dev"], cand_s, cand_f, apply_sparse=True,
+                    slots=st.pending_ids.shape[0] or None)
                 snapshot = GRTrainState(dense, opt, st.table, p_ids,
                                         p_rows, st.step + 1)
                 self.state = GRTrainState(
@@ -450,7 +457,8 @@ class GREngine:
         else:
             dense, opt, table, p_ids, p_rows = self._j_emb_bwd(
                 st.dense, st.dense_opt, st.table, full["dout"],
-                full["dev"], cand_s, cand_f, apply_sparse=True)
+                full["dev"], cand_s, cand_f, apply_sparse=True,
+                slots=st.pending_ids.shape[0] or None)
             self.state = snapshot = GRTrainState(
                 dense, opt, table, jnp.full_like(p_ids, -1),
                 jnp.zeros_like(p_rows), st.step + 1)
@@ -476,7 +484,8 @@ class GREngine:
     def _obs_step(self, i: int, rec: Dict[str, Any],
                   full: Dict[str, Any]) -> None:
         """Per-step derived gauges: measured step wall time, measured MFU
-        (vs the static roofline estimate in launch/roofline.py), and the
+        (against the device's published peak; None and no gauge on a
+        device kind without one), and the
         per-device token-load imbalance — the paper's 54.71%-MFU and
         47%→2.4%-imbalance axes, live per step. The derived values also
         ride the record so callers see them without a registry read."""
@@ -488,7 +497,7 @@ class GREngine:
         loads = np.asarray(full["np"]["offsets"])[:, -1]
         rec["step_wall_s"] = wall
         rec["mfu"] = measured_mfu(self._obs_flops_per_token * rec["tokens"],
-                                  wall)
+                                  wall, self._peak_flops)
         rec["imbalance"] = token_imbalance(loads)
         mx = self._mx
         mx.counter("train_steps_total", "training steps completed").inc()
@@ -497,8 +506,9 @@ class GREngine:
             self._resume_base + i)
         mx.gauge("train_loss", "last step loss").set(rec["loss"])
         mx.gauge("train_step_wall_s", "last step wall time").set(wall)
-        mx.gauge("train_mfu_measured",
-                 "measured model-FLOPs utilization").set(rec["mfu"])
+        if rec["mfu"] is not None:
+            mx.gauge("train_mfu_measured",
+                     "measured model-FLOPs utilization").set(rec["mfu"])
         mx.gauge("train_token_imbalance",
                  "per-device token-load imbalance").set(rec["imbalance"])
         if wall > 0.0:

@@ -117,20 +117,25 @@ def make_lm_train_step(loss_fn: Callable[[Params, Batch], jax.Array], *,
 class GRTrainState(NamedTuple):
     dense: Params
     dense_opt: O.AdamWState
-    table: ET.ShadowedTable         # fp32 master + fp16 shadow + AdaGrad S
+    table: ET.ShadowedTable         # fp32 master + bf16 shadow + AdaGrad S
     pending_ids: jax.Array          # (N,) int32, −1 = empty (τ=1, §4.2.2)
     pending_rows: jax.Array         # (N, D) fp32 delayed sparse grad rows
     step: jax.Array
 
 
 def gr_train_state(dense: Params, table: jax.Array,
-                   opt_dtype=jnp.float32, *, qdtype=jnp.float16,
-                   pending_slots: int = 0) -> GRTrainState:
+                   opt_dtype=jnp.float32, *, qdtype=ET.SHADOW_DTYPE,
+                   pending_slots: int = 0,
+                   vocab: Optional[int] = None) -> GRTrainState:
     """``table`` is the fp32 master; a ``qdtype`` shadow (None = disabled)
     is derived from it. ``pending_slots`` presizes the τ=1 delayed-grad
     pair buffers — 0 lets the first train step size them from the batch
-    (one extra jit compile in a steady-shape loop)."""
+    (one extra jit compile in a steady-shape loop). The pairs carry unique
+    ids, so the buffers never hold more slots than the id space has rows:
+    ``vocab``, or the table's row count (a cache window passes its full
+    vocab, so cached and uncached states keep one shape)."""
     tbl = table.master if isinstance(table, ET.ShadowedTable) else table
+    pending_slots = min(pending_slots, vocab or tbl.shape[0])
     st = (table if isinstance(table, ET.ShadowedTable)
           else ET.make_shadowed(tbl, qdtype=qdtype))
     return GRTrainState(
@@ -141,13 +146,14 @@ def gr_train_state(dense: Params, table: jax.Array,
         step=jnp.zeros((), jnp.int32))
 
 
-def gr_pending_slots(batch: Batch) -> int:
+def gr_pending_slots(batch: Batch, vocab: Optional[int] = None) -> int:
     """Static size of the τ=1 pending (id, row) pair buffers for a batch:
-    one candidate per table read (input ids + labels + negatives). Pass to
+    one candidate per table read (input ids + labels + negatives), capped
+    at ``vocab`` rows when given (the pairs carry unique ids). Pass to
     :func:`gr_train_state` to presize the state (required for AOT-compiled
     steps, avoids one recompile for jitted loops)."""
-    return int(batch["ids"].size + batch["labels"].size
-               + batch["neg_ids"].size)
+    n = int(batch["ids"].size + batch["labels"].size + batch["neg_ids"].size)
+    return n if vocab is None else min(n, int(vocab))
 
 
 def host_unique_candidates(batch, vocab: int):
@@ -185,19 +191,27 @@ def host_unique_candidates(batch, vocab: int):
 
 def _table_grad_pairs(gt: jax.Array, batch: Batch, vocab: int,
                       cand_sorted: Optional[jax.Array] = None,
-                      cand_first: Optional[jax.Array] = None):
+                      cand_first: Optional[jax.Array] = None,
+                      slots: Optional[int] = None):
     """Dense table grad → deduplicated sparse (id, grad-row) pairs.
 
     Every table read happens at the batch's candidate ids (input ids,
     labels, negative ids), so those rows cover the grad's support exactly.
     Duplicates are collapsed by a first-occurrence mask over the sorted
-    candidate list (−1 sentinels elsewhere), giving unique ids whose
-    gathered rows are the already-aggregated per-row gradients.
+    candidate list, giving unique ids whose gathered rows are the
+    already-aggregated per-row gradients. The unique ids fill the first
+    ``slots`` entries in ascending order (default: one per candidate),
+    −1 / zero rows after them.
 
     ``cand_sorted``/``cand_first`` accept the host "unique" stage's
     precomputed sort (:func:`host_unique_candidates`) so the pipeline can
     overlap the candidate dedup with device compute; when absent the sort
     runs in-graph (the flat fused step).
+
+    A batch reads far more candidates than the table has rows (T·R
+    negatives alone), so the state sizes ``slots`` to at most the table's
+    rows (:func:`gr_train_state`): the pair buffers then cost (V, D)
+    rather than (T·(R+2), D).
     """
     if cand_sorted is None:
         cand = jnp.concatenate([
@@ -208,8 +222,11 @@ def _table_grad_pairs(gt: jax.Array, batch: Batch, vocab: int,
         first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
     else:
         s, first = cand_sorted, cand_first
-    uids = jnp.where(first, s, -1)
-    rows = gt[jnp.where(first, s, 0)] * first[:, None]
+    n = s.shape[0]
+    (at,) = jnp.nonzero(first, size=slots or n, fill_value=n)
+    hit = at < n
+    uids = jnp.where(hit, s[jnp.minimum(at, n - 1)], -1)
+    rows = gt[jnp.maximum(uids, 0)] * hit[:, None]
     return uids, rows.astype(jnp.float32)
 
 
@@ -244,10 +261,11 @@ class GRStages(NamedTuple):
         dense params, the fresh master (labels/negatives) and the
         prefetched input rows.
     emb_bwd(dense, dense_opt, table, dout, batch, cand_sorted, cand_first,
-            *, apply_sparse) -> (dense', opt', table', p_ids, p_rows)
+            *, apply_sparse, slots) -> (dense', opt', table', p_ids, p_rows)
         _table_grad_pairs + AdamW + (optionally deferred) row-sparse
         Eq.-1 AdaGrad. ``apply_sparse=False`` returns the pairs as the
-        τ=1 pending cross-batch artifact instead of applying them.
+        τ=1 pending cross-batch artifact instead of applying them;
+        ``slots`` is the pending buffers' length.
     sparse_apply(table, p_ids, p_rows) -> table'
         The deferred landing of pending pairs (Algorithm 1 line 3).
     """
@@ -315,7 +333,7 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
     def emb_bwd(dense, dense_opt, table: ET.ShadowedTable,
                 dout: GRDenseOut, batch,
                 cand_sorted=None, cand_first=None, *,
-                apply_sparse: bool = True):
+                apply_sparse: bool = True, slots: Optional[int] = None):
         vocab = table.master.shape[0]
         if semi_async:
             if dout.grad_x is not None:
@@ -327,11 +345,16 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
                     lambda t: input_gather(t, batch), tsd)(dout.grad_x)[0]
             else:
                 g_stale = dout.grad_stale
-            gt = (g_stale + dout.grad_table).astype(jnp.float32)
+            # the barrier pins the summation order: fused into one jit
+            # with the scatter that built g_stale, XLA may fold the add
+            # into it and round differently than the staged engine
+            g_stale, g_fresh = jax.lax.optimization_barrier(
+                (g_stale, dout.grad_table))
+            gt = (g_stale + g_fresh).astype(jnp.float32)
         else:
             gt = dout.grad_table.astype(jnp.float32)
         p_ids, p_rows = _table_grad_pairs(gt, batch, vocab,
-                                          cand_sorted, cand_first)
+                                          cand_sorted, cand_first, slots)
         new_dense, new_opt = O.adamw_update(
             dout.grads_dense, dense_opt, dense, lr=lr_dense,
             weight_decay=0.0)
@@ -377,6 +400,7 @@ def make_gr_train_step(loss_fn: Callable[..., jax.Array], *,
 
     def train_step(state: GRTrainState, batch: Batch):
         tbl = state.table
+        slots = state.pending_ids.shape[0] or None
 
         if semi_async:
             # emb_fwd for this batch reads the stale master (the pipeline
@@ -390,12 +414,13 @@ def make_gr_train_step(loss_fn: Callable[..., jax.Array], *,
             dout = st.dense_fwd_bwd(state.dense, fresh, batch, x, stale)
             new_dense, new_opt, new_table, p_ids, p_rows = st.emb_bwd(
                 state.dense, state.dense_opt, fresh, dout, batch,
-                apply_sparse=False)   # pairs become the next step's carry
+                apply_sparse=False,   # pairs become the next step's carry
+                slots=slots)
         else:
             dout = st.dense_fwd_bwd(state.dense, tbl, batch)
             new_dense, new_opt, new_table, uids, rows = st.emb_bwd(
                 state.dense, state.dense_opt, tbl, dout, batch,
-                apply_sparse=True)
+                apply_sparse=True, slots=slots)
             p_ids = jnp.full_like(uids, -1)
             p_rows = jnp.zeros_like(rows)
 

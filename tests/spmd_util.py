@@ -1,4 +1,4 @@
-"""Run an SPMD test body in a subprocess with N fake host devices.
+"""Run an SPMD test body in a subprocess with N fake host (CPU) devices.
 
 jax locks the platform device count at first init, so multi-device tests
 cannot run inside the main pytest process (which must keep 1 device for
@@ -20,6 +20,8 @@ def run_spmd(body: str, devices: int = 8, timeout: int = 600) -> dict:
     prog = textwrap.dedent(body)
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # fake host devices: the child must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", prog], env=env,
                           capture_output=True, text=True, timeout=timeout)

@@ -171,7 +171,7 @@ def test_checkpoint_save_materializes_cache_nodes():
     np.testing.assert_array_equal(np.asarray(got["t"].master), want)
     # the stored shadow is the stripped placeholder; restore rebuilds it
     np.testing.assert_array_equal(np.asarray(got["t"].shadow),
-                                  want.astype(np.float16))
+                                  want.astype(jnp.bfloat16))
     assert c.dirty[0]          # materialize (used by save) is non-mutating
 
 

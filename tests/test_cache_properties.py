@@ -4,6 +4,7 @@ Skipped wholesale without hypothesis (same guard as test_hsp /
 test_jagged); the deterministic cache tests live in
 tests/test_cache_embedding.py.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -44,7 +45,7 @@ def test_cached_lookup_bit_identical_to_full_table(ids):
     want = master[np.clip(a, 0, 95)]
     np.testing.assert_array_equal(rows, want)
     shadow = np.asarray(win.shadow)[c.translate(a)]
-    np.testing.assert_array_equal(shadow, want.astype(np.float16))
+    np.testing.assert_array_equal(shadow, want.astype(jnp.bfloat16))
     c.release(0, dirty=False)
 
 

@@ -36,7 +36,8 @@ def test_hsp_lookup_fwd_bwd_vs_dense():
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.hsp import make_hsp_lookup
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         V, d = 64, 8
         table = jax.random.normal(jax.random.PRNGKey(0), (V, d), jnp.float32)
         ids = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, V)
@@ -62,7 +63,8 @@ def test_hsp_global_baseline_lookup():
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.hsp import make_hsp_lookup
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         V, d = 64, 8
         table = jax.random.normal(jax.random.PRNGKey(0), (V, d), jnp.float32)
         ids = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, V)
@@ -86,7 +88,8 @@ def test_adagrad_state_identity_across_groups():
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.hsp import make_hsp_lookup, adagrad_update
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         V, d, lr = 32, 4, 0.1
         table0 = jax.random.normal(jax.random.PRNGKey(0), (V, d), jnp.float32)
         lookup = make_hsp_lookup(mesh, group_axes=("model",),
@@ -134,7 +137,8 @@ def test_hsp_collective_scale_reduction():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.hsp import make_hsp_lookup
         from repro.launch.hlo_analysis import analyze_text
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         V, d = 1024, 64
         ids_sds = jax.ShapeDtypeStruct((8, 128), jnp.int32)
         tbl_sds = jax.ShapeDtypeStruct((V, d), jnp.float32)
@@ -164,7 +168,8 @@ def test_grad_wire_compression_dtypes():
         import json, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.core.hsp import make_hsp_lookup
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         V, d = 64, 16
         table = jax.random.normal(jax.random.PRNGKey(0), (V, d), jnp.float32)
         ids = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, V)

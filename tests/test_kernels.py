@@ -11,7 +11,11 @@ from repro.kernels.jagged_attention import (jagged_attention,
 from repro.kernels.jagged_lookup import (jagged_lookup, jagged_lookup_ref,
                                          multi_table_lookup,
                                          scatter_add_rows, scatter_add_ref)
-from repro.kernels.neg_logits import neg_logits, neg_logits_ref
+from repro.kernels.jagged_lookup import kernel as LK
+from repro.kernels.jagged_lookup.ops import _segment_totals
+from repro.kernels.neg_logits import (fused_recall_lse, neg_logits,
+                                      neg_logits_ref)
+from repro.kernels.neg_logits import fused as NF
 from repro.models.hstu import init_rab
 
 
@@ -131,6 +135,73 @@ def test_scatter_add_matches_ref():
     out = scatter_add_rows(rows, ids, 20, interpret=True)
     ref = scatter_add_ref(rows, ids, 20)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
+def test_gather_kernel_half_width_tiles_bitwise(dtype):
+    # rows are picked out of their 8/16-row HBM tile in VMEM: exact for
+    # every dtype, including a table whose rows are not a tile multiple
+    table = jax.random.normal(jax.random.PRNGKey(0), (100, 16)).astype(dtype)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (37,), 0, 100)
+    out = LK.gather_pallas(table, ids, interpret=True)
+    assert out.dtype == table.dtype
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(table[ids], np.float32))
+
+
+def test_runsum_kernel_matches_segment_totals():
+    rows = jax.random.normal(jax.random.PRNGKey(2), (37, 16))
+    sids = jnp.sort(jax.random.randint(jax.random.PRNGKey(3), (37,), 0, 10))
+    got = LK.runsum_pallas(rows, sids, interpret=True)
+    want = _segment_totals(rows, sids)
+    end = np.r_[np.asarray(sids[:-1] != sids[1:]), True]
+    np.testing.assert_allclose(np.asarray(got)[end], np.asarray(want)[end],
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("slots_per_call", [1 << 15, 16, 8])
+def test_weighted_scatter_kernel_matches_scatter_add(monkeypatch,
+                                                     slots_per_call):
+    # small chunks force runs and tiles to straddle kernel calls
+    monkeypatch.setattr(LK, "SCATTER_SLOTS_PER_CALL", slots_per_call)
+    T, R, D, V = 33, 3, 16, 50
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    w = jax.random.normal(ks[0], (T * R,))
+    o = jax.random.normal(ks[1], (T, D))
+    ids = jax.random.randint(ks[2], (T * R,), -2, V + 3)
+    valid = (ids >= 0) & (ids < V)
+    skey = jnp.where(valid, ids, 2 ** 30)
+    order = jnp.argsort(skey)
+    got = LK.weighted_runsum_scatter(o, w[order] * valid[order], skey[order],
+                                     order // R, V, scale=0.7,
+                                     interpret=True)
+    want = jnp.zeros((V, D)).at[jnp.where(valid, ids, V)].add(
+        w[:, None] * (o[jnp.arange(T * R) // R] * 0.7), mode="drop")
+    np.testing.assert_allclose(np.asarray(got[:V]), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[V]).any()       # the drop sink stays zero
+
+
+def test_fused_neg_segment_groups_bitwise(monkeypatch):
+    # SMEM bounds the ids per kernel call: groups of whole segments run as
+    # separate calls, which must not change a bit of lse or its grads
+    T, R, V, D, seg = 64, 4, 100, 16, 16
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    out = jax.random.normal(ks[0], (T, D))
+    pos = jax.random.normal(ks[1], (T,))
+    table = jax.random.normal(ks[2], (V, D))
+    ids = jax.random.randint(ks[3], (T, R), 0, V)
+
+    def run():
+        return jax.value_and_grad(
+            lambda o, t: jnp.sum(fused_recall_lse(o, pos, t, ids, segment=seg,
+                                                  interpret=True)),
+            argnums=(0, 1))(out, table)
+
+    one = run()
+    monkeypatch.setattr(NF, "IDS_PER_CALL", seg * R)     # one segment/call
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(run())):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_multi_table_lookup_table_major():

@@ -11,6 +11,7 @@ import pytest
 
 from repro.configs import ARCHS, reduced
 from repro.core.pipeline import StageEvent, timeline_report
+from repro.launch.roofline import device_peak
 from repro.data.synthetic import synth_jagged_batch
 from repro.models.model_zoo import get_bundle
 from repro.obs import (Obs, MetricsRegistry, Tracer, busy_from_intervals,
@@ -122,7 +123,7 @@ def test_zero_event_export_and_ratios():
     assert pipeline_goodput([]) == {"wall_s": 0.0, "busy_s": 0.0,
                                     "goodput": 0.0, "bubble_ratio": 0.0}
     assert token_imbalance([]) == 0.0
-    assert measured_mfu(0.0, 0.0) == 0.0
+    assert measured_mfu(0.0, 0.0, 197e12) == 0.0
     assert MetricsRegistry().snapshot() == {}
 
 
@@ -227,9 +228,14 @@ def test_registry_publish_flattens_nested_stats():
 
 def test_measured_mfu():
     # 1 TFLOP in 0.01 s on a 197 TFLOP/s part
-    assert measured_mfu(1e12, 0.01) == pytest.approx(1e12 / (0.01 * 197e12))
+    peak = device_peak("TPU v5 lite")["flops"]
+    assert measured_mfu(1e12, 0.01, peak) == pytest.approx(
+        1e12 / (0.01 * 197e12))
     assert measured_mfu(1e12, 0.01, peak_flops=1e14) == pytest.approx(1.0)
-    assert measured_mfu(1e12, 0.0) == 0.0
+    assert measured_mfu(1e12, 0.0, peak) == 0.0
+    # a device kind without a published peak gets no MFU, not a v5e guess
+    assert device_peak("cpu") is None
+    assert measured_mfu(1e12, 0.01, None) is None
 
 
 def test_token_imbalance():
@@ -302,14 +308,18 @@ def test_engine_metrics_namespace():
     eng.run(3)
     snap = obs.snapshot()
     for fam in ("train_steps_total", "train_tokens_total", "train_loss",
-                "train_mfu_measured", "train_token_imbalance",
+                "train_token_imbalance",
                 "train_step_wall_s", "train_step_s",
                 "train_pipeline_goodput", "train_pipeline_bubble_ratio",
                 "train_timeline_wall_s"):
         assert fam in snap, fam
     assert snap["train_steps_total"]["values"][""] == 3.0
-    mfu = snap["train_mfu_measured"]["values"][""]
-    assert 0.0 < mfu < 1.0
+    if device_peak(jax.devices()[0].device_kind) is None:
+        # no published peak for this device: MFU is not measured
+        assert "train_mfu_measured" not in snap
+    else:
+        mfu = snap["train_mfu_measured"]["values"][""]
+        assert 0.0 < mfu < 1.0
     assert snap["train_step_s"]["values"][""]["count"] == 3
     # prometheus rendering of the full engine namespace stays well-formed
     text = obs.to_prometheus()

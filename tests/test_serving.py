@@ -385,7 +385,7 @@ def test_engine_from_raw_master_skips_optimizer_accum():
     eng = RecallEngine(cfg, dense, table.master, num_shards=1,
                        users_per_shard=2, k=10, retrieval_block=256)
     assert eng.table.accum.shape[0] == 0
-    assert eng.table.shadow.dtype == jnp.float16
+    assert eng.table.shadow.dtype == jnp.bfloat16
     rng = np.random.default_rng(31)
     hist = _histories(rng, 2, cfg.vocab_size)
     res = eng.serve([(u, *hist[u]) for u in hist])

@@ -391,7 +391,8 @@ def test_gr_serve_specs_compile_on_8_device_mesh():
     out = run_spmd("""
         import json, jax
         assert len(jax.devices()) == 8
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         from repro.launch.dryrun import build_serve_cell
         rec = build_serve_cell("hstu-tiny", max_users=15, rows_per_tick=4,
                                append_window=4, mesh=mesh)

@@ -207,7 +207,7 @@ def _gr_fused_setup(semi_async):
 @pytest.mark.parametrize("semi_async", [False, True])
 def test_trainer_fused_shadow_invariant_and_descent(semi_async):
     state, step, batch = _gr_fused_setup(semi_async)
-    assert state.table.shadow.dtype == jnp.float16
+    assert state.table.shadow.dtype == ET.SHADOW_DTYPE
     losses = []
     for i in range(6):
         state, m = step(state, batch(i % 2))
@@ -236,17 +236,17 @@ def test_checkpoint_roundtrip_rebuilds_shadow():
         with open(os.path.join(d, "step_2", "manifest.msgpack"), "rb") as f:
             manifest = msgpack.unpackb(f.read())
         V_, D_ = state.table.master.shape
-        fp16_shapes = [tuple(s) for s, dt in zip(manifest["shapes"],
+        half_shapes = [tuple(s) for s, dt in zip(manifest["shapes"],
                                                  manifest["dtypes"])
-                       if dt == "float16"]
-        assert (0, D_) in fp16_shapes
-        assert (V_, D_) not in fp16_shapes
+                       if dt == "bfloat16"]
+        assert (0, D_) in half_shapes
+        assert (V_, D_) not in half_shapes
         got = CKPT.restore(d, state._asdict())
         tbl = got["table"]
         assert tbl.shadow.shape == state.table.master.shape
         np.testing.assert_array_equal(
             np.asarray(tbl.shadow, np.float32),
-            np.asarray(tbl.master.astype(jnp.float16), np.float32))
+            np.asarray(tbl.master.astype(ET.SHADOW_DTYPE), np.float32))
         np.testing.assert_allclose(np.asarray(tbl.master),
                                    np.asarray(state.table.master))
 

@@ -37,7 +37,8 @@ def test_lm_train_step_parity_sharded_vs_single():
         s0, m0 = jax.jit(step)(s0, batch)
 
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeConfig("t", 64, 8, "train")
         plan = PT.make_plan(cfg, shape, mesh)
         pspecs = PT.lm_param_specs(jax.eval_shape(lambda: params), mesh, plan)
@@ -82,7 +83,8 @@ def test_moe_arch_parity_sharded_vs_single():
         loss_fn = lambda p: b.loss(p, batch, q_block=32)
         l0 = float(jax.jit(loss_fn)(params))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeConfig("t", 64, 4, "train")
         plan = PT.make_plan(cfg, shape, mesh)
         pspecs = PT.lm_param_specs(jax.eval_shape(lambda: params), mesh, plan)

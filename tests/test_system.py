@@ -57,11 +57,39 @@ def test_train_driver_cli():
                "--max-seq-len", "64", "--users-per-device", "2",
                "--num-negatives", "8", "--log-every", "2",
                "--ckpt-dir", d, "--ckpt-every", "2"]
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(d, "jax_cache")
         proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
                               timeout=600)
         assert proc.returncode == 0, proc.stdout + proc.stderr
         assert "[done]" in proc.stdout
         assert os.path.exists(os.path.join(d, "LATEST"))
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_placement(from_env):
+    """Entry points keep JAX's compilation cache at one fixed path in the
+    checkout, unless JAX_COMPILATION_CACHE_DIR places it; then JAX's own
+    reading of the variable governs and no code overrides it."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    with tempfile.TemporaryDirectory() as d:
+        if from_env:
+            env["JAX_COMPILATION_CACHE_DIR"] = d
+        prog = ("import jax\n"
+                "from repro.launch.compile_cache import (CACHE_DIR,\n"
+                "    use_repo_compile_cache)\n"
+                "used = use_repo_compile_cache()\n"
+                "print(used, jax.config.jax_compilation_cache_dir, CACHE_DIR)")
+        proc = subprocess.run([sys.executable, "-c", prog], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr
+        used, configured, repo_dir = proc.stdout.split()
+        want = d if from_env else repo_dir
+        assert used == configured == want
+        assert repo_dir == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
 
 
 @pytest.mark.slow_spmd
@@ -82,7 +110,8 @@ def test_dryrun_single_cell_small_mesh():
 
         cfg = reduced(ARCHS["internlm2-20b"])
         shape = ShapeConfig("t", 64, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         plan = PT.make_plan(cfg, shape, mesh)
         b = get_bundle(cfg)
         key = jax.random.PRNGKey(0)
