@@ -14,16 +14,22 @@ a thread pool; the schedule below is Algorithm 1 verbatim:
                   wait_unique(i+3); emb_fwd(i+2); dense_bwd(i+1);
                   wait_a2a + start_unique(i+4); dataload(i+5)
 
-Every stage invocation is timestamped; :func:`timeline_report` reproduces
-Table 6's computing/communication/not-overlapped/free breakdown.
+Every stage invocation is timestamped (``obs.trace.stage_span``: a
+``StageEvent`` and a profiler-trace span of the same interval), and every
+wait of the main thread on a host stage's future is a profiler-trace span
+``wait_<stage>``; :func:`timeline_report` reproduces Table 6's
+computing/communication/not-overlapped/free breakdown.
 """
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Tuple
+
+from jax.profiler import TraceAnnotation
+
+from repro.obs.trace import StageEvent, stage_span
 
 STAGES = ("dataload", "a2a", "unique", "emb_fwd", "dense_fwd", "dense_bwd",
           "emb_bwd")
@@ -35,14 +41,6 @@ COMM_STAGES = ("a2a",)
 # of where the async dispatch happens to block — the report coalesces both
 # under one honest stage name instead of showing a fake 0% backward.
 REPORT_MERGED = {"dense_fwd": "dense_fwd_bwd", "dense_bwd": "dense_fwd_bwd"}
-
-
-@dataclass
-class StageEvent:
-    stage: str
-    batch: int
-    start: float
-    end: float
 
 
 @dataclass
@@ -75,11 +73,9 @@ class SixStagePipeline:
 
     # -- plumbing ----------------------------------------------------------
     def _run(self, stage: str, i: int, *args) -> Any:
-        t0 = time.perf_counter()
-        out = getattr(self.hooks, stage)(i, *args)
+        with stage_span(self.events, stage, i, self._lock):
+            out = getattr(self.hooks, stage)(i, *args)
         with self._lock:
-            self.events.append(StageEvent(stage, i, t0,
-                                          time.perf_counter()))
             self._artifacts[(stage, i)] = out
         return out
 
@@ -91,7 +87,8 @@ class SixStagePipeline:
     def _wait(self, stage: str, i: int) -> Any:
         fut = self._futures.pop((stage, i), None)
         if fut is not None:
-            return fut.result()
+            with TraceAnnotation(f"wait_{stage}", step=i):
+                return fut.result()
         return self._get(stage, i)
 
     def _get(self, stage: str, i: int) -> Any:

@@ -24,6 +24,8 @@ import re
 from dataclasses import asdict, dataclass
 from typing import Dict, Mapping, Optional, Tuple
 
+import numpy as np
+
 from repro.configs.base import (ArchConfig, count_active_params, count_params)
 from repro.configs.shapes import ShapeConfig
 
@@ -148,6 +150,23 @@ def gr_dense_params(cfg: ArchConfig) -> int:
         d_ff = cfg.d_ff
         per += 3 * d * d_ff                      # gated interaction FFN
     return L * per
+
+
+def gr_model_flops(cfg: ArchConfig, lengths) -> float:
+    """Forward and backward operations one GR training step requires, from
+    its jagged sequence lengths (any shape; padding rows are 0),
+    recomputation not counted: 6 per dense weight per token, 12·H·d_qkv
+    per causal query-key pair per layer, and 6·d·(R + 1) per token for the
+    positive and negative logits. The benchmark's ``bench/flops.
+    model_flops`` counts the same."""
+    lengths = np.asarray(lengths, np.int64).reshape(-1)
+    t = int(lengths.sum())
+    pairs = int((lengths * (lengths + 1) // 2).sum())
+    dqk = cfg.qkv_dim or cfg.resolved_head_dim
+    dense = 6.0 * gr_dense_params(cfg) * t
+    attn = 12.0 * cfg.num_heads * dqk * pairs * cfg.num_layers
+    logits = 6.0 * t * (cfg.num_negatives + 1) * cfg.d_model
+    return dense + attn + logits
 
 
 def model_flops_per_step(cfg: ArchConfig, shape: ShapeConfig) -> Tuple[float, int]:

@@ -222,10 +222,6 @@ def main():
           f"{100*r.get('comm_not_overlapped_ratio', 0):.2f}%  "
           f"free {100*r.get('free_ratio', 0):.1f}%")
     if obs is not None:
-        gp = obs.snapshot().get("train_pipeline_goodput", {})
-        vals = gp.get("values", {})
-        if vals:
-            print(f"[obs] pipeline goodput {100*next(iter(vals.values())):.1f}%")
         if args.trace_out:
             obs.export_trace(args.trace_out)
             print(f"[obs] wrote Perfetto trace to {args.trace_out} "

@@ -89,7 +89,8 @@ def gr_hidden(params: Params, cfg: ArchConfig, x: jax.Array,
             f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
         return f(x), None
 
-    x, _ = jax.lax.scan(body, x, params["blocks"])
+    with jax.named_scope("blocks"):
+        x, _ = jax.lax.scan(body, x, params["blocks"])
     return _final_norm(params, cfg, x)
 
 

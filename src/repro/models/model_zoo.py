@@ -190,48 +190,52 @@ class GRBundle:
         h = GR.gr_hidden_sharded(dense_params, cfg, x, batch["offsets"],
                                  batch["timestamps"], attn_fn=attn_fn,
                                  remat=remat)
-        pos_emb = lookup(table, batch["labels"])             # (G, cap, d)
+        # the loss layer: positive logits and the negative path
+        with jax.named_scope("loss"):
+            pos_emb = lookup(table, batch["labels"])             # (G, cap, d)
 
-        G, cap = batch["ids"].shape
-        valid = (jnp.arange(cap, dtype=I32)[None, :]
-                 < batch["offsets"][:, -1][:, None])         # (G, cap)
+            G, cap = batch["ids"].shape
+            valid = (jnp.arange(cap, dtype=I32)[None, :]
+                     < batch["offsets"][:, -1][:, None])         # (G, cap)
 
-        tau = 1.0
-        if neg_mode == "fused":
-            # tokens are independent in the negative path: flatten the
-            # shard axis so one kernel launch covers the global batch (and
-            # §4.3.3 sharing mixes tokens across shards — intra-*batch*).
-            R = batch["neg_ids"].shape[-1]
-            return NS.fused_sampled_softmax_loss(
-                h.reshape(G * cap, -1), pos_emb.reshape(G * cap, -1),
-                table, batch["neg_ids"].reshape(G * cap, R),
-                key=jax.random.PRNGKey(batch["rng"][0]), tau=tau,
-                valid=valid.reshape(-1), segment=neg_segment,
-                expansion=expansion, fetch_dtype=fetch_dtype,
-                shadow=shadow, impl=neg_impl,
-                rows_per_step=neg_rows_per_step,
-                scatter_impl=neg_scatter_impl)
-        if neg_mode == "baseline":
-            neg_emb = jnp.take(table, batch["neg_ids"], axis=0)  # (G,cap,R,d)
-            logits = jax.vmap(partial(NS.neg_logits_baseline, tau=tau))(
-                h, neg_emb.astype(h.dtype))
-        else:
-            logits = jax.vmap(
-                lambda hh, nn: NS.neg_logits_segmented(
-                    hh, table, nn, segment=neg_segment, tau=tau,
-                    fetch_dtype=fetch_dtype))(h, batch["neg_ids"])
-        if expansion > 1:
-            key = jax.random.PRNGKey(batch["rng"][0])
-            keys = jax.random.split(key, G)
-            logits = jax.vmap(
-                lambda k, lg, vv: NS.share_logits(k, lg, expansion, vv)
-            )(keys, logits, valid)
+            tau = 1.0
+            if neg_mode == "fused":
+                # tokens are independent in the negative path: flatten
+                # the shard axis so one kernel launch covers the global
+                # batch (and §4.3.3 sharing mixes tokens across shards —
+                # intra-*batch*).
+                R = batch["neg_ids"].shape[-1]
+                return NS.fused_sampled_softmax_loss(
+                    h.reshape(G * cap, -1), pos_emb.reshape(G * cap, -1),
+                    table, batch["neg_ids"].reshape(G * cap, R),
+                    key=jax.random.PRNGKey(batch["rng"][0]), tau=tau,
+                    valid=valid.reshape(-1), segment=neg_segment,
+                    expansion=expansion, fetch_dtype=fetch_dtype,
+                    shadow=shadow, impl=neg_impl,
+                    rows_per_step=neg_rows_per_step,
+                    scatter_impl=neg_scatter_impl)
+            if neg_mode == "baseline":
+                # (G, cap, R, d)
+                neg_emb = jnp.take(table, batch["neg_ids"], axis=0)
+                logits = jax.vmap(partial(NS.neg_logits_baseline, tau=tau))(
+                    h, neg_emb.astype(h.dtype))
+            else:
+                logits = jax.vmap(
+                    lambda hh, nn: NS.neg_logits_segmented(
+                        hh, table, nn, segment=neg_segment, tau=tau,
+                        fetch_dtype=fetch_dtype))(h, batch["neg_ids"])
+            if expansion > 1:
+                key = jax.random.PRNGKey(batch["rng"][0])
+                keys = jax.random.split(key, G)
+                logits = jax.vmap(
+                    lambda k, lg, vv: NS.share_logits(k, lg, expansion, vv)
+                )(keys, logits, valid)
 
-        pos = jnp.sum(h.astype(jnp.float32) * pos_emb.astype(jnp.float32),
-                      axis=-1) / tau
-        return NS.sampled_softmax_loss(
-            pos.reshape(-1), logits.reshape(G * cap, -1),
-            valid.reshape(-1))
+            pos = jnp.sum(h.astype(jnp.float32)
+                          * pos_emb.astype(jnp.float32), axis=-1) / tau
+            return NS.sampled_softmax_loss(
+                pos.reshape(-1), logits.reshape(G * cap, -1),
+                valid.reshape(-1))
 
     # ---- dry-run specs ----------------------------------------------------
     def input_specs(self, shape: ShapeConfig,

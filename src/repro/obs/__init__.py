@@ -17,7 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from repro.obs.derived import measured_mfu, pipeline_goodput, token_imbalance
+from repro.obs.derived import measured_mfu, token_imbalance
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry)
 from repro.obs.trace import Span, Tracer, busy_from_intervals, trace_busy_by_track
@@ -33,7 +33,6 @@ __all__ = [
     "DEFAULT_BUCKETS",
     "measured_mfu",
     "token_imbalance",
-    "pipeline_goodput",
     "busy_from_intervals",
     "trace_busy_by_track",
 ]

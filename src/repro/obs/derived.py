@@ -1,31 +1,27 @@
-"""Derived gauges: measured MFU, token-load imbalance, pipeline goodput.
+"""Derived gauges: measured MFU and token-load imbalance.
 
 These close the loop between the static roofline estimates in
 ``launch/roofline.py`` and what a run actually did:
 
-- ``measured_mfu`` — model FLOPs per step over *measured* step wall
-  time against the device's peak from ``launch/roofline.DEVICE_PEAKS``
-  (paper's 54.71% MFU axis); None ("not measured") for a device kind
-  with no published peak.
+- ``measured_mfu`` — model FLOPs per step (``launch/roofline.
+  gr_model_flops``: dense weights, causal attention pairs, positive and
+  negative logits) over *measured* step wall time against the device's
+  peak from ``launch/roofline.DEVICE_PEAKS`` (paper's 54.71% MFU axis);
+  None ("not measured") for a device kind with no published peak.
 - ``token_imbalance`` — makespan-relative imbalance of per-device
   token loads (paper's 47% -> 2.4% axis), delegating to
   ``core/load_balance.imbalance_ratio``.
-- ``pipeline_goodput`` — busy/wall ratio of the stage-event stream
-  (paper's 94%-NPU-utilization axis), with bubble ratio as the
-  complement.
 
-All guards: zero events / zero wall time / empty loads return zeros,
-never divide-by-zero.
+All guards: zero wall time / empty loads return zeros, never
+divide-by-zero.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Mapping, Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 from repro.core import load_balance as LB
-from repro.core.pipeline import StageEvent
-from repro.obs.trace import busy_from_intervals
 
-__all__ = ["measured_mfu", "token_imbalance", "pipeline_goodput"]
+__all__ = ["measured_mfu", "token_imbalance"]
 
 
 def measured_mfu(model_flops: float, wall_s: float,
@@ -33,7 +29,7 @@ def measured_mfu(model_flops: float, wall_s: float,
     """Measured model-FLOPs utilization for one step.
 
     ``model_flops`` comes from ``roofline.model_flops_per_step`` (or
-    ``6 * n_dense_params * tokens`` for GR); ``wall_s`` is the measured
+    ``roofline.gr_model_flops`` for GR); ``wall_s`` is the measured
     step wall time; ``peak_flops`` the device's entry in
     ``roofline.DEVICE_PEAKS``. Returns None without a peak (not
     measured) and 0.0 when a count is non-positive.
@@ -58,21 +54,3 @@ def token_imbalance(loads: Sequence[float]) -> float:
         return 0.0
     return float(LB.imbalance_ratio((), (), loads=loads))
 
-
-def pipeline_goodput(events: Iterable[StageEvent]) -> Dict[str, float]:
-    """Goodput / bubble ratio of a stage-event stream.
-
-    Busy time is the interval *union* across all stages (any stage
-    active counts as busy); wall is first-start to last-end.  Bubble
-    ratio is ``1 - goodput``.  Zero events -> all-zero dict.
-    """
-    ivs: list = [(ev.start, ev.end) for ev in events]
-    if not ivs:
-        return {"wall_s": 0.0, "busy_s": 0.0, "goodput": 0.0, "bubble_ratio": 0.0}
-    wall = max(e for _, e in ivs) - min(s for s, _ in ivs)
-    busy = busy_from_intervals(ivs)
-    if wall <= 0.0:
-        return {"wall_s": 0.0, "busy_s": busy, "goodput": 0.0, "bubble_ratio": 0.0}
-    goodput = busy / wall
-    return {"wall_s": wall, "busy_s": busy, "goodput": goodput,
-            "bubble_ratio": max(0.0, 1.0 - goodput)}

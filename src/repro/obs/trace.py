@@ -1,13 +1,22 @@
-"""Span tracer with Chrome/Perfetto ``trace_event`` export.
+"""Stage spans on two clocks, and a span tracer with Chrome/Perfetto
+``trace_event`` export.
+
+:func:`stage_span` times one pipeline stage call once and writes it to two
+sinks: a ``StageEvent`` on ``time.perf_counter`` (what ``timeline_report``
+and :meth:`Tracer.ingest_stage_events` read) and a
+``jax.profiler.TraceAnnotation`` named by the stage with the step index as
+its ``step`` argument, which lands in a profiler trace on the device
+trace's clock whenever a profiler session is open (and costs about a
+microsecond when none is).
 
 The tracer is deliberately dumb: a thread-safe append-only list of
 closed ``Span`` records on a monotonic clock.  Everything clever —
-per-track busy-time union, goodput ratios, the Chrome JSON layout —
+per-track busy-time union, the Chrome JSON layout —
 is computed at export/report time from the immutable span list, so
 recording stays cheap enough to leave on during benchmarks.
 
 Clocks: spans carry ``time.perf_counter()`` timestamps (seconds,
-monotonic, same clock as ``core/pipeline.StageEvent``), so spans
+monotonic, same clock as ``StageEvent``), so spans
 recorded live and spans ingested from a ``SixStagePipeline`` event
 stream land on a common timeline.  Tests inject explicit ``now=``
 values instead of patching the clock.
@@ -17,19 +26,47 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
-from repro.core.pipeline import REPORT_MERGED, StageEvent
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
+    "StageEvent",
     "Tracer",
     "NULL_SPAN",
     "busy_from_intervals",
+    "stage_span",
     "trace_busy_by_track",
 ]
+
+
+@dataclass
+class StageEvent:
+    """One call of a pipeline stage for batch ``batch``, on
+    ``perf_counter`` seconds."""
+    stage: str
+    batch: int
+    start: float
+    end: float
+
+
+@contextmanager
+def stage_span(events: List[StageEvent], stage: str, i: int,
+               lock: Optional[threading.Lock] = None):
+    """Time one call of ``stage`` for batch ``i``: a profiler-trace span
+    ``stage`` with argument ``step=i`` around the body, and, when the body
+    returns, the same interval appended to ``events`` (under ``lock``
+    where other threads append too). A body that raises records no
+    event."""
+    t0 = time.perf_counter()
+    with TraceAnnotation(stage, step=i):
+        yield
+    t1 = time.perf_counter()
+    with lock or nullcontext():
+        events.append(StageEvent(stage, i, t0, t1))
 
 
 @dataclass(frozen=True)
@@ -103,7 +140,6 @@ class Tracer:
         self.clock = clock
         self._lock = threading.Lock()
         self._spans: List[Span] = []
-        self._instants: List[Tuple[str, str, float, Mapping[str, Any]]] = []
 
     # ---- recording ---------------------------------------------------
     @contextmanager
@@ -131,20 +167,10 @@ class Tracer:
         with self._lock:
             self._spans.append(Span(name, track, start, end, args or {}))
 
-    def instant(self, name: str, track: str = "events",
-                now: Optional[float] = None,
-                args: Optional[Mapping[str, Any]] = None) -> None:
-        """Record a zero-duration marker (Chrome ``ph: "i"``)."""
-        if not self.enabled:
-            return
-        ts = self.clock() if now is None else now
-        with self._lock:
-            self._instants.append((name, track, ts, args or {}))
-
     # ---- adapters ----------------------------------------------------
     def ingest_stage_events(self, events: Sequence[StageEvent],
                             records: Optional[Mapping[int, Mapping[str, Any]]] = None,
-                            merge: Mapping[str, str] = REPORT_MERGED) -> int:
+                            merge: Optional[Mapping[str, str]] = None) -> int:
         """Ingest a ``SixStagePipeline`` event stream as spans.
 
         One track per (merged) stage name, matching ``timeline_report``'s
@@ -154,6 +180,8 @@ class Tracer:
         """
         if not self.enabled:
             return 0
+        if merge is None:
+            from repro.core.pipeline import REPORT_MERGED as merge
         n = 0
         for ev in events:
             track = merge.get(ev.stage, ev.stage)
@@ -170,33 +198,6 @@ class Tracer:
             n += 1
         return n
 
-    def ingest_recovery_events(self, events: Sequence[Any],
-                               t0: float = 0.0) -> int:
-        """Ingest resilience ``RecoveryEvent``s as spans on a "recovery"
-        track.
-
-        ``RecoveryEvent`` carries only durations (``wall_s``), so spans
-        are laid end-to-end from ``t0`` — a post-hoc view, not a real
-        timeline.  ``GREngine.run_resilient`` records recovery spans
-        live with real timestamps instead; this adapter covers event
-        lists captured elsewhere.
-        """
-        if not self.enabled:
-            return 0
-        t = t0
-        n = 0
-        for ev in events:
-            wall = float(getattr(ev, "wall_s", 0.0))
-            self.record("recovery", "recovery", t, t + wall, {
-                "failed_step": getattr(ev, "failed_step", None),
-                "restored_step": getattr(ev, "restored_step", None),
-                "steps_lost": getattr(ev, "steps_lost", None),
-                "error": str(getattr(ev, "error", "")),
-            })
-            t += wall
-            n += 1
-        return n
-
     # ---- views -------------------------------------------------------
     def spans(self) -> List[Span]:
         with self._lock:
@@ -209,7 +210,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
-            self._instants.clear()
 
     def busy_by_track(self) -> Dict[str, float]:
         """Per-track busy seconds (interval union of that track's spans)."""
@@ -233,10 +233,8 @@ class Tracer:
         metadata events; spans become ``X`` complete events with float-µs
         timestamps so round-tripped busy times match to <1 ns.
         """
-        with self._lock:
-            spans = list(self._spans)
-            instants = list(self._instants)
-        tracks = sorted({s.track for s in spans} | {t for _, t, _, _ in instants})
+        spans = self.spans()
+        tracks = sorted({s.track for s in spans})
         tid_of = {t: i + 1 for i, t in enumerate(tracks)}
         events: List[Dict[str, Any]] = [{
             "name": "process_name", "ph": "M", "pid": 1, "tid": 0,
@@ -251,10 +249,6 @@ class Tracer:
                 "ts": sp.start * 1e6, "dur": sp.dur * 1e6,
                 "cat": sp.track, "args": dict(sp.args),
             })
-        for name, track, ts, args in instants:
-            events.append({"name": name, "ph": "i", "pid": 1,
-                           "tid": tid_of[track], "ts": ts * 1e6, "s": "t",
-                           "args": dict(args)})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def export(self, path: str, process_name: str = "repro") -> Dict[str, Any]:

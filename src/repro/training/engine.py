@@ -46,14 +46,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.pipeline import (PipelineHooks, STAGES, SixStagePipeline,
                                  StageEvent,
                                  timeline_report as _timeline_report)
 from repro.embedding import cache as EC
 from repro.embedding import tables as ET
-from repro.launch.roofline import device_peak, gr_dense_params
+from repro.launch.roofline import device_peak, gr_model_flops
 from repro.obs import Obs
-from repro.obs.derived import measured_mfu, pipeline_goodput, token_imbalance
+from repro.obs.derived import measured_mfu, token_imbalance
+from repro.obs.trace import stage_span
 from repro.training import resilience as R
 from repro.training.trainer import (GRTrainState, gr_pending_slots,
                                     gr_train_state, host_unique_candidates,
@@ -186,10 +189,9 @@ class GREngine:
         live = obs is not None and obs.enabled
         self._mx = obs.metrics if live else None
         self._tr = obs.tracer if live else None
-        # measured MFU: model FLOPs for GR = 6 * dense params * tokens,
-        # against the device's published peak (none on a kind without one)
-        self._obs_flops_per_token = (
-            6.0 * gr_dense_params(bundle.cfg) if live else 0.0)
+        # measured MFU: the step's model FLOPs (launch/roofline.
+        # gr_model_flops) against the device's published peak (none on a
+        # kind without one)
         peak = device_peak(jax.devices()[0].device_kind)
         self._peak_flops = peak["flops"] if peak else None
         self._last_step_end: Optional[float] = None
@@ -300,12 +302,14 @@ class GREngine:
         skip = (("weights",) if self.cache is None
                 else ("weights", "ids", "labels", "neg_ids"))
         dev = {k: jnp.asarray(v) for k, v in nb.items() if k not in skip}
-        jax.block_until_ready(dev)
+        with TraceAnnotation("h2d", step=i):
+            jax.block_until_ready(dev)
         return {"np": nb, "dev": dev}
 
     def _hk_unique(self, i: int, art):
         if self.cache is not None:
-            return self._cache_prefetch(i, art)
+            with TraceAnnotation("cache_prefetch", step=i):
+                return self._cache_prefetch(i, art)
         vocab = self.bundle.cfg.vocab_size
         if self.state is not None:
             vocab = self.state.table.master.shape[0]
@@ -374,7 +378,8 @@ class GREngine:
 
     def _hk_dense_bwd(self, i: int, art):
         full = self._arts[i]
-        loss = float(full["dout"].loss)   # realize the dispatched fwd+bwd
+        with TraceAnnotation("loss_sync", step=i):
+            loss = float(full["dout"].loss)   # realize the dispatched fwd+bwd
         tokens = int(np.asarray(full["np"]["offsets"])[:, -1].sum())
         rec = {"step": i, "loss": loss, "tokens": tokens}
         if self.cache is not None:
@@ -415,8 +420,7 @@ class GREngine:
             if self.cache is not None:
                 self.cache.release(i, dirty=False)
             self._bcache[i] = None
-            if self.step_callback:
-                self.step_callback(i, rec, st)
+            self._callback(i, rec, st)
             return rec
         cand_s, cand_f = full["cand"]
         release_dirty = False   # unpin AFTER the callback (see below)
@@ -466,8 +470,7 @@ class GREngine:
                 self.cache.publish(table)
                 release_dirty = True
         self._bcache[i] = None            # free the consumed numpy batch
-        if self.step_callback:
-            self.step_callback(i, rec, snapshot)
+        self._callback(i, rec, snapshot)
         if self.cache is not None and release_dirty:
             # unpin only now: the callback may checkpoint the pre-landing
             # snapshot, and a concurrent worker-thread prepare() must not
@@ -476,6 +479,11 @@ class GREngine:
             # carries the pairs — a double-apply on restore)
             self.cache.release(i, dirty=True)
         return rec
+
+    def _callback(self, i: int, rec: Dict[str, Any], snapshot) -> None:
+        if self.step_callback:
+            with TraceAnnotation("step_callback", step=i):
+                self.step_callback(i, rec, snapshot)
 
     def _make_hooks(self) -> PipelineHooks:
         return PipelineHooks(**self._stage_fns)
@@ -496,7 +504,8 @@ class GREngine:
         wall = now - prev
         loads = np.asarray(full["np"]["offsets"])[:, -1]
         rec["step_wall_s"] = wall
-        rec["mfu"] = measured_mfu(self._obs_flops_per_token * rec["tokens"],
+        lengths = np.diff(np.asarray(full["np"]["offsets"]), axis=-1)
+        rec["mfu"] = measured_mfu(gr_model_flops(self.bundle.cfg, lengths),
                                   wall, self._peak_flops)
         rec["imbalance"] = token_imbalance(loads)
         mx = self._mx
@@ -522,8 +531,7 @@ class GREngine:
     def _obs_finalize(self, results: List[Dict[str, Any]]) -> None:
         """End-of-run observability: ingest the stage-event trace (one
         Perfetto track per merged stage), publish the Table-6 timeline
-        breakdown, pipeline goodput/bubble (the 94%-utilization axis),
-        and the cache's cumulative counters."""
+        breakdown and the cache's cumulative counters."""
         if self._mx is None:
             return
         recs = {r["step"]: r for r in results}
@@ -531,11 +539,6 @@ class GREngine:
         tl = self.timeline_report()
         if tl:
             self._mx.publish("train_timeline", tl)
-        gp = pipeline_goodput(self.events)
-        self._mx.gauge("train_pipeline_goodput",
-                       "busy/wall of the stage stream").set(gp["goodput"])
-        self._mx.gauge("train_pipeline_bubble_ratio",
-                       "1 - goodput").set(gp["bubble_ratio"])
         if self.cache is not None:
             self._mx.publish("cache", self.cache.counters())
 
@@ -577,7 +580,8 @@ class GREngine:
         """Train ``steps`` batches; returns per-step records."""
         if steps <= 0:
             return []
-        self._prepare_run(steps)
+        with TraceAnnotation("prepare_run"):
+            self._prepare_run(steps)
         if self.schedule == "algorithm1":
             pipe = SixStagePipeline(self._make_hooks(), workers=self.workers)
             results = pipe.run(steps)
@@ -594,10 +598,8 @@ class GREngine:
         results = []
 
         def stage(name, i, *a, **kw):
-            t0 = time.perf_counter()
-            out = self._stage_fns[name](i, *a, **kw)
-            self.events.append(StageEvent(name, i, t0, time.perf_counter()))
-            return out
+            with stage_span(self.events, name, i):
+                return self._stage_fns[name](i, *a, **kw)
 
         self._leftover = False            # flat lands pending every step
         for i in range(steps):
@@ -607,12 +609,12 @@ class GREngine:
             art = stage("emb_fwd", i, art)
             if self.semi_async:
                 # the sparse half of emb_bwd(i−1): the delayed landing
-                t0 = time.perf_counter()
-                self._land_pending()
+                # (at i = 0, pairs a previous run left pending)
                 if i > 0:
-                    self.events.append(
-                        StageEvent("emb_bwd", i - 1, t0,
-                                   time.perf_counter()))
+                    with stage_span(self.events, "emb_bwd", i - 1):
+                        self._land_pending()
+                else:
+                    self._land_pending()
             small = stage("dense_fwd", i, art)
             rec = stage("dense_bwd", i, small)
             stage("emb_bwd", i, rec, defer_sparse=True)

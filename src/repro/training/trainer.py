@@ -303,10 +303,15 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
     """
     x_mode = semi_async and input_gather is not None
 
+    # Named scopes mark each layer's ops in the HLO metadata (the op_name
+    # path a profiler trace carries, e.g. ``transpose(jvp(loss))``), so a
+    # trace reader can split a stage's device time by layer; they change
+    # no value.
     def emb_fwd(stale_master, batch):
         if not x_mode:
             return None
-        return input_gather(stale_master, batch)
+        with jax.named_scope("input_gather"):
+            return input_gather(stale_master, batch)
 
     def dense_fwd_bwd(dense, table: ET.ShadowedTable, batch,
                       x=None, stale_master=None) -> GRDenseOut:
@@ -335,36 +340,41 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
                 cand_sorted=None, cand_first=None, *,
                 apply_sparse: bool = True, slots: Optional[int] = None):
         vocab = table.master.shape[0]
-        if semi_async:
-            if dout.grad_x is not None:
-                # transpose of the emb_fwd gather: the input-side scatter
-                # the fused step's autodiff emits for input_table
-                tsd = jax.ShapeDtypeStruct(table.master.shape,
-                                           table.master.dtype)
-                g_stale = jax.linear_transpose(
-                    lambda t: input_gather(t, batch), tsd)(dout.grad_x)[0]
+        with jax.named_scope("table_grad"):
+            if semi_async:
+                if dout.grad_x is not None:
+                    # transpose of the emb_fwd gather: the input-side
+                    # scatter the fused step's autodiff emits for
+                    # input_table
+                    tsd = jax.ShapeDtypeStruct(table.master.shape,
+                                               table.master.dtype)
+                    g_stale = jax.linear_transpose(
+                        lambda t: input_gather(t, batch), tsd)(
+                            dout.grad_x)[0]
+                else:
+                    g_stale = dout.grad_stale
+                # the barrier pins the summation order: fused into one jit
+                # with the scatter that built g_stale, XLA may fold the add
+                # into it and round differently than the staged engine
+                g_stale, g_fresh = jax.lax.optimization_barrier(
+                    (g_stale, dout.grad_table))
+                gt = (g_stale + g_fresh).astype(jnp.float32)
             else:
-                g_stale = dout.grad_stale
-            # the barrier pins the summation order: fused into one jit
-            # with the scatter that built g_stale, XLA may fold the add
-            # into it and round differently than the staged engine
-            g_stale, g_fresh = jax.lax.optimization_barrier(
-                (g_stale, dout.grad_table))
-            gt = (g_stale + g_fresh).astype(jnp.float32)
-        else:
-            gt = dout.grad_table.astype(jnp.float32)
-        p_ids, p_rows = _table_grad_pairs(gt, batch, vocab,
-                                          cand_sorted, cand_first, slots)
-        new_dense, new_opt = O.adamw_update(
-            dout.grads_dense, dense_opt, dense, lr=lr_dense,
-            weight_decay=0.0)
-        new_table = (O.adagrad_sparse_update(table, p_ids, p_rows,
-                                             lr=lr_sparse)
+                gt = dout.grad_table.astype(jnp.float32)
+            p_ids, p_rows = _table_grad_pairs(gt, batch, vocab,
+                                              cand_sorted, cand_first, slots)
+        with jax.named_scope("adamw"):
+            new_dense, new_opt = O.adamw_update(
+                dout.grads_dense, dense_opt, dense, lr=lr_dense,
+                weight_decay=0.0)
+        new_table = (sparse_apply(table, p_ids, p_rows)
                      if apply_sparse else table)
         return new_dense, new_opt, new_table, p_ids, p_rows
 
     def sparse_apply(table: ET.ShadowedTable, p_ids, p_rows):
-        return O.adagrad_sparse_update(table, p_ids, p_rows, lr=lr_sparse)
+        with jax.named_scope("adagrad"):
+            return O.adagrad_sparse_update(table, p_ids, p_rows,
+                                           lr=lr_sparse)
 
     return GRStages(emb_fwd, dense_fwd_bwd, emb_bwd, sparse_apply)
 

@@ -1,23 +1,29 @@
-"""Observability layer: tracer, exporter, registry, derived gauges, and
-regression tests that migrated stats surfaces stay bit-unchanged."""
+"""Observability layer: tracer, exporter, registry, derived gauges, the
+engine's spans and layer scopes in a profiler trace, and regression tests
+that migrated stats surfaces stay bit-unchanged."""
+import glob
+import importlib.util
 import json
 import os
 import tempfile
 import threading
+from collections import Counter
 
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS, reduced
-from repro.core.pipeline import StageEvent, timeline_report
-from repro.launch.roofline import device_peak
+from repro.core.pipeline import STAGES, StageEvent, timeline_report
+from repro.launch.roofline import device_peak, gr_model_flops
 from repro.data.synthetic import synth_jagged_batch
 from repro.models.model_zoo import get_bundle
 from repro.obs import (Obs, MetricsRegistry, Tracer, busy_from_intervals,
-                       measured_mfu, pipeline_goodput, token_imbalance,
-                       trace_busy_by_track)
+                       measured_mfu, token_imbalance, trace_busy_by_track)
 from repro.training.engine import GREngine
+from repro.training.trainer import host_unique_candidates
+
+BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +68,6 @@ def test_disabled_tracer_records_nothing():
     with t.span("x", "y"):
         pass
     t.record("a", "b", 0.0, 1.0)
-    t.instant("i")
     assert len(t) == 0
     assert t.busy_by_track() == {}
     # shared null context: span() must not allocate per call
@@ -93,13 +98,12 @@ def test_chrome_trace_schema():
     t = Tracer()
     t.record("a", "s1", 0.0, 1.0, {"step": 0})
     t.record("b", "s2", 0.5, 2.0)
-    t.instant("marker", "s1", now=0.75)
     trace = t.to_chrome_trace(process_name="proc")
     # JSON round-trip must be clean (Perfetto loads the file as-is)
     trace = json.loads(json.dumps(trace))
     assert trace["displayTimeUnit"] == "ms"
     evs = trace["traceEvents"]
-    assert all(ev["ph"] in ("X", "M", "i") for ev in evs)
+    assert all(ev["ph"] in ("X", "M") for ev in evs)
     meta = [ev for ev in evs if ev["ph"] == "M"]
     assert any(ev["name"] == "process_name" and
                ev["args"]["name"] == "proc" for ev in meta)
@@ -120,8 +124,6 @@ def test_zero_event_export_and_ratios():
     assert trace["traceEvents"][0]["name"] == "process_name"
     assert trace_busy_by_track(trace) == {}
     assert t.busy_by_track() == {}
-    assert pipeline_goodput([]) == {"wall_s": 0.0, "busy_s": 0.0,
-                                    "goodput": 0.0, "bubble_ratio": 0.0}
     assert token_imbalance([]) == 0.0
     assert measured_mfu(0.0, 0.0, 197e12) == 0.0
     assert MetricsRegistry().snapshot() == {}
@@ -141,19 +143,6 @@ def test_ingest_stage_events_merges_and_decorates():
     assert busy["dense_fwd_bwd"] == 2.0 and busy["dataload"] == 0.25
     sp = [s for s in t.spans() if s.name == "dense_fwd"][0]
     assert sp.args["loss"] == 1.5 and sp.args["cache_hit_rate"] == 0.9
-
-
-def test_ingest_recovery_events_lays_spans_cumulatively():
-    class Ev:
-        failed_step, restored_step, steps_lost = 7, 5, 2
-        error, wall_s = "boom", 0.5
-
-    t = Tracer()
-    assert t.ingest_recovery_events([Ev(), Ev()], t0=1.0) == 2
-    spans = t.spans()
-    assert (spans[0].start, spans[0].end) == (1.0, 1.5)
-    assert (spans[1].start, spans[1].end) == (1.5, 2.0)
-    assert spans[0].args["failed_step"] == 7
 
 
 # ---------------------------------------------------------------------------
@@ -246,16 +235,6 @@ def test_token_imbalance():
     assert token_imbalance([0, 0]) == 0.0
 
 
-def test_pipeline_goodput():
-    evs = [StageEvent("dataload", 0, 0.0, 1.0),
-           StageEvent("dense_fwd", 0, 0.5, 2.0),
-           StageEvent("emb_bwd", 0, 3.0, 4.0)]
-    gp = pipeline_goodput(evs)
-    assert gp["wall_s"] == 4.0 and gp["busy_s"] == 3.0
-    assert gp["goodput"] == pytest.approx(0.75)
-    assert gp["bubble_ratio"] == pytest.approx(0.25)
-
-
 # ---------------------------------------------------------------------------
 # engine integration + migration regression
 # ---------------------------------------------------------------------------
@@ -310,9 +289,9 @@ def test_engine_metrics_namespace():
     for fam in ("train_steps_total", "train_tokens_total", "train_loss",
                 "train_token_imbalance",
                 "train_step_wall_s", "train_step_s",
-                "train_pipeline_goodput", "train_pipeline_bubble_ratio",
                 "train_timeline_wall_s"):
         assert fam in snap, fam
+    assert not [f for f in snap if "goodput" in f or "bubble" in f]
     assert snap["train_steps_total"]["values"][""] == 3.0
     if device_peak(jax.devices()[0].device_kind) is None:
         # no published peak for this device: MFU is not measured
@@ -324,6 +303,204 @@ def test_engine_metrics_namespace():
     # prometheus rendering of the full engine namespace stays well-formed
     text = obs.to_prometheus()
     assert "# TYPE train_step_s histogram" in text
+
+
+# ---------------------------------------------------------------------------
+# the engine's spans and layer scopes in a profiler trace
+# ---------------------------------------------------------------------------
+
+SPAN_STEPS = 4
+SUB_SPANS = ("loss_sync", "step_callback", "h2d", "prepare_run")
+WAITS = tuple(f"wait_{s}" for s in STAGES)
+
+
+def _span_engine(schedule):
+    cfg = reduced(ARCHS["hstu-tiny"]).replace(num_negatives=8,
+                                              vocab_size=512)
+
+    def data_fn(i):
+        return synth_jagged_batch(jax.random.PRNGKey(i), 2, 96, 512, 8)
+
+    return GREngine(get_bundle(cfg), data_fn, schedule=schedule, workers=2,
+                    step_callback=lambda i, rec, state: None)
+
+
+def _trace_spans(trace_dir):
+    """The program's spans in a profiler trace, one list per host thread
+    that has any: ``(name, start_ns, end_ns, step)``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    names = set(STAGES) | set(SUB_SPANS) | set(WAITS)
+    threads = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            spans = []
+            for e in line.events:
+                if e.name in names:
+                    step = dict(e.stats).get("step")
+                    spans.append((e.name, e.start_ns,
+                                  e.start_ns + e.duration_ns,
+                                  None if step is None else int(step)))
+            if spans:
+                threads.append(spans)
+    return threads
+
+
+@pytest.fixture(scope="module", params=("algorithm1", "flat"))
+def profiled(request):
+    """The same tiny run from one seed, plainly and inside a profiler
+    session (Python tracer off)."""
+    plain = _span_engine(request.param)
+    plain_recs = plain.run(SPAN_STEPS)
+    eng = _span_engine(request.param)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            recs = eng.run(SPAN_STEPS)
+        finally:
+            jax.profiler.stop_trace()
+        threads = _trace_spans(d)
+    return request.param, eng, recs, plain, plain_recs, threads
+
+
+def _nested(line, sub, stage):
+    """Every ``sub`` span of a thread lies inside a ``stage`` span of the
+    same step; returns how many there are."""
+    outer = [(a, b, st) for n, a, b, st in line if n == stage]
+    subs = [(a, b, st) for n, a, b, st in line if n == sub]
+    for a, b, st in subs:
+        assert any(oa <= a and b <= ob and ost == st
+                   for oa, ob, ost in outer), (sub, stage, st)
+    return len(subs)
+
+
+def test_stage_spans_in_profiler_trace(profiled):
+    schedule, eng, _, _, _, threads = profiled
+    main, = [t for t in threads if any(n == "prepare_run" for n, *_ in t)]
+    spans = [sp for t in threads for sp in t]
+    stages = Counter((n, st) for n, _, _, st in spans if n in STAGES)
+    # one interval, two sinks: the trace holds exactly the engine's events
+    assert stages == Counter((e.stage, e.batch) for e in eng.events)
+    if schedule == "algorithm1":
+        assert stages == Counter({(s, i): 1 for s in STAGES
+                                  for i in range(SPAN_STEPS)})
+    device = ("emb_fwd", "dense_fwd", "dense_bwd", "emb_bwd")
+    assert {n for n, *_ in main if n in STAGES} >= set(device)
+    (_, p0, p1, _), = [sp for sp in main if sp[0] == "prepare_run"]
+    assert p1 <= min(a for n, a, _, _ in spans if n in STAGES)
+    assert _nested(main, "loss_sync", "dense_bwd") == SPAN_STEPS
+    assert _nested(main, "step_callback", "emb_bwd") == SPAN_STEPS
+    assert sum(_nested(t, "h2d", "a2a") for t in threads) == SPAN_STEPS
+    waits = [n for n, *_ in main if n in WAITS]
+    assert not [n for t in threads if t is not main
+                for n, *_ in t if n in WAITS]
+    if schedule == "algorithm1":
+        # the main thread joins every host future it consumes
+        assert set(waits) == {"wait_dataload", "wait_a2a", "wait_unique"}
+    else:
+        assert waits == []
+
+
+def test_profiler_session_leaves_math_bit_identical(profiled):
+    _, eng, recs, plain, plain_recs, _ = profiled
+    assert [r["loss"] for r in recs] == [r["loss"] for r in plain_recs]
+    for a, b in zip(jax.tree.leaves(eng.state), jax.tree.leaves(plain.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def scope_names(op_name):
+    """The scopes of an HLO op's ``op_name`` path, each component with
+    its transform wrappers stripped (``transpose(jvp(loss))`` -> loss)."""
+    out = set()
+    for part in op_name.split("/"):
+        while part.endswith(")") and "(" in part:
+            part = part[part.index("(") + 1:-1]
+        out.add(part)
+    return out
+
+
+@pytest.fixture(scope="module")
+def stage_scopes():
+    """Scope names in the compiled HLO metadata of each jitted stage."""
+    import re
+
+    import jax.numpy as jnp
+    eng = _span_engine("algorithm1")
+    eng.run(1)
+    st, nb = eng.state, eng._data_fn(0)
+    dev = {k: jnp.asarray(v) for k, v in nb.items() if k != "weights"}
+    s, first, _ = host_unique_candidates(nb, st.table.master.shape[0])
+    x = eng._j_emb_fwd(st.table.master, dev)
+    dout = eng._j_dense(st.dense, st.table, dev, x, None)
+    lowered = {
+        "emb_fwd": eng._j_emb_fwd.lower(st.table.master, dev),
+        "dense_fwd_bwd": eng._j_dense.lower(st.dense, st.table, dev, x,
+                                            None),
+        "emb_bwd": eng._j_emb_bwd.lower(
+            st.dense, st.dense_opt, st.table, dout, dev, jnp.asarray(s),
+            jnp.asarray(first), apply_sparse=True,
+            slots=st.pending_ids.shape[0] or None),
+        "sparse_apply": eng._j_sparse_apply.lower(
+            st.table, st.pending_ids, st.pending_rows)}
+    out = {}
+    for stage, low in lowered.items():
+        text = low.compile().as_text()
+        out[stage] = set().union(*map(scope_names,
+                                      re.findall(r'op_name="([^"]*)"', text)))
+    return out
+
+
+@pytest.mark.parametrize("scope,stages", [
+    ("input_gather", ("emb_fwd",)),
+    ("blocks", ("dense_fwd_bwd",)),
+    ("loss", ("dense_fwd_bwd",)),
+    ("table_grad", ("emb_bwd",)),
+    ("adamw", ("emb_bwd",)),
+    ("adagrad", ("emb_bwd", "sparse_apply"))])
+def test_layer_scopes_in_stage_metadata(stage_scopes, scope, stages):
+    for stage in stages:
+        assert scope in stage_scopes[stage], (scope, stage)
+    others = set(stage_scopes) - set(stages)
+    assert not [o for o in others if scope in stage_scopes[o]]
+
+
+def _bench_flops():
+    spec = importlib.util.spec_from_file_location(
+        "bench_flops", os.path.join(BENCH, "flops.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("arch", ["hstu-tiny", "hstu-large", "fuxi-large"])
+def test_gr_model_flops_matches_bench_count(arch):
+    cfg = ARCHS[arch]
+    if arch == "hstu-tiny":
+        cfg = reduced(cfg)
+    model = {"block": cfg.gr_block, "d_model": cfg.d_model,
+             "num_heads": cfg.num_heads, "qkv_dim": cfg.qkv_dim,
+             "d_ff": cfg.d_ff, "num_layers": cfg.num_layers,
+             "num_negatives": cfg.num_negatives}
+    lengths = [[2048, 2048, 1500, 7, 0], [1, 300, 0, 0, 0]]
+    flat = [l for row in lengths for l in row]
+    assert gr_model_flops(cfg, lengths) == \
+        _bench_flops().model_flops(model, flat)
+
+
+def test_engine_mfu_counts_the_step_work(monkeypatch):
+    from repro.training import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "device_peak", lambda kind: {"flops": 1.0})
+    eng = _tiny_gr(obs=Obs())
+    for rec in eng.run(3):
+        lengths = np.diff(np.asarray(eng._data_fn(rec["step"])["offsets"]),
+                          axis=-1)
+        assert rec["mfu"] == pytest.approx(
+            gr_model_flops(eng.bundle.cfg, lengths) / rec["step_wall_s"])
 
 
 def test_timeline_report_pure_function_regression():
