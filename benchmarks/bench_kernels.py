@@ -9,9 +9,9 @@ rows recorded into ``BENCH_kernels.json``:
             forward output and q/k/v grads; also records the
             consecutive-duplicate block-index fractions (the DMA-skip
             opportunity the multi-operand gather exploits).
-  neg       fused negative-sampling megakernel: tuned ``rows_per_step``
-            must cut grid steps vs the default at a bit-identical lse
-            (and match the materialized oracle).
+  neg       fused negative-sampling megakernel: the token block sized from
+            the shapes must cut grid steps vs one token a step at a
+            bit-identical lse (and match the materialized oracle).
   scatter   backward embedding grad: the fused sorted-runsum path must
             lower WITHOUT the (T·R, D) row buffer the two-pass oracle
             materializes — checked against compiled memory_analysis()
@@ -42,7 +42,9 @@ from repro.kernels import autotune
 from repro.kernels.jagged_attention import ops as attn_ops
 from repro.kernels.jagged_lookup.kernel import gather_pallas
 from repro.kernels.jagged_lookup.ops import scatter_add_weighted_rows
-from repro.kernels.neg_logits.ops import fused_recall_lse
+from repro.kernels.neg_logits.fused import fwd_pallas, gather_source
+from repro.kernels.neg_logits.ops import (fused_recall_lse,
+                                         prepare_fused_inputs)
 from repro.kernels.neg_logits.ref import fused_recall_lse_ref
 from repro.obs import MetricsRegistry, Tracer
 
@@ -135,7 +137,7 @@ def bench_attn():
 
 
 # ---------------------------------------------------------------------------
-# section 2: fused negative sampling, tuned rows_per_step
+# section 2: fused negative sampling, the shape-sized token block
 # ---------------------------------------------------------------------------
 
 def bench_neg():
@@ -148,41 +150,44 @@ def bench_neg():
     ids = jax.random.randint(ks[3], (T, R), 0, V)
     valid = jnp.arange(T) < T - 5
     dims = {"segment": seg, "R": R, "D": D, "T": T, "expansion": exp}
-    rank0 = autotune.rank_candidates("neg_fused", dims)[0]
-    rps_t = int(rank0["rows_per_step"])
-    if rps_t == 1:
-        rps_t = 4
+    tb = autotune.neg_tokens_per_step(dims)
     kw = dict(segment=seg, tau=0.9, expansion=exp, key=ks[4], valid=valid)
+    o_p, pos_p, ids_p, valid_p, perms, n_seg = prepare_fused_inputs(
+        out, pos, table, ids, segment=seg, expansion=exp, key=ks[4],
+        valid=valid)
+    words, _ = gather_source(table, None, None)
 
-    def lse(rps):
-        return fused_recall_lse(out, pos, table, ids, rows_per_step=rps,
-                                interpret=True, **kw)
+    def lse(tps):
+        return fwd_pallas(o_p, pos_p.reshape(n_seg, seg), words,
+                          ids_p.reshape(-1), valid_p.reshape(n_seg, seg),
+                          perms, segment=seg, R=R, expansion=exp, tau=0.9,
+                          tokens_per_step=tps,
+                          interpret=True).reshape(-1)[:T]
 
-    lse_d, lse_t = lse(1), lse(rps_t)
+    lse_1 = lse(1)
+    lse_b = fused_recall_lse(out, pos, table, ids, interpret=True, **kw)
     ref = fused_recall_lse_ref(out, pos, table, ids, **kw)
-    bit_ok = _bitwise(lse_d, lse_t)
-    _gate("neg_bitwise_rps", bit_ok, f"rps={rps_t} vs 1")
-    oracle_ok = bool(np.allclose(np.asarray(lse_t), np.asarray(ref),
+    bit_ok = _bitwise(lse_1, lse_b)
+    _gate("neg_bitwise_tps", bit_ok, f"tokens_per_step={tb} vs 1")
+    oracle_ok = bool(np.allclose(np.asarray(lse_b), np.asarray(ref),
                                  rtol=2e-5, atol=2e-5))
     _gate("neg_matches_oracle", oracle_ok, "vs fused_recall_lse_ref")
-    steps_d = int(autotune.estimate_cost(
-        "neg_fused", dims, {"rows_per_step": 1})["grid_steps"])
-    steps_t = int(autotune.estimate_cost(
-        "neg_fused", dims, {"rows_per_step": rps_t})["grid_steps"])
-    _gate("neg_fewer_grid_steps", steps_t < steps_d,
-          f"{steps_t} < {steps_d} (rps={rps_t})")
-    us_d = time_fn(lambda: lse(1))
-    us_t = time_fn(lambda: lse(rps_t))
-    emit("kernels/neg/fused_lse", us_t,
-         f"default={us_d:.1f}us steps {steps_d}->{steps_t}")
+    steps_1 = int(autotune.estimate_cost(
+        "neg_fused", dims, {"tokens_per_step": 1})["grid_steps"])
+    steps_b = int(autotune.estimate_cost("neg_fused", dims)["grid_steps"])
+    _gate("neg_fewer_grid_steps", steps_b < steps_1,
+          f"{steps_b} < {steps_1} (tokens_per_step={tb})")
+    us_1 = time_fn(lambda: lse(1))
+    us_b = time_fn(lambda: lse(tb))
+    emit("kernels/neg/fused_lse", us_b,
+         f"one_token={us_1:.1f}us steps {steps_1}->{steps_b}")
     return {
         "regime": "longtail", "T": T, "R": R, "D": D, "segment": seg,
         "expansion": exp,
-        "config_default": {"rows_per_step": 1},
-        "config_tuned": {"rows_per_step": rps_t},
-        "model_ranked_best": dict(rank0),
-        "grid_steps_default": steps_d, "grid_steps_tuned": steps_t,
-        "latency_us_default": us_d, "latency_us_tuned": us_t,
+        "config_one_token": {"tokens_per_step": 1},
+        "config_default": {"tokens_per_step": tb},
+        "grid_steps_one_token": steps_1, "grid_steps_default": steps_b,
+        "latency_us_one_token": us_1, "latency_us_default": us_b,
         "bitwise_identical": bit_ok, "oracle_allclose": oracle_ok,
     }
 

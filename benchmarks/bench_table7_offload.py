@@ -96,8 +96,7 @@ def main():
             "no_TRD_or_TRk_buffer": mem_ok,
             "tuning_config": {
                 "bucket": autotune.shape_bucket(tdims),
-                "rows_per_step": autotune.resolve(
-                    "neg_fused", tdims, "rows_per_step"),
+                "tokens_per_step": autotune.neg_tokens_per_step(tdims),
                 "scatter_impl": autotune.resolve(
                     "neg_fused", tdims, "scatter_impl"),
             },

@@ -82,8 +82,7 @@ def main():
             "expansion": k, "train_step_peak_temp_bytes": peak,
             "tuning_config": {
                 "bucket": autotune.shape_bucket(tdims),
-                "rows_per_step": autotune.resolve(
-                    "neg_fused", tdims, "rows_per_step"),
+                "tokens_per_step": autotune.neg_tokens_per_step(tdims),
                 "scatter_impl": autotune.resolve(
                     "neg_fused", tdims, "scatter_impl"),
             },

@@ -137,13 +137,14 @@ def negative_parity(seed: int, vocab: int, T: int = 1024, R: int = 128,
                     D: int = 1024) -> None:
     import jax
     import jax.numpy as jnp
+    from repro.embedding.tables import shadow_of, shadow_values
     from repro.kernels.neg_logits import fused_recall_lse, fused_recall_lse_ref
 
     ks = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
     out = jax.random.normal(ks[0], (T, D), jnp.bfloat16)
     pos = jax.random.normal(ks[1], (T,))
     master = 0.02 * jax.random.normal(ks[2], (vocab, D))
-    shadow = master.astype(jnp.bfloat16)
+    shadow = shadow_of(master, jnp.bfloat16)      # stored as training does
     ids = jax.random.randint(ks[3], (T, R), 0, vocab)
     valid = jnp.arange(T) < T * 9 // 10
 
@@ -164,7 +165,8 @@ def negative_parity(seed: int, vocab: int, T: int = 1024, R: int = 128,
     gk, lk = jax.jit(jax.grad(kern, argnums=(0, 1, 2), has_aux=True))(
         out, pos, master, shadow, ids)
     gr, lr = jax.jit(jax.grad(ref, argnums=(0, 1, 2), has_aux=True))(
-        out.astype(jnp.float32), pos, shadow.astype(jnp.float32), ids)
+        out.astype(jnp.float32), pos,
+        shadow_values(shadow).astype(jnp.float32), ids)
     check("negatives lse", rel_err(lk, lr), F32_TOL, F32_WHY)
     check("negatives d_out", rel_err(gk[0], gr[0]), BF16_TOL,
           "d_out comes back in out_emb's bf16")

@@ -213,7 +213,7 @@ def shadow_gather(table: jax.Array, shadow: jax.Array,
 
     @jax.custom_vjp
     def _fetch(tbl, ids_):
-        return jnp.take(shadow, ids_, axis=0)
+        return ET.shadow_values(jnp.take(shadow, ids_, axis=0))
 
     def fwd(tbl, ids_):
         return _fetch(tbl, ids_), ids_
@@ -291,7 +291,6 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
                                fetch_dtype=ET.SHADOW_DTYPE,
                                shadow: Optional[jax.Array] = None,
                                impl: Optional[str] = None,
-                               rows_per_step: Optional[int] = None,
                                scatter_impl: Optional[str] = None,
                                interpret: Optional[bool] = None
                                ) -> jax.Array:
@@ -308,9 +307,9 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
     fetch instead (same numerics under the shadow invariant, full
     bandwidth).
 
-    ``rows_per_step`` / ``scatter_impl`` tune the Pallas megakernel's
-    gather batching and backward-scatter schedule (kernels/autotune.py
-    resolves tuned.json defaults when None; ignored by the XLA impl).
+    ``scatter_impl`` tunes the Pallas megakernel's backward-scatter
+    schedule (kernels/autotune.py resolves the tuned.json default when
+    None; ignored by the XLA impl).
     """
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -320,7 +319,6 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
               valid=valid, fetch_dtype=fetch_dtype, gather_table=shadow)
     if impl == "pallas":
         lse = fused_recall_lse(out_emb, pos, table, neg_ids,
-                               rows_per_step=rows_per_step,
                                scatter_impl=scatter_impl,
                                interpret=interpret, **kw)
     elif impl == "xla":

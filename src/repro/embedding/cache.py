@@ -209,7 +209,7 @@ class CachedShadowedTable:
         master = jnp.asarray(wm.reshape(self.rows, D))
         accum = jnp.asarray(wa.reshape(self.rows, D))
         shadow = (None if self.qdtype is None
-                  else master.astype(self.qdtype))
+                  else ET.shadow_of(master, self.qdtype))
         return ET.ShadowedTable(master=master, shadow=shadow, accum=accum)
 
     def publish(self, window: ET.ShadowedTable) -> None:
@@ -409,8 +409,8 @@ class CachedShadowedTable:
         """Land a prepared plan's chunks into the window (device scatter).
 
         Cheap async-dispatched `.at[slots].set` over the chunk-major view;
-        the shadow slice is cast from the spliced master rows, preserving
-        ``shadow == master.astype(qdtype)`` bitwise. The touched slots
+        the shadow slice is made from the spliced master rows, preserving
+        ``shadow == shadow_of(master, qdtype)`` bitwise. The touched slots
         belong to chunks no in-flight batch reads or writes (they were
         just non-resident and everything in flight is pinned), so the
         splice commutes with concurrent sparse landings.
@@ -425,9 +425,11 @@ class CachedShadowedTable:
                  .at[plan.slots].set(plan.accum).reshape(C * R, D))
         shadow = table.shadow
         if shadow is not None:
-            shadow = (shadow.reshape(C, R, D)
-                      .at[plan.slots].set(plan.master.astype(shadow.dtype))
-                      .reshape(C * R, D))
+            row = shadow.shape[1:]
+            shadow = (shadow.reshape(C, R, *row)
+                      .at[plan.slots].set(
+                          ET.shadow_of(plan.master, ET.shadow_dtype(shadow)))
+                      .reshape(C * R, *row))
         return ET.ShadowedTable(master=master, shadow=shadow, accum=accum)
 
     def _mark_rows_dirty_locked(self, uids: Optional[np.ndarray]) -> None:
@@ -508,7 +510,7 @@ class CachedShadowedTable:
         master = jnp.asarray(m[:self.vocab])
         accum = jnp.asarray(a[:self.vocab])
         shadow = (None if self.qdtype is None
-                  else jnp.zeros((0, self.dim), self.qdtype))
+                  else ET.shadow_of(jnp.zeros((0, self.dim)), self.qdtype))
         return ET.ShadowedTable(master=master, shadow=shadow, accum=accum)
 
     def flush(self, window: Optional[ET.ShadowedTable] = None) -> None:

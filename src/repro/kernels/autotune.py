@@ -1,10 +1,12 @@
 """Per-shape-regime autotuner for the repo's Pallas kernels.
 
 The three megakernels (work-list jagged attention, fused negative
-sampling, sorted-runsum scatter) expose schedule knobs — ``rows_per_step``
-for the neg/lookup gathers, ``pairs_per_step`` for the attention
-work-list, the backward-scatter ``scatter_impl`` — and this module owns
-everything around picking their values:
+sampling, sorted-runsum scatter) expose schedule knobs —
+``rows_per_step`` for the lookup gather, ``pairs_per_step`` for the
+attention work-list, the backward-scatter ``scatter_impl`` — and this
+module owns everything around picking their values (the negative kernel's
+token block is no knob: :func:`neg_tokens_per_step` sizes it from the
+shapes):
 
 * **candidate enumeration** from divisibility/alignment constraints and a
   coarse VMEM budget (``enumerate_candidates``);
@@ -41,8 +43,8 @@ from jax.experimental import pallas as pl
 
 __all__ = [
     "DEFAULTS", "CANDIDATES", "shape_bucket", "knob_valid",
-    "enumerate_candidates", "estimate_cost", "rank_candidates",
-    "pallas_cost", "TunedStore", "default_path", "resolve",
+    "enumerate_candidates", "neg_tokens_per_step", "estimate_cost",
+    "rank_candidates", "pallas_cost", "TunedStore", "default_path", "resolve",
     "measure", "sweep",
 ]
 
@@ -61,7 +63,7 @@ VMEM_BUDGET = 12 * 2 ** 20  # usable VMEM per kernel (conservative)
 
 DEFAULTS: Dict[str, Dict[str, Any]] = {
     # fused negative-sampling megakernel (kernels/neg_logits/fused.py)
-    "neg_fused": {"rows_per_step": 1, "scatter_impl": "fused"},
+    "neg_fused": {"scatter_impl": "fused"},
     # work-list jagged attention (kernels/jagged_attention)
     "attn_worklist": {"pairs_per_step": 1},
     # packed-index embedding gather (kernels/jagged_lookup)
@@ -69,8 +71,7 @@ DEFAULTS: Dict[str, Dict[str, Any]] = {
 }
 
 CANDIDATES: Dict[str, Dict[str, Tuple[Any, ...]]] = {
-    "neg_fused": {"rows_per_step": (1, 2, 4, 8, 16),
-                  "scatter_impl": ("fused", "two_pass")},
+    "neg_fused": {"scatter_impl": ("fused", "two_pass")},
     "attn_worklist": {"pairs_per_step": (1, 2, 4)},
     "lookup_gather": {"rows_per_step": (1, 2, 4, 8)},
 }
@@ -105,12 +106,6 @@ def knob_valid(kernel: str, dims: Mapping[str, Any], knob: str,
     shapes can never produce an invalid kernel configuration.
     """
     if kernel == "neg_fused":
-        if knob == "rows_per_step":
-            R = int(dims.get("R", 1))
-            seg_r = int(dims.get("segment", 128)) * R
-            return (isinstance(value, int) and not isinstance(value, bool)
-                    and 1 <= value <= seg_r and seg_r % value == 0
-                    and (R % value == 0 or value % R == 0))
         if knob == "scatter_impl":
             return value in ("fused", "two_pass")
     elif kernel == "attn_worklist":
@@ -131,9 +126,13 @@ def _vmem_bytes(kernel: str, dims: Mapping[str, Any],
     if kernel == "neg_fused":
         seg = int(dims.get("segment", 128))
         R = int(dims.get("R", 1))
-        rps = int(config.get("rows_per_step", 1))
-        # o block + rps table rows (×2 pipeline) + logits/weights/do scratch
-        return 4 * (seg * D + 2 * rps * D + 3 * seg * R + seg * D)
+        tb = int(config.get("tokens_per_step", 1))
+        row_bytes = D * int(dims.get("itemsize", 4))
+        # the backward, the larger of the two: the double-buffered row slab
+        # + o and d_out blocks (×2 pipeline) + logit/weight scratch and the
+        # w block (×2) + one token's rows widened to fp32 and their product
+        return (2 * tb * R * row_bytes
+                + 4 * (4 * seg * D + 4 * seg * R + 2 * R * D))
     if kernel == "attn_worklist":
         blk = int(dims.get("block", 128))
         H = int(dims.get("H", 1))
@@ -164,6 +163,24 @@ def enumerate_candidates(kernel: str,
     return out
 
 
+# token blocks of the fused negative kernel; its time follows how fast the
+# row DMAs are started, not the block (a v5e read the same time at 4, 8 and
+# 16 tokens)
+NEG_TOKEN_BLOCKS = (1, 2, 4, 8, 16)
+
+
+def neg_tokens_per_step(dims: Mapping[str, Any]) -> int:
+    """Tokens a grid step of the fused negative kernel covers: the largest
+    of :data:`NEG_TOKEN_BLOCKS` that divides the segment and whose row
+    slab, with the rest of the backward's buffers, fits
+    :data:`VMEM_BUDGET`."""
+    seg = int(dims.get("segment", 128))
+    fits = [tb for tb in NEG_TOKEN_BLOCKS
+            if seg % tb == 0 and _vmem_bytes(
+                "neg_fused", dims, {"tokens_per_step": tb}) <= VMEM_BUDGET]
+    return max(fits, default=1)
+
+
 # ---------------------------------------------------------------------------
 # cost model — shared by candidate ranking and pl.CostEstimate wiring
 # ---------------------------------------------------------------------------
@@ -185,16 +202,17 @@ def estimate_cost(kernel: str, dims: Mapping[str, Any],
         T = int(dims.get("T", seg))
         k_exp = int(dims.get("expansion", 1))
         n_seg = -(-T // seg)
-        rps = int(config.get("rows_per_step", 1))
+        tb = int(config.get("tokens_per_step") or neg_tokens_per_step(dims))
+        itemsize = int(dims.get("itemsize", 4))
         pairs = n_seg * seg * R
         flops = 2.0 * pairs * D                       # per-slot dot
         flops += 2.0 * n_seg * (k_exp - 1) * seg * seg * R  # sharing matmuls
         flops += 3.0 * n_seg * seg * (1 + k_exp * R)  # logsumexp adds
         transc = 1.0 * n_seg * seg * (1 + k_exp * R)  # exp in logsumexp
-        bytes_ = 4.0 * (pairs * D      # gathered table rows
-                        + n_seg * seg * D   # o blocks
-                        + n_seg * seg * 3)  # pos/valid/lse blocks
-        steps = n_seg * (seg * R // max(rps, 1))
+        bytes_ = (itemsize * pairs * D    # gathered table rows, one DMA each
+                  + 4.0 * (n_seg * seg * D   # o blocks
+                           + n_seg * seg * 3))  # pos/valid/lse blocks
+        steps = n_seg * (seg // max(tb, 1))
     elif kernel == "attn_worklist":
         blk = int(dims.get("block", 128))
         H = int(dims.get("H", 1))

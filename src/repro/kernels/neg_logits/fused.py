@@ -2,11 +2,10 @@
 
 One pass fuses the four stages the paper keeps separate (§4.3.1-§4.3.3):
 
-  gather    — scalar-prefetched negative ids drive the table BlockSpec
-              ``index_map`` (the ``jagged_lookup`` technique), so each grid
-              step DMAs the HBM tiles of ``rows_per_step`` *live* embedding
-              rows into VMEM; the (T, R, D) negative tensor never exists
-              anywhere.
+  gather    — the kernel DMAs each negative's table row from HBM into a
+              VMEM slab itself, one row copy per (token, slot), driven by
+              the scalar-prefetched ids; the (T, R, D) negative tensor
+              never exists anywhere.
   dequant   — rows stored (or emulated-fetched) bf16/fp16 are widened to
               fp32 in VMEM right before the dot (§4.3.2).
   sharing   — intra-batch logit sharing (§4.3.3) is a deterministic
@@ -17,31 +16,42 @@ One pass fuses the four stages the paper keeps separate (§4.3.1-§4.3.3):
               [pos | own negatives | shared negatives] is produced directly;
               HBM output is just (T,) plus the tiny per-segment blocks.
 
-Grid layout: ``(n_seg, segment·R / rows_per_step)`` — the outer dim walks
-fixed-size segments of packed valid positions, the inner dim walks that
-segment's (token, slot) pairs ``rows_per_step`` gathered rows at a time
-(the autotunable knob; the table rides in once per slot with its own
-window). A table row is reached through the block of its HBM tile
-(``jagged_lookup.kernel.row_tile`` rows, the chip's tiling unit) and picked
-out in VMEM. Per-slot logits are placed into the token's (1, R) logit row
-with lane selects and stored once per step. Per-slot arithmetic keeps the
-exact rps=1 op order (each slot's dot is its own reduction), so every
-legal rows_per_step is bitwise-identical. Output blocks are indexed by the
-outer dim only, so they stay VMEM-resident across the inner sweep and are
-flushed once per segment (the standard inner-accumulation pattern).
-Per-token vectors (positive logit, validity, lse) travel as (seg, 1)
-columns so they line up with the token-major logit rows.
+The gather source is a ``(V, 1, W)`` array of 32-bit words in HBM
+(``memory_space=pltpu.HBM``: the kernel copies from it itself), chosen by
+:func:`gather_source`: the persistent bf16 shadow where it is stored packed
+two elements to a word (``W = D/2``, the layout of ``embedding.tables``),
+else the fp32 master's rows (``W = D``) rounded in VMEM. The chip cannot
+copy one row of a ``(V, D)`` array — its HBM tiles hold 8 rows, and a
+16-bit row shares its words with the next — but with a unit second-minor
+dim each row is a contiguous ``W``-word run, so a one-row copy moves
+exactly the row's bytes (2 KiB for a packed bf16 row at D = 1024).
+
+Grid layout: ``(n_seg, segment / tb)`` — the outer dim walks fixed-size
+segments of packed valid positions, the inner dim walks that segment in
+blocks of ``tb`` tokens (``tokens_per_step``: by default the largest block
+whose slab fits the VMEM budget, ``autotune.neg_tokens_per_step``). Each
+grid step starts the ``tb·R`` row copies of the *next* block into the
+other half of a double-buffered ``(2, tb·R, 1, W)`` slab (one DMA
+semaphore per half), waits on the current half, then computes each
+token's ``(R, D)`` rows against its broadcast ``(1, D)`` vector, reduced
+over lanes to an ``(R, 1)`` logit column. Columns land in a slot-major
+``(R, segment)`` logit scratch that is transposed once per segment.
+A token's arithmetic does not depend on ``tb``, so every legal value is
+bitwise-identical. Output blocks are indexed by the outer dim only, so
+they stay VMEM-resident across the inner sweep and are flushed once per
+segment. Per-token vectors (positive logit, validity, lse) travel as
+(seg, 1) columns so they line up with the token-major logit rows.
 
 Backward is the same sweep twice inside one kernel (grid
-``(n_seg, 2·segment·R / rows_per_step)``): phase 0 re-gathers and rebuilds
-the segment logits, the phase boundary turns them into softmax weights
-(folding the shared-logit contributions back onto their source rows with
-the transposed permutation), phase 1 re-gathers to accumulate d_out — one
-weight-row load per step, slot accumulation kept sequential for
-bitwise-stable grads. The table gradient leaves the kernel as
-per-(token, slot) *weights* only — the ops wrapper reduces them through
-the fused weighted scatter (grad rows generated in sorted-id order inside
-that kernel), never a dense (T·R, D) row buffer.
+``(n_seg, 2·segment / tb)``), both sweeps through the same slab gather:
+phase 0 rebuilds the segment logits, the phase boundary turns them into
+softmax weights (folding the shared-logit contributions back onto their
+source rows with the transposed permutation), phase 1 forms each token's
+``d_out = Σ_r w[t, r]·row_r / τ`` as one broadcast-multiply and sublane
+reduction over its ``(R, D)`` rows. The table gradient leaves the kernel
+as per-(token, slot) *weights* only — the ops wrapper reduces them
+through the fused weighted scatter (grad rows generated in sorted-id
+order inside that kernel), never a dense (T·R, D) row buffer.
 
 The negative ids are scalar-prefetched into SMEM, which holds 1 MiB, so a
 batch is processed in groups of whole segments of at most
@@ -50,14 +60,16 @@ batch is processed in groups of whole segments of at most
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.embedding import tables as ET
 from repro.kernels import autotune
-from repro.kernels.jagged_lookup.kernel import pick_row, row_tile
 
 # Sentinel for masked (invalid-token) pool logits: large-negative instead of
 # -inf so logsumexp arithmetic stays NaN-free even if a whole row masks out.
@@ -69,13 +81,37 @@ IDS_PER_CALL = 1 << 16
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _dequant(tile, fetch_dtype):
-    if fetch_dtype is not None and tile.dtype != jnp.dtype(fetch_dtype):
-        # fp32-stored master table with a bf16/fp16 *fetch*: round in VMEM
-        # so numerics match a half-stored table (§4.3.2) without ever
-        # casting the (V, D) table in HBM.
-        tile = tile.astype(fetch_dtype)
-    return tile.astype(jnp.float32)
+def gather_source(table: jax.Array, shadow, fetch_dtype):
+    """What the kernels DMA rows from: ``(words, fetch_dtype)``.
+
+    ``words`` is a ``(V, 1, W)`` view of 32-bit words, one row a contiguous
+    run. A packed shadow (``embedding.tables``) is viewed as it is stored,
+    two bf16 elements a word (``W = D/2``), and needs no rounding. Any other
+    source is the master's fp32 rows (``W = D``, a free reshape of an fp32
+    master), rounded in VMEM to ``fetch_dtype``; an unpacked shadow holds
+    exactly ``master.astype(its dtype)``, so its rounding stands in for it
+    and no step copies the table to another width.
+    """
+    if shadow is not None and ET.is_packed(shadow):
+        return ET.packed_row_words(shadow), None
+    if shadow is not None:
+        fetch_dtype = shadow.dtype
+    words = table.astype(jnp.float32).reshape(table.shape[0], 1, -1)
+    return words, fetch_dtype
+
+
+def _row_parts(words, fetch_dtype):
+    """(R, W) words of a token's rows → [(lane offset, (R, w) fp32 rows)]:
+    the two halves of packed bf16 rows, or whole fp32 rows."""
+    if ET.is_packed(words):
+        lo, hi = ET.unpack_halves(words)
+        return [(0, lo), (words.shape[1], hi)]
+    if fetch_dtype is not None:
+        # fp32 master rows with a bf16/fp16 *fetch*: round in VMEM so
+        # numerics match a half-stored table (§4.3.2) without ever casting
+        # the (V, D) table in HBM.
+        words = words.astype(fetch_dtype).astype(jnp.float32)
+    return [(0, words)]
 
 
 def _share_terms(logits, valid_col, perm_ref, expansion, segment):
@@ -95,51 +131,82 @@ def _share_terms(logits, valid_col, perm_ref, expansion, segment):
                                preferred_element_type=jnp.float32)
 
 
-def check_rows_per_step(rows_per_step: int, segment: int, R: int) -> int:
-    """Legal rows_per_step: divides segment·R and aligns to token rows
-    (divides R, or is a whole multiple of R). Returns it validated."""
-    rps = int(rows_per_step)
-    seg_r = segment * R
-    if not (1 <= rps <= seg_r and seg_r % rps == 0
-            and (R % rps == 0 or rps % R == 0)):
+def check_tokens_per_step(tokens_per_step: Optional[int], segment: int,
+                          R: int, D: int, words: jax.Array) -> int:
+    """Tokens a grid step covers: ``tokens_per_step``, which must divide the
+    segment, or by default the largest block whose slab fits the VMEM
+    budget. Every legal value gives bitwise the same results."""
+    if tokens_per_step is None:
+        return autotune.neg_tokens_per_step(
+            {"segment": segment, "R": R, "D": D,
+             "itemsize": words.shape[2] * 4 // D})
+    tb = int(tokens_per_step)
+    if not (1 <= tb <= segment and segment % tb == 0):
         raise ValueError(
-            f"rows_per_step={rps} invalid for segment={segment}, R={R}")
-    return rps
+            f"tokens_per_step={tb} invalid for segment={segment}")
+    return tb
 
 
-def _slot_rows(ids_ref, tbl_refs, base, sub, fetch_dtype):
-    """The (1, D) fp32 rows of this step's slots, picked from their tiles."""
-    return [pick_row(_dequant(t[...], fetch_dtype), ids_ref[base + u] % sub)
-            for u, t in enumerate(tbl_refs)]
+def _start_rows(ids_ref, words_ref, slab, sem, block, slot, n):
+    """Start the ``n`` row copies of token block ``block`` into slab half
+    ``slot``, all signalling that half's semaphore."""
+    unroll = math.gcd(n, 8)
+
+    def body(i, carry):
+        for u in range(unroll):
+            k = i * unroll + u
+            pltpu.make_async_copy(words_ref.at[pl.ds(ids_ref[block * n + k],
+                                                     1)],
+                                  slab.at[slot, pl.ds(k, 1)],
+                                  sem.at[slot]).start()
+        return carry
+
+    jax.lax.fori_loop(0, n // unroll, body, 0)
 
 
-def _store_logits(acc_ref, o_ref, rows, jj, *, R, inv_tau):
-    """Per-slot logits for inner step jj into the (seg, R) logit scratch.
-    Each slot's dot is its own (1, D) reduction — the exact rps=1 op
-    order — placed into its token's logit row by a lane select."""
-    rps = len(rows)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+def _gather_block(ids_ref, words_ref, slab, sem, step, last, block_of):
+    """Double-buffered slab gather for grid step ``step`` (counted over the
+    whole call) of a sweep whose token block at step s is ``block_of(s)``:
+    prime the first block, start the next one into the other half, wait
+    for this one. Returns the slab half that holds this step's rows."""
+    n = slab.shape[1]
+    slot = step % 2
 
-    def logit(o_t, row):
-        return jnp.sum(o_t * row, axis=1, keepdims=True) * inv_tau  # (1, 1)
+    @pl.when(step == 0)
+    def _prime():
+        _start_rows(ids_ref, words_ref, slab, sem, block_of(step), slot, n)
 
-    if rps <= R:                        # rps slots inside one token row
-        t = (jj * rps) // R
-        r0 = (jj * rps) % R
-        o_t = o_ref[pl.ds(t, 1), :]
-        blk = acc_ref[pl.ds(t, 1), :]
-        for u in range(rps):
-            blk = jnp.where(lane == r0 + u, logit(o_t, rows[u]), blk)
-        acc_ref[pl.ds(t, 1), :] = blk
-        return
-    m = rps // R                        # whole tokens per step
-    for g in range(m):
-        t = jj * m + g
-        o_t = o_ref[pl.ds(t, 1), :]
-        blk = jnp.zeros((1, R), jnp.float32)
-        for s in range(R):
-            blk = jnp.where(lane == s, logit(o_t, rows[g * R + s]), blk)
-        acc_ref[pl.ds(t, 1), :] = blk
+    @pl.when(step < last)
+    def _prefetch():
+        _start_rows(ids_ref, words_ref, slab, sem, block_of(step + 1),
+                    1 - slot, n)
+
+    # one wait for the whole half: its byte count is the sum of the rows'
+    pltpu.make_async_copy(slab.at[slot], slab.at[slot], sem.at[slot]).wait()
+    return slot
+
+
+def _token_parts(slab, slot, g, R, fetch_dtype):
+    words = slab[slot, pl.ds(g * R, R)]                      # (R, 1, W)
+    return _row_parts(words.reshape(R, words.shape[-1]), fetch_dtype)
+
+
+def _block_logits(o_ref, slab, slot, lt_ref, b, *, tb, R, inv_tau,
+                  fetch_dtype):
+    """Logit columns of token block ``b`` into the (R, seg) scratch: each
+    token's rows times its broadcast vector, reduced over lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, lt_ref.shape, 1)
+
+    def token(g, lt):
+        t = b * tb + g
+        prod = None
+        for off, rows in _token_parts(slab, slot, g, R, fetch_dtype):
+            p = rows * o_ref[pl.ds(t, 1), pl.ds(off, rows.shape[1])]
+            prod = p if prod is None else prod + p
+        col = jnp.sum(prod, axis=1, keepdims=True) * inv_tau   # (R, 1)
+        return jnp.where(lane == t, col, lt)
+
+    lt_ref[...] = jax.lax.fori_loop(0, tb, token, lt_ref[...])
 
 
 def _lse_cols(cols):
@@ -173,34 +240,37 @@ def _map_groups(call, n_seg: int, seg_r: int, args):
     return jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), outs)
 
 
-def _tbl_specs(table, rps, seg_r, sub, inner):
-    """One (sub, D) table window per slot, at the tile of its id. ``inner``
-    maps the grid's inner index to the sweep step."""
-    return [pl.BlockSpec(
-        (sub, table.shape[1]),
-        lambda si, j, ids, u=u: (ids[si * seg_r + inner(j) * rps + u] // sub,
-                                 0))
-        for u in range(rps)]
+def _slab_scratch(words, tb, R):
+    return [pltpu.VMEM((2, tb * R, 1, words.shape[2]), words.dtype),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+def _cost(segment, R, D, Tp, expansion, tb, words):
+    return autotune.estimate_cost(
+        "neg_fused",
+        {"segment": segment, "R": R, "D": D, "T": Tp, "expansion": expansion,
+         "itemsize": words.shape[2] * 4 // D},
+        {"tokens_per_step": tb})
 
 
 # --------------------------------------------------------------------------
 # forward: gather + dequant + share + logsumexp
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(ids_ref, o_ref, *refs, segment, R, rps, sub, expansion,
+def _fwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, perm_ref,
+                lse_ref, lt_ref, slab, sem, *, segment, R, tb, expansion,
                 inv_tau, fetch_dtype):
-    tbl_refs = refs[:rps]
-    pos_ref, valid_ref, perm_ref, lse_ref, acc_ref = refs[rps:rps + 5]
-    si, j = pl.program_id(0), pl.program_id(1)
-    G = segment * R // rps
+    si, b = pl.program_id(0), pl.program_id(1)
+    nb = segment // tb
+    step = si * nb + b
+    slot = _gather_block(ids_ref, words_ref, slab, sem, step,
+                         pl.num_programs(0) * nb - 1, lambda s: s)
+    _block_logits(o_ref, slab, slot, lt_ref, b, tb=tb, R=R, inv_tau=inv_tau,
+                  fetch_dtype=fetch_dtype)
 
-    rows = _slot_rows(ids_ref, tbl_refs, si * segment * R + j * rps, sub,
-                      fetch_dtype)
-    _store_logits(acc_ref, o_ref, rows, j, R=R, inv_tau=inv_tau)
-
-    @pl.when(j == G - 1)
+    @pl.when(b == nb - 1)
     def _finalize():
-        logits = acc_ref[...]                               # (seg, R)
+        logits = lt_ref[...].T                              # (seg, R)
         cols = [pos_ref[0], logits]                         # (seg, 1) pos
         cols += [aux for _, aux in _share_terms(logits, valid_ref[0],
                                                 perm_ref, expansion,
@@ -208,52 +278,48 @@ def _fwd_kernel(ids_ref, o_ref, *refs, segment, R, rps, sub, expansion,
         lse_ref[0] = _lse_cols(cols)
 
 
-def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, table: jax.Array,
+def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                ids_flat: jax.Array, valid2d: jax.Array, perms: jax.Array, *,
                segment: int, R: int, expansion: int, tau: float,
-               fetch_dtype=None, rows_per_step: int = 1,
+               fetch_dtype=None, tokens_per_step: Optional[int] = None,
                interpret: bool = False) -> jax.Array:
-    """out_emb (Tp, D) · ids_flat (Tp·R,) → per-token lse (n_seg, segment)."""
+    """out_emb (Tp, D) · ids_flat (Tp·R,) → per-token lse (n_seg, segment).
+    ``words`` and ``fetch_dtype`` are as :func:`gather_source` gives them."""
     Tp, D = out_emb.shape
     n_seg = Tp // segment
-    seg_r = segment * R
-    rps = check_rows_per_step(rows_per_step, segment, R)
-    G = seg_r // rps
-    sub = row_tile(table.dtype)
-    col = pl.BlockSpec((1, segment, 1), lambda si, j, ids: (si, 0, 0))
-    cost = autotune.estimate_cost(
-        "neg_fused",
-        {"segment": segment, "R": R, "D": D, "T": Tp, "expansion": expansion},
-        {"rows_per_step": rps})
+    tb = check_tokens_per_step(tokens_per_step, segment, R, D, words)
+    col = pl.BlockSpec((1, segment, 1), lambda si, b, ids: (si, 0, 0))
+    cost = _cost(segment, R, D, Tp, expansion, tb, words)
 
     def call(o, pos3, ids, valid3, pm):
         ns = o.shape[0] // segment
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, segment=segment, R=R, rps=rps,
-                              sub=sub, expansion=expansion,
-                              inv_tau=1.0 / tau, fetch_dtype=fetch_dtype),
+            functools.partial(_fwd_kernel, segment=segment, R=R, tb=tb,
+                              expansion=expansion, inv_tau=1.0 / tau,
+                              fetch_dtype=fetch_dtype),
             name="neg_fused_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(ns, G),
+                grid=(ns, segment // tb),
                 in_specs=[
-                    pl.BlockSpec((segment, D), lambda si, j, ids: (si, 0)),
-                    *_tbl_specs(table, rps, seg_r, sub, lambda j: j),
+                    pl.BlockSpec((segment, D), lambda si, b, ids: (si, 0)),
+                    pl.BlockSpec(memory_space=pltpu.HBM),
                     col, col,
                     pl.BlockSpec((1, pm.shape[1], segment),
-                                 lambda si, j, ids: (si, 0, 0)),
+                                 lambda si, b, ids: (si, 0, 0)),
                 ],
                 out_specs=col,
-                scratch_shapes=[pltpu.VMEM((segment, R), jnp.float32)],
+                scratch_shapes=[pltpu.VMEM((R, segment), jnp.float32),
+                                *_slab_scratch(words, tb, R)],
             ),
             out_shape=jax.ShapeDtypeStruct((ns, segment, 1), jnp.float32),
             interpret=interpret,
             **autotune.pallas_cost(**{k: cost[k] for k in
                                       ("flops", "bytes_accessed",
                                        "transcendentals")}),
-        )(ids, o, *([table] * rps), pos3, valid3, pm)
+        )(ids, o, words, pos3, valid3, pm)
 
-    lse = _map_groups(call, n_seg, seg_r,
+    lse = _map_groups(call, n_seg, segment * R,
                       (out_emb.astype(jnp.float32),
                        pos_logit2d.reshape(n_seg, segment, 1), ids_flat,
                        valid2d.reshape(n_seg, segment, 1), perms))
@@ -262,32 +328,32 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, table: jax.Array,
 
 # --------------------------------------------------------------------------
 # backward: two-phase sweep in one kernel
-#   phase 0 (j < G)    re-gather → rebuild segment logits
-#   boundary (j == G)  logits → softmax weights w (sharing transposed
-#                      back onto source rows), d_pos
-#   phase 1 (j ≥ G)    re-gather → accumulate d_out from w (one weight-row
-#                      load per step, sequential slot accumulation)
+#   phase 0 (b < nb)    gather → rebuild segment logits
+#   boundary (b == nb)  logits → softmax weights w (sharing transposed
+#                       back onto source rows), d_pos
+#   phase 1 (b ≥ nb)    gather → d_out of each token from its weights
 # --------------------------------------------------------------------------
 
-def _bwd_kernel(ids_ref, o_ref, *refs, segment, R, rps, sub, expansion,
-                inv_tau, fetch_dtype):
-    tbl_refs = refs[:rps]
-    pos_ref, valid_ref, lse_ref, g_ref, perm_ref = refs[rps:rps + 5]
-    w_ref, dout_ref, dpos_ref = refs[rps + 5:rps + 8]
-    acc_ref, w_acc, do_acc = refs[rps + 8:rps + 11]
+def _bwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, lse_ref,
+                g_ref, perm_ref, w_ref, dout_ref, dpos_ref, lt_ref, wt_ref,
+                slab, sem, *, segment, R, tb, expansion, inv_tau,
+                fetch_dtype):
     si, j = pl.program_id(0), pl.program_id(1)
-    G = segment * R // rps
-    jj = j % G
-    rows = _slot_rows(ids_ref, tbl_refs, si * segment * R + jj * rps, sub,
-                      fetch_dtype)
+    nb = segment // tb
+    step = si * 2 * nb + j
+    slot = _gather_block(
+        ids_ref, words_ref, slab, sem, step, pl.num_programs(0) * 2 * nb - 1,
+        lambda s: (s // (2 * nb)) * nb + s % nb)
+    b = j % nb
 
-    @pl.when(j < G)
+    @pl.when(j < nb)
     def _rebuild():
-        _store_logits(acc_ref, o_ref, rows, jj, R=R, inv_tau=inv_tau)
+        _block_logits(o_ref, slab, slot, lt_ref, b, tb=tb, R=R,
+                      inv_tau=inv_tau, fetch_dtype=fetch_dtype)
 
-    @pl.when(j == G)
+    @pl.when(j == nb)
     def _weights():
-        logits = acc_ref[...]                               # (seg, R)
+        logits = lt_ref[...].T                              # (seg, R)
         pos, lse, g = pos_ref[0], lse_ref[0], g_ref[0]      # (seg, 1) each
         # d lse / d logit = softmax prob; scale by upstream g per consumer.
         w = g * jnp.exp(logits - lse)
@@ -298,70 +364,57 @@ def _bwd_kernel(ids_ref, o_ref, *refs, segment, R, rps, sub, expansion,
             # each consumer's prob mass back to its source row.
             w = w + jax.lax.dot(p_t, p_aux, precision=_HIGHEST,
                                 preferred_element_type=jnp.float32)
-        w_acc[...] = w
-        do_acc[...] = jnp.zeros_like(do_acc)
+        w_ref[0] = w
+        wt_ref[...] = w.T
         dpos_ref[0] = g * jnp.exp(pos - lse)
 
-    @pl.when(j >= G)
-    def _accum_dout():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+    @pl.when(j >= nb)
+    def _dout():
+        wt = wt_ref[...]                                    # (R, seg)
+        lane = jax.lax.broadcasted_iota(jnp.int32, wt.shape, 1)
 
-        def accum(t, r0, slot_rows):
-            wv = w_acc[pl.ds(t, 1), :]                      # (1, R)
-            cur = do_acc[pl.ds(t, 1), :]
-            for u, row in enumerate(slot_rows):
-                w_u = jnp.sum(jnp.where(lane == r0 + u, wv, 0.0), axis=1,
-                              keepdims=True)                # (1, 1)
-                cur = cur + w_u * row * inv_tau
-            do_acc[pl.ds(t, 1), :] = cur
+        def token(gi, carry):
+            t = b * tb + gi
+            w_col = jnp.sum(jnp.where(lane == t, wt, 0.0), axis=1,
+                            keepdims=True)                  # (R, 1)
+            for off, rows in _token_parts(slab, slot, gi, R,
+                                          fetch_dtype):
+                dout_ref[pl.ds(t, 1), pl.ds(off, rows.shape[1])] = (
+                    jnp.sum(rows * w_col, axis=0, keepdims=True) * inv_tau)
+            return carry
 
-        if rps <= R:
-            accum((jj * rps) // R, (jj * rps) % R, rows)
-        else:
-            m = rps // R
-            for g_ in range(m):
-                accum(jj * m + g_, 0, rows[g_ * R:(g_ + 1) * R])
-
-    @pl.when(j == 2 * G - 1)
-    def _flush():
-        w_ref[0] = w_acc[...]
-        dout_ref[...] = do_acc[...].astype(dout_ref.dtype)
+        jax.lax.fori_loop(0, tb, token, 0)
 
 
-def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, table: jax.Array,
+def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                ids_flat: jax.Array, valid2d: jax.Array, perms: jax.Array,
                lse2d: jax.Array, g2d: jax.Array, *, segment: int, R: int,
                expansion: int, tau: float, fetch_dtype=None,
-               rows_per_step: int = 1, interpret: bool = False):
+               tokens_per_step: Optional[int] = None,
+               interpret: bool = False):
     """→ (w (n_seg, seg, R) softmax weights·g, d_out (Tp, D) fp32,
          d_pos (n_seg, seg) fp32). Table grads are finished by the caller
     via the fused weighted scatter (sparse (id, w·o) pairs)."""
     Tp, D = out_emb.shape
     n_seg = Tp // segment
-    seg_r = segment * R
-    rps = check_rows_per_step(rows_per_step, segment, R)
-    G = seg_r // rps
-    sub = row_tile(table.dtype)
+    tb = check_tokens_per_step(tokens_per_step, segment, R, D, words)
     col = pl.BlockSpec((1, segment, 1), lambda si, j, ids: (si, 0, 0))
     rowblk = pl.BlockSpec((segment, D), lambda si, j, ids: (si, 0))
-    cost = autotune.estimate_cost(
-        "neg_fused",
-        {"segment": segment, "R": R, "D": D, "T": Tp, "expansion": expansion},
-        {"rows_per_step": rps})
+    cost = _cost(segment, R, D, Tp, expansion, tb, words)
 
     def call(o, pos3, ids, valid3, lse3, g3, pm):
         ns = o.shape[0] // segment
         return pl.pallas_call(
-            functools.partial(_bwd_kernel, segment=segment, R=R, rps=rps,
-                              sub=sub, expansion=expansion,
-                              inv_tau=1.0 / tau, fetch_dtype=fetch_dtype),
+            functools.partial(_bwd_kernel, segment=segment, R=R, tb=tb,
+                              expansion=expansion, inv_tau=1.0 / tau,
+                              fetch_dtype=fetch_dtype),
             name="neg_fused_bwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(ns, 2 * G),
+                grid=(ns, 2 * (segment // tb)),
                 in_specs=[
                     rowblk,
-                    *_tbl_specs(table, rps, seg_r, sub, lambda j: j % G),
+                    pl.BlockSpec(memory_space=pltpu.HBM),
                     col, col, col, col,
                     pl.BlockSpec((1, pm.shape[1], segment),
                                  lambda si, j, ids: (si, 0, 0)),
@@ -372,23 +425,24 @@ def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, table: jax.Array,
                     rowblk,
                     col,
                 ],
-                scratch_shapes=[pltpu.VMEM((segment, R), jnp.float32),
-                                pltpu.VMEM((segment, R), jnp.float32),
-                                pltpu.VMEM((segment, D), jnp.float32)],
+                scratch_shapes=[pltpu.VMEM((R, segment), jnp.float32),
+                                pltpu.VMEM((R, segment), jnp.float32),
+                                *_slab_scratch(words, tb, R)],
             ),
             out_shape=[jax.ShapeDtypeStruct((ns, segment, R), jnp.float32),
                        jax.ShapeDtypeStruct((ns * segment, D), jnp.float32),
                        jax.ShapeDtypeStruct((ns, segment, 1), jnp.float32)],
             interpret=interpret,
+            # two row sweeps
             **autotune.pallas_cost(
                 flops=2 * cost["flops"],
                 bytes_accessed=2 * cost["bytes_accessed"],
                 transcendentals=2 * cost["transcendentals"]),
-        )(ids, o, *([table] * rps), pos3, valid3, lse3, g3, pm)
+        )(ids, o, words, pos3, valid3, lse3, g3, pm)
 
     col3 = lambda x: x.reshape(n_seg, segment, 1)
     w, dout, dpos = _map_groups(
-        call, n_seg, seg_r,
+        call, n_seg, segment * R,
         (out_emb.astype(jnp.float32), col3(pos_logit2d), ids_flat,
          col3(valid2d), col3(lse2d), col3(g2d), perms))
     return w, dout, dpos.reshape(n_seg, segment)

@@ -14,10 +14,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.embedding import tables as ET
 from repro.kernels import autotune
 from repro.kernels.jagged_lookup.ops import scatter_add_weighted_rows
 from repro.kernels.neg_logits import fused as F
 from repro.kernels.neg_logits import kernel as K
+from repro.obs.metrics import KERNEL_METRICS
 
 
 def default_interpret() -> bool:
@@ -123,54 +125,61 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
                      expansion: int = 1, key: Optional[jax.Array] = None,
                      valid: Optional[jax.Array] = None, fetch_dtype=None,
                      gather_table: Optional[jax.Array] = None,
-                     rows_per_step: Optional[int] = None,
                      scatter_impl: Optional[str] = None,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Per-token logsumexp over [pos | R negatives | (k−1)·R shared] (Eq. 2).
 
     out_emb (T, D), pos_logit (T,), table (V, D) — possibly stored
     fp16/bf16 — neg_ids (T, R) int32. Neither the (T, R, D) negative
-    embeddings nor the (T, R·k) expanded logits ever exist in HBM: rows are
-    gathered segment-by-segment straight into VMEM, and sharing shuffles
+    embeddings nor the (T, R·k) expanded logits ever exist in HBM: the
+    kernels DMA each negative's row straight from the table into a
+    double-buffered VMEM slab, a block of tokens at a time (the block sized
+    from the shapes and the VMEM budget), and sharing shuffles
     VMEM-resident logits. Differentiable in (out_emb, pos_logit, table);
     the table gradient is reduced from sparse (id, w·out_row) pairs through
     the sorted run-sum kernel.
 
-    ``gather_table`` (V, D), when given, is the §4.3.2 persistent
-    half-precision shadow: the kernel's BlockSpec gather DMAs its
-    half-width rows (real half-bandwidth HBM→VMEM traffic) and dequantizes
-    in VMEM, while the gradient still flows to ``table`` (the fp32 master)
-    — under the ``shadow == master.astype(qdtype)`` invariant the numerics
-    equal the fp32-round emulation exactly. Without it, ``fetch_dtype``
-    emulates the rounding on fp32 master rows (numerics-faithful, not
-    bandwidth-faithful).
+    ``gather_table``, when given, is the §4.3.2 persistent half-precision
+    shadow as ``embedding.tables.shadow_of`` stores it, while the gradient
+    still flows to ``table`` (the fp32 master). A packed shadow's rows are
+    DMA'd as they are stored, two bf16 elements per 32-bit word (real
+    half-bandwidth HBM→VMEM traffic), and dequantized in VMEM; an unpacked
+    one (a width whose halves are not lane-aligned) holds exactly the
+    master rounded to its dtype, so the kernels read the master's fp32 rows
+    and round them in VMEM instead (:func:`fused.gather_source`) — under
+    the shadow invariant the numerics are the same either way. Without a
+    shadow, ``fetch_dtype`` emulates the rounding on fp32 master rows
+    (numerics-faithful, not bandwidth-faithful). The bytes each row DMA
+    moves, and whether the rows were packed, are recorded at trace time as
+    the ``neg_gather_bytes_per_row`` gauge of
+    :data:`repro.obs.KERNEL_METRICS`.
 
-    ``rows_per_step`` (gathered rows per grid step — bitwise-invariant)
-    and ``scatter_impl`` (``"fused"`` in-kernel grad-row generation vs the
-    ``"two_pass"`` materialized oracle) default to the tuned.json entry
-    for this shape regime via :mod:`repro.kernels.autotune`.
+    ``scatter_impl`` (``"fused"`` in-kernel grad-row generation vs the
+    ``"two_pass"`` materialized oracle) defaults to the tuned.json entry,
+    else ``"fused"``.
     """
     interpret_ = default_interpret() if interpret is None else interpret
     T, R = neg_ids.shape
     V, D = table.shape
     inv_tau = 1.0 / tau
-    tune_dims = {"segment": segment, "R": R, "D": D, "T": T,
-                 "expansion": expansion}
-    if rows_per_step is None:
-        rows_per_step = autotune.resolve("neg_fused", tune_dims,
-                                         "rows_per_step", default=1)
     if scatter_impl is None:
+        tune_dims = {"segment": segment, "R": R, "D": D, "T": T,
+                     "expansion": expansion}
         scatter_impl = autotune.resolve("neg_fused", tune_dims,
                                         "scatter_impl", default="fused")
-    # shadow rows are already half-width: no in-VMEM rounding on top
-    fdt = fetch_dtype if gather_table is None else None
+    packed = gather_table is not None and ET.is_packed(gather_table)
+    KERNEL_METRICS.gauge(
+        "neg_gather_bytes_per_row",
+        "HBM bytes each negative-row DMA of the fused kernel moves",
+        labels={"path": "packed_row" if packed else "row"}).set(
+            ET.stored_row_bytes(gather_table) if packed else 4 * D)
 
     def _gather_src(tbl):
-        # the shadow rides in by closure (non-differentiable state, like
-        # ids_flat/valid2/perms); WITHOUT a shadow the gather must use the
-        # custom_vjp *argument* — closing over `table` there would leak
-        # the caller's JVPTracer into the primal.
-        return tbl if gather_table is None else gather_table
+        # a packed shadow rides in by closure (non-differentiable state,
+        # like ids_flat/valid2/perms); any other source must be the
+        # custom_vjp *argument* — closing over `table` would leak the
+        # caller's JVPTracer into the primal.
+        return F.gather_source(tbl, gather_table, fetch_dtype)
 
     o_p, pos_p, ids_p, valid_p, perms, n_seg = prepare_fused_inputs(
         out_emb, pos_logit, table, neg_ids, segment=segment,
@@ -182,10 +191,10 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
 
     @jax.custom_vjp
     def _lse(o, pos2d, tbl):
-        return F.fwd_pallas(o, pos2d, _gather_src(tbl), ids_flat, valid2,
+        words, fdt = _gather_src(tbl)
+        return F.fwd_pallas(o, pos2d, words, ids_flat, valid2,
                             perms, segment=segment, R=R,
                             expansion=expansion, tau=tau, fetch_dtype=fdt,
-                            rows_per_step=rows_per_step,
                             interpret=interpret_)
 
     def fwd(o, pos2d, tbl):
@@ -194,11 +203,12 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
 
     def bwd(res, g):
         o, pos2d, tbl, lse = res
+        words, fdt = _gather_src(tbl)
         w, dout, dpos = F.bwd_pallas(
-            o, pos2d, _gather_src(tbl), ids_flat, valid2, perms, lse,
+            o, pos2d, words, ids_flat, valid2, perms, lse,
             g.astype(jnp.float32), segment=segment, R=R,
             expansion=expansion, tau=tau, fetch_dtype=fdt,
-            rows_per_step=rows_per_step, interpret=interpret_)
+            interpret=interpret_)
         # sparse per-(token, slot) weights → weighted runsum-scatter; the
         # "fused" impl generates each w·o·τ⁻¹ grad row in sorted-run order
         # inside the kernel, so the (T·R, D) row buffer never exists.

@@ -137,7 +137,6 @@ class GRBundle:
              neg_mode: str = "fused", expansion: int = 1,
              neg_segment: int = 128, fetch_dtype=ET.SHADOW_DTYPE,
              neg_impl: Optional[str] = None,
-             neg_rows_per_step: Optional[int] = None,
              neg_scatter_impl: Optional[str] = None, attn_fn=None,
              input_table: Optional[jax.Array] = None,
              x_emb: Optional[jax.Array] = None,
@@ -151,9 +150,8 @@ class GRBundle:
                   gather + dequant + §4.3.3 sharing + Eq.-2 logsumexp in
                   one pass, no (T, R, d) or (T, R·k) HBM buffers
                   (``neg_impl`` picks pallas/xla, None = backend dispatch;
-                  ``neg_rows_per_step``/``neg_scatter_impl`` forward the
-                  kernel's tuning knobs — None reads tuned.json via
-                  kernels.autotune);
+                  ``neg_scatter_impl`` forwards the kernel's tuning
+                  knob — None reads tuned.json via kernels.autotune);
                   "baseline" materializes (G, cap, R, d) (§4.3 challenge,
                   the Table 7 reference);
                   "segmented" scans fixed-size segments with quantized
@@ -212,7 +210,6 @@ class GRBundle:
                     valid=valid.reshape(-1), segment=neg_segment,
                     expansion=expansion, fetch_dtype=fetch_dtype,
                     shadow=shadow, impl=neg_impl,
-                    rows_per_step=neg_rows_per_step,
                     scatter_impl=neg_scatter_impl)
             if neg_mode == "baseline":
                 # (G, cap, R, d)

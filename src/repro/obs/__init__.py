@@ -18,8 +18,8 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 from repro.obs.derived import measured_mfu, token_imbalance
-from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
-                               MetricsRegistry)
+from repro.obs.metrics import (DEFAULT_BUCKETS, KERNEL_METRICS, Counter,
+                               Gauge, Histogram, MetricsRegistry)
 from repro.obs.trace import Span, Tracer, busy_from_intervals, trace_busy_by_track
 
 __all__ = [
@@ -27,6 +27,7 @@ __all__ = [
     "Tracer",
     "Span",
     "MetricsRegistry",
+    "KERNEL_METRICS",
     "Counter",
     "Gauge",
     "Histogram",

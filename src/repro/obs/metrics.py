@@ -21,7 +21,8 @@ import re
 import threading
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS"]
+__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
+           "DEFAULT_BUCKETS", "KERNEL_METRICS"]
 
 # log-spaced second buckets: 100µs .. 30s, good for step/tick/ckpt times
 DEFAULT_BUCKETS = (1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 1e-1, 3e-1,
@@ -267,3 +268,9 @@ def _flatten(stats: Mapping[str, Any], prefix: str = "") -> List[Tuple[str, Any]
         else:
             out.append((path, v))
     return out
+
+
+# What kernel wrappers record while a program is traced: the schedule each
+# compiled kernel holds (e.g. ``neg_gather_bytes_per_row``). Compiled
+# programs live for the whole process, so this registry does too.
+KERNEL_METRICS = MetricsRegistry()

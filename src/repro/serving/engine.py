@@ -105,7 +105,8 @@ class RecallEngine:
             # state at production vocab sizes otherwise
             self.table = ET.ShadowedTable(
                 master=table,
-                shadow=table.astype(ET.SHADOW_DTYPE) if use_shadow else None,
+                shadow=(ET.shadow_of(table, ET.SHADOW_DTYPE) if use_shadow
+                        else None),
                 accum=jnp.zeros((0, table.shape[-1]), jnp.float32))
         self.k = k
         self.num_shards = num_shards
@@ -374,7 +375,8 @@ class StreamingRecallEngine:
         else:
             self.table = ET.ShadowedTable(
                 master=table,
-                shadow=table.astype(ET.SHADOW_DTYPE) if use_shadow else None,
+                shadow=(ET.shadow_of(table, ET.SHADOW_DTYPE) if use_shadow
+                        else None),
                 accum=jnp.zeros((0, table.shape[-1]), jnp.float32))
         self.k = k
         self.admission = admission

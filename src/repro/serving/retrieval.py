@@ -26,7 +26,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.embedding.tables import ShadowedTable, live_shadow
+from repro.embedding.tables import (ShadowedTable, live_shadow, shadow_values,
+                                   stored_row_bytes)
 
 
 def topk_dense(emb: jax.Array, table: jax.Array, k: int
@@ -40,11 +41,11 @@ def topk_blocked(emb: jax.Array, table: jax.Array, *, k: int,
                  block_v: int = 4096) -> Tuple[jax.Array, jax.Array]:
     """Blocked-scan top-k: per-shard partial top-k → running merge.
 
-    emb (B, d) any float dtype; table (V, D) fp32 master or fp16/bf16
-    shadow (rows are cast to fp32 *after* the shard gather, so a
-    half-precision table is fetched at half the bytes and never copied to
-    fp32 wholesale). Returns fp32 (B, k) scores + int32 (B, k) item ids,
-    score-descending. The last shard is handled by re-sliding the window
+    emb (B, d) any float dtype; table (V, D) fp32 master or a stored
+    fp16/bf16 shadow (rows are unpacked and cast to fp32 *after* the shard
+    gather, so a half-precision table is fetched at half the bytes and
+    never copied to fp32 wholesale). Returns fp32 (B, k) scores + int32
+    (B, k) item ids, score-descending. The last shard is handled by re-sliding the window
     to V − block_v and masking re-scored ids, so no padded table copy is
     ever materialized.
     """
@@ -60,7 +61,8 @@ def topk_blocked(emb: jax.Array, table: jax.Array, *, k: int,
     def body(i, carry):
         vals, idx = carry
         start = jnp.minimum(i * block_v, V - block_v)
-        blk = jax.lax.dynamic_slice_in_dim(table, start, block_v)
+        blk = shadow_values(jax.lax.dynamic_slice_in_dim(table, start,
+                                                         block_v))
         s = ef @ blk.astype(jnp.float32).T                 # (B, block_v)
         gidx = start + jnp.arange(block_v, dtype=jnp.int32)
         # the re-slid last window overlaps the previous shard; score each
@@ -104,12 +106,12 @@ def table_scan_bytes(table: jax.Array,
     ceil(V/block_v) windows of block_v rows — the re-slid last window
     re-reads up to block_v − (V mod block_v) rows when block_v does not
     divide V. Without ``block_v`` (dense full scoring), exactly V rows."""
-    V, D = int(table.shape[0]), int(table.shape[1])
+    V = int(table.shape[0])
     rows = V
     if block_v is not None:
         bv = min(block_v, V)
         rows = -(-V // bv) * bv
-    return rows * D * jnp.dtype(table.dtype).itemsize
+    return rows * stored_row_bytes(table)
 
 
 def bytes_per_query(table: jax.Array, batch: int,

@@ -13,6 +13,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.embedding import tables as ET
 from repro.embedding.tables import ShadowedTable
 
 
@@ -84,6 +85,15 @@ def adagrad_update(grads: Any, state: AdaGradState, params: Any, *,
     return new_params, AdaGradState(accum=new_accum)
 
 
+# Table elements that making the shadow anew converts in the time a scatter
+# lands one row: the landing makes it anew once the step's slots times this
+# reach the table's size. On a v5e a scatter took 80-100 ns a row and a
+# rebuild 0.024 ns an element at 2^18 x 1024 (packed: 4,200 elements a row;
+# the scatter wins below a share of 0.22) and 0.010 at 2^22 x 128 (bf16:
+# 7,700; below 1/64).
+SHADOW_SCATTER_ROW_ELEMS = 6144
+
+
 def adagrad_sparse_update(table: ShadowedTable, ids: jax.Array,
                           grad_rows: jax.Array, *, lr: float = 4e-3,
                           eps: float = 1e-10,
@@ -93,14 +103,18 @@ def adagrad_sparse_update(table: ShadowedTable, ids: jax.Array,
     ``ids`` (n,) int32 (< 0 = empty slot, duplicates allowed) and
     ``grad_rows`` (n, D) are deduplicated through the jagged_lookup
     sorted-runsum (table-major sort + run-sum, unique ids at run ends),
-    then master, accumulator and shadow are rewritten at *only the touched
-    rows* — the dense (V, D) update this replaces rewrote every row just
-    to change the few thousand a batch references, and rebuilding the
-    whole shadow each step would forfeit the §4.3.2 bandwidth saving.
+    then master and accumulator are rewritten at *only the touched rows* —
+    the dense (V, D) update this replaces rewrote every row just to change
+    the ones a batch references. A live shadow is landed from the landed
+    master rows, in one of two ways chosen from the shapes: a scatter of
+    the touched rows, or, where the step's slots are many for the table's
+    size (:data:`SHADOW_SCATTER_ROW_ELEMS`), the whole shadow made anew
+    from the master in one elementwise pass. Since ``shadow ==
+    shadow_of(master, qdtype)`` held for every row, both leave the same
+    bits. A stripped 0-row placeholder stays as it is.
 
     Numerics are identical to :func:`adagrad_update` on the touched rows
-    (same fp32 ops in the same order); untouched rows are bit-unchanged,
-    preserving the ``shadow == master.astype(qdtype)`` invariant globally.
+    (same fp32 ops in the same order); untouched rows are bit-unchanged.
     """
     if ids.shape[0] == 0:
         return table
@@ -119,11 +133,15 @@ def adagrad_sparse_update(table: ShadowedTable, ids: jax.Array,
     accum = table.accum.at[dest].add(
         jnp.where(keep[:, None], g * g, 0.0), mode="drop")
     shadow = table.shadow
-    if shadow is not None:
-        # re-gather the rows the scatter actually wrote: recomputing
+    if ET.live_shadow(table) is not None:
+        # from the rows the scatter actually wrote: recomputing
         # master[safe] + delta here can differ by an ulp when XLA fuses
         # the two delta uses differently, silently breaking the bitwise
-        # shadow == master.astype(qdtype) invariant
-        shadow = shadow.at[dest].set(
-            master[safe].astype(shadow.dtype), mode="drop")
+        # invariant
+        qdtype = ET.shadow_dtype(shadow)
+        if ids.shape[0] * SHADOW_SCATTER_ROW_ELEMS >= table.master.size:
+            shadow = ET.shadow_of(master, qdtype)
+        else:
+            shadow = shadow.at[dest].set(ET.shadow_of(master[safe], qdtype),
+                                         mode="drop")
     return ShadowedTable(master=master, shadow=shadow, accum=accum)

@@ -59,16 +59,20 @@ def test_enumerate_only_valid(kernel, dims):
 
 
 def test_rank_candidates_sorted_by_model():
-    ranked = rank_candidates("neg_fused", NEG_DIMS)
-    scores = [autotune._score(estimate_cost("neg_fused", NEG_DIMS, c))
+    ranked = rank_candidates("lookup_gather", LOOKUP_DIMS)
+    scores = [autotune._score(estimate_cost("lookup_gather", LOOKUP_DIMS, c))
               for c in ranked]
-    assert scores == sorted(scores)
+    assert len(ranked) > 1 and scores == sorted(scores)
 
 
 def test_grid_steps_shrink_with_grouping():
-    s1 = estimate_cost("neg_fused", NEG_DIMS, {"rows_per_step": 1})
-    s8 = estimate_cost("neg_fused", NEG_DIMS, {"rows_per_step": 8})
+    s1 = estimate_cost("neg_fused", NEG_DIMS, {"tokens_per_step": 1})
+    s8 = estimate_cost("neg_fused", NEG_DIMS, {"tokens_per_step": 8})
     assert s8["grid_steps"] * 8 == s1["grid_steps"]
+    # without a block, the cost describes the one the kernel compiles
+    tb = autotune.neg_tokens_per_step(NEG_DIMS)
+    assert (estimate_cost("neg_fused", NEG_DIMS)["grid_steps"] * tb
+            == s1["grid_steps"])
     adims = {"block": 8, "H": 2, "D": 16, "num_pairs": 36, "num_blocks": 12}
     a1 = estimate_cost("attn_worklist", adims, {"pairs_per_step": 1})
     a4 = estimate_cost("attn_worklist", adims, {"pairs_per_step": 4})
@@ -76,11 +80,30 @@ def test_grid_steps_shrink_with_grouping():
 
 
 def test_knob_valid_rejects_bad_values():
-    assert not knob_valid("neg_fused", NEG_DIMS, "rows_per_step", 3)
-    assert not knob_valid("neg_fused", NEG_DIMS, "rows_per_step", True)
+    assert not knob_valid("lookup_gather", LOOKUP_DIMS, "rows_per_step", 0)
+    assert not knob_valid("lookup_gather", LOOKUP_DIMS, "rows_per_step",
+                          True)
+    assert not knob_valid("lookup_gather", LOOKUP_DIMS, "rows_per_step", 65)
     assert not knob_valid("neg_fused", NEG_DIMS, "scatter_impl", "magic")
-    assert knob_valid("neg_fused", NEG_DIMS, "rows_per_step", 16)  # > R ok
+    assert knob_valid("neg_fused", NEG_DIMS, "scatter_impl", "two_pass")
+    # the negative kernel's token block is sized from the shapes, no knob
+    assert not knob_valid("neg_fused", NEG_DIMS, "tokens_per_step", 16)
+    assert "tokens_per_step" not in CANDIDATES["neg_fused"]
     assert not knob_valid("attn_worklist", ATTN_DIMS, "pairs_per_step", 0)
+
+
+@pytest.mark.parametrize("itemsize,tb", [(2, 16), (4, 8)])
+def test_neg_tokens_per_step_fills_the_vmem_budget(itemsize, tb):
+    # the cells' shapes: D=1024, R=128, segment 128; bf16 rows packed two
+    # to a word (2 bytes an element) or fp32 rows (4)
+    dims = {"segment": 128, "R": 128, "D": 1024, "T": 8192,
+            "expansion": 1, "itemsize": itemsize}
+    assert autotune.neg_tokens_per_step(dims) == tb
+    assert autotune._vmem_bytes("neg_fused", dims, {"tokens_per_step": tb}
+                                ) <= autotune.VMEM_BUDGET
+    assert autotune._vmem_bytes("neg_fused", dims,
+                                {"tokens_per_step": 2 * tb}
+                                ) > autotune.VMEM_BUDGET
 
 
 def test_pallas_cost_shape():
@@ -97,17 +120,19 @@ def test_pallas_cost_shape():
 def test_store_round_trip(tuned_path):
     store = TunedStore()
     assert store.path == tuned_path
-    store.put("neg_fused", NEG_DIMS, {"rows_per_step": 8},
+    store.put("neg_fused", NEG_DIMS, {"scatter_impl": "two_pass"},
               stats={"seconds": 1e-3})
     store.save()
-    assert autotune.resolve("neg_fused", NEG_DIMS, "rows_per_step") == 8
+    assert autotune.resolve("neg_fused", NEG_DIMS,
+                            "scatter_impl") == "two_pass"
     # fresh store object re-reads the file
     again = TunedStore()
-    assert again.get("neg_fused", NEG_DIMS) == {"rows_per_step": 8}
+    assert again.get("neg_fused", NEG_DIMS) == {"scatter_impl": "two_pass"}
 
 
 def test_resolve_defaults_on_missing(tuned_path):
-    assert autotune.resolve("neg_fused", NEG_DIMS, "rows_per_step") == 1
+    assert autotune.resolve("lookup_gather", LOOKUP_DIMS,
+                            "rows_per_step") == 1
     assert autotune.resolve("neg_fused", NEG_DIMS, "scatter_impl") == "fused"
     assert autotune.resolve("attn_worklist", ATTN_DIMS, "pairs_per_step",
                             default=2) == 2
@@ -116,19 +141,22 @@ def test_resolve_defaults_on_missing(tuned_path):
 def test_resolve_corrupt_file_falls_back(tuned_path):
     with open(tuned_path, "w") as f:
         f.write("{not json")
-    assert autotune.resolve("neg_fused", NEG_DIMS, "rows_per_step") == 1
+    assert autotune.resolve("neg_fused", NEG_DIMS, "scatter_impl") == "fused"
     with open(tuned_path, "w") as f:
         json.dump({"version": 1, "entries": "nope"}, f)
-    assert autotune.resolve("neg_fused", NEG_DIMS, "rows_per_step") == 1
+    assert autotune.resolve("neg_fused", NEG_DIMS, "scatter_impl") == "fused"
 
 
 def test_resolve_stale_entry_guard(tuned_path):
     # a stored value that no longer satisfies the current dims degrades
     # to the default instead of configuring an invalid kernel
     store = TunedStore()
-    store.put("neg_fused", NEG_DIMS, {"rows_per_step": 3})  # 3 ∤ seg·R
+    store.put("neg_fused", NEG_DIMS, {"scatter_impl": "three_pass"})
+    store.put("lookup_gather", LOOKUP_DIMS, {"rows_per_step": 0})
     store.save()
-    assert autotune.resolve("neg_fused", NEG_DIMS, "rows_per_step") == 1
+    assert autotune.resolve("neg_fused", NEG_DIMS, "scatter_impl") == "fused"
+    assert autotune.resolve("lookup_gather", LOOKUP_DIMS,
+                            "rows_per_step") == 1
 
 
 def test_cache_invalidated_on_rewrite(tuned_path):

@@ -54,6 +54,89 @@ def test_fused_fwd_matches_oracle(T, R, D, seg, expansion, table_dtype,
                                rtol=1e-6, atol=1e-6)
 
 
+def _row_dma_case(T=44, R=4, D=256, V=96, seg=16, seed=5):
+    """Ids that probe the row DMA: every offset within a 16-row tile, a
+    repeated id inside one token block, id V−1; T=44 pads to 48 (no
+    multiple of the 8-token block) and tokens 16.. are padding, so the
+    last two segments are all padding."""
+    out, master, ids, pos = _setup(T, R, D, V, seed=seed)
+    ids = np.asarray(ids).copy()
+    ids[:4] = (np.arange(16) + 32).reshape(4, R)     # offsets 0..15
+    ids[4, 1] = ids[4, 0]                            # repeat within a token
+    ids[5, 2] = ids[4, 0]                            # ... and a block
+    ids[6, 3] = V - 1
+    valid = jnp.arange(T) < seg
+    return out, master, jnp.asarray(ids), pos, valid
+
+
+@pytest.mark.parametrize("expansion", [1, 4])
+@pytest.mark.parametrize("source", ["stored_shadow", "bf16_shadow",
+                                    "fp32_master_fetch"])
+def test_fused_row_dma_matches_oracle(expansion, source):
+    """Forward, d_out, d_pos and the table gradient against the oracle,
+    for the shadow as training stores it (packed words), an unpacked bf16
+    shadow (read as master rows rounded in VMEM), and the fp32-master
+    fetch path."""
+    from repro.embedding.tables import shadow_of
+    out, master, ids, pos, valid = _row_dma_case()
+    shadow = master.astype(jnp.bfloat16)
+    kw = dict(segment=16, expansion=expansion, key=KEY, valid=valid)
+    if source == "stored_shadow":
+        ker_kw = dict(gather_table=shadow_of(master, jnp.bfloat16),
+                      fetch_dtype=jnp.bfloat16)
+        xla = NS.fused_recall_lse_xla(out, pos, master, ids, **kw, **ker_kw)
+    elif source == "bf16_shadow":
+        ker_kw = dict(gather_table=shadow, fetch_dtype=jnp.bfloat16)
+    else:
+        ker_kw = dict(fetch_dtype=jnp.bfloat16)
+    vf = valid.astype(jnp.float32)
+
+    def loss_k(o, t, p):
+        lse = fused_recall_lse(o, p, t, ids, interpret=True, **kw,
+                               **ker_kw)
+        return jnp.sum((lse - p) * vf), lse
+
+    def loss_r(o, t, p):
+        # the bf16 rows as an fp32 table: exact rows, fp32 cotangents
+        lse = fused_recall_lse_ref(o, p, t, ids, **kw)
+        return jnp.sum((lse - p) * vf), lse
+
+    (_, lse_k), gk = jax.value_and_grad(loss_k, argnums=(0, 1, 2),
+                                        has_aux=True)(out, master, pos)
+    (_, lse_r), gr = jax.value_and_grad(loss_r, argnums=(0, 1, 2),
+                                        has_aux=True)(
+        out, shadow.astype(jnp.float32), pos)
+    np.testing.assert_allclose(np.asarray(lse_k), np.asarray(lse_r),
+                               rtol=1e-5, atol=1e-5)
+    if source == "stored_shadow":
+        np.testing.assert_allclose(np.asarray(xla), np.asarray(lse_k),
+                                   rtol=1e-6, atol=1e-6)
+    for name, a, b in zip(["d_out", "table", "d_pos"], gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,D,path,bytes_", [
+    (jnp.bfloat16, 256, "packed_row", 512),   # two elements a word
+    (jnp.bfloat16, 16, "row", 64),            # halves not lane-aligned
+    (jnp.float32, 16, "row", 64),
+    (jnp.float16, 256, "row", 1024),          # only bf16 is packed
+    (None, 16, "row", 64),                    # no shadow: master rows
+])
+def test_gather_counter_reports_bytes_per_row(dtype, D, path, bytes_):
+    from repro.embedding.tables import shadow_of
+    from repro.obs import KERNEL_METRICS
+    out, master, ids, pos = _setup(T=16, R=2, D=D)
+    gauge = KERNEL_METRICS.gauge("neg_gather_bytes_per_row",
+                                 labels={"path": path})
+    gauge.set(0)
+    jax.eval_shape(lambda o, t: fused_recall_lse(
+        o, pos, t, ids, segment=8, interpret=True,
+        gather_table=None if dtype is None else shadow_of(t, dtype)),
+        out, master)
+    assert gauge.value == bytes_
+
+
 def test_fused_expansion1_equals_composed_baseline():
     """k=1 fused loss ≡ neg_logits_baseline + sampled_softmax_loss."""
     out, table, ids, _ = _setup(T=48, R=8, D=16)

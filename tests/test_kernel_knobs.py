@@ -13,7 +13,10 @@ import pytest
 from repro.kernels.jagged_attention import ops as attn_ops
 from repro.kernels.jagged_lookup.kernel import gather_pallas
 from repro.kernels.jagged_lookup.ops import scatter_add_weighted_rows
-from repro.kernels.neg_logits.ops import fused_recall_lse
+from repro.embedding.tables import shadow_of
+from repro.kernels.neg_logits import fused as NF
+from repro.kernels.neg_logits.ops import (fused_recall_lse,
+                                         prepare_fused_inputs)
 from repro.kernels.neg_logits.ref import fused_recall_lse_ref
 
 
@@ -37,13 +40,38 @@ def test_gather_rows_per_step_bitwise(rps, n):
 
 
 # ---------------------------------------------------------------------------
-# fused negative sampling: rows_per_step (incl. rps > R) + padding rows
+# fused negative sampling: the token block + padding rows. The block is no
+# knob of the wrappers (it is sized from the shapes), so these drive the
+# kernels' entry points at each block; the first three tests keep the names
+# they had under the rows_per_step knob the block replaced.
 # ---------------------------------------------------------------------------
 
 NEG_SHAPES = dict(T=44, R=4, V=256, D=16, seg=16)
 
 
-@pytest.mark.parametrize("rps", [2, 4, 8])       # 8 > R=4: multi-row steps
+def _neg_kernels(out, pos, table, ids, tb, *, segment, tau=1.0,
+                 expansion=1, key=None, valid=None, shadow=None):
+    """lse (T,) and the backward's (w, d_out, d_pos, table grad) at
+    ``tokens_per_step=tb``, for an upstream gradient of ones."""
+    T, R = ids.shape
+    o, p, i, v, perms, n_seg = prepare_fused_inputs(
+        out, pos, table, ids, segment=segment, expansion=expansion,
+        key=key, valid=valid)
+    words, fdt = NF.gather_source(table, shadow, None)
+    col = lambda x: x.reshape(n_seg, segment)
+    kw = dict(segment=segment, R=R, expansion=expansion, tau=tau,
+              fetch_dtype=fdt, tokens_per_step=tb, interpret=True)
+    lse = NF.fwd_pallas(o, col(p), words, i.reshape(-1), col(v), perms,
+                        **kw)
+    w, dout, dpos = NF.bwd_pallas(o, col(p), words, i.reshape(-1), col(v),
+                                  perms, lse, jnp.ones_like(lse), **kw)
+    dtbl = scatter_add_weighted_rows(w.reshape(-1, R), o, i.reshape(-1),
+                                     table.shape[0], scale=1.0 / tau,
+                                     interpret=True)
+    return lse.reshape(-1)[:T], (w, dout, dpos, dtbl)
+
+
+@pytest.mark.parametrize("rps", [2, 4, 8])       # T=44 is no multiple of 8
 @pytest.mark.parametrize("expansion", [1, 2])
 def test_fused_neg_rows_per_step_bitwise(rps, expansion):
     T, R, V, D, seg = (NEG_SHAPES[k] for k in ("T", "R", "V", "D", "seg"))
@@ -54,21 +82,21 @@ def test_fused_neg_rows_per_step_bitwise(rps, expansion):
     ids = jax.random.randint(ks[3], (T, R), 0, V)
     valid = jnp.arange(T) < T - 7                # T=44 pads to 48: dead tail
     kw = dict(segment=seg, tau=0.8, expansion=expansion,
-              key=ks[4] if expansion > 1 else None, valid=valid,
-              interpret=True)
-    base = fused_recall_lse(out, pos, table, ids, rows_per_step=1, **kw)
-    got = fused_recall_lse(out, pos, table, ids, rows_per_step=rps, **kw)
+              key=ks[4] if expansion > 1 else None, valid=valid)
+    base, _ = _neg_kernels(out, pos, table, ids, 1, **kw)
+    got, _ = _neg_kernels(out, pos, table, ids, rps, **kw)
     _bitwise(base, got)
-    ref = fused_recall_lse_ref(out, pos, table, ids,
-                               **{k: v for k, v in kw.items()
-                                  if k != "interpret"})
+    # the wrapper's own block, sized from the shapes
+    _bitwise(base, fused_recall_lse(out, pos, table, ids, interpret=True,
+                                    **kw))
+    ref = fused_recall_lse_ref(out, pos, table, ids, **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_fused_neg_all_padding_segment():
-    # a whole trailing segment of invalid tokens must not disturb the
-    # grouped gather (its clipped ids still index row 0 safely)
+    # whole trailing segments of invalid tokens must not disturb the
+    # block gather (their clipped ids still index row 0 safely)
     T, R, V, D, seg = 40, 4, 128, 8, 8
     ks = jax.random.split(jax.random.PRNGKey(4), 4)
     out = jax.random.normal(ks[0], (T, D), jnp.float32)
@@ -76,10 +104,11 @@ def test_fused_neg_all_padding_segment():
     table = jax.random.normal(ks[2], (V, D), jnp.float32)
     ids = jax.random.randint(ks[3], (T, R), 0, V)
     valid = jnp.arange(T) < 2 * seg              # segments 3..5 fully dead
-    kw = dict(segment=seg, tau=1.0, valid=valid, interpret=True)
-    base = fused_recall_lse(out, pos, table, ids, rows_per_step=1, **kw)
-    got = fused_recall_lse(out, pos, table, ids, rows_per_step=8, **kw)
-    _bitwise(base, got)
+    kw = dict(segment=seg, tau=1.0, valid=valid)
+    base, gb = _neg_kernels(out, pos, table, ids, 1, **kw)
+    got, gg = _neg_kernels(out, pos, table, ids, 8, **kw)
+    for a, b in zip((base, *gb), (got, *gg)):
+        _bitwise(a, b)
 
 
 def test_fused_neg_grads_match_across_rps():
@@ -89,17 +118,30 @@ def test_fused_neg_grads_match_across_rps():
     pos = jax.random.normal(ks[1], (T,), jnp.float32)
     table = jax.random.normal(ks[2], (V, D), jnp.float32)
     ids = jax.random.randint(ks[3], (T, R), 0, V)
-
-    def loss(out, table, rps):
-        lse = fused_recall_lse(out, pos, table, ids, segment=seg,
-                               rows_per_step=rps, interpret=True)
-        return jnp.sum(lse - pos)
-
-    g1 = jax.grad(loss, argnums=(0, 1))(out, table, 1)
-    g4 = jax.grad(loss, argnums=(0, 1))(out, table, 4)
+    _, g1 = _neg_kernels(out, pos, table, ids, 1, segment=seg)
+    _, g4 = _neg_kernels(out, pos, table, ids, 4, segment=seg)
     for a, b in zip(g1, g4):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
+        _bitwise(a, b)
+
+
+@pytest.mark.parametrize("tb", [2, 8])
+@pytest.mark.parametrize("expansion", [1, 4])
+def test_fused_neg_packed_shadow_tokens_per_step_bitwise(tb, expansion):
+    """The packed bf16-shadow path (D a multiple of 256): lse and every
+    backward output bit-identical across token blocks."""
+    T, R, V, D, seg = 36, 4, 64, 256, 16
+    ks = jax.random.split(jax.random.PRNGKey(8), 5)
+    out = jax.random.normal(ks[0], (T, D), jnp.float32)
+    pos = jax.random.normal(ks[1], (T,), jnp.float32)
+    master = jax.random.normal(ks[2], (V, D), jnp.float32)
+    shadow = shadow_of(master, jnp.bfloat16)
+    ids = jax.random.randint(ks[3], (T, R), 0, V)
+    kw = dict(segment=seg, expansion=expansion, key=ks[4],
+              valid=jnp.arange(T) < T - 5, shadow=shadow)
+    lse1, g1 = _neg_kernels(out, pos, master, ids, 1, **kw)
+    lse_t, g_t = _neg_kernels(out, pos, master, ids, tb, **kw)
+    for a, b in zip((lse1, *g1), (lse_t, *g_t)):
+        _bitwise(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -262,3 +262,88 @@ def test_checkpoint_strip_keeps_leaf_count():
     np.testing.assert_array_equal(
         np.asarray(rebuilt["table"].shadow, np.float32),
         np.asarray(tbl.shadow, np.float32))
+
+
+# --------------------------------------------------------------------------
+# the packed layout: bf16 at a width the fused kernel gathers as words
+# --------------------------------------------------------------------------
+
+DP = 512                      # halves lane-aligned: (V, DP/256, 128) words
+
+
+def _packed_table(key):
+    master = jax.random.normal(key, (V, DP), jnp.float32) * 0.1
+    tbl = ET.make_shadowed(master)
+    assert tbl.shadow.shape == (V, DP // 256, 128)
+    assert tbl.shadow.dtype == jnp.uint32
+    return tbl
+
+
+def test_packed_shadow_invariant_after_sparse_updates():
+    tbl = _packed_table(jax.random.PRNGKey(20))
+    for i in range(3):
+        ki, kr = jax.random.split(jax.random.PRNGKey(30 + i))
+        ids = jax.random.randint(ki, (40,), -1, V + 2, dtype=jnp.int32)
+        rows = jax.random.normal(kr, (40, DP), jnp.float32)
+        tbl = O.adagrad_sparse_update(tbl, ids, rows, lr=0.05)
+    assert bool(ET.shadow_consistent(tbl))
+    np.testing.assert_array_equal(
+        np.asarray(ET.shadow_values(tbl.shadow), np.float32),
+        np.asarray(tbl.master.astype(jnp.bfloat16), np.float32))
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_shadow_landing_scatter_equals_rebuild(packed, monkeypatch):
+    """The landing's two ways, the touched rows' scatter and the whole
+    shadow made anew, leave the same bits, in both layouts."""
+    tbl = (_packed_table(jax.random.PRNGKey(25)) if packed
+           else _table(jax.random.PRNGKey(25), jnp.bfloat16))
+    width = tbl.master.shape[1]
+    ki, kr = jax.random.split(jax.random.PRNGKey(26))
+    ids = jax.random.randint(ki, (12,), -1, V + 2, dtype=jnp.int32)
+    rows = jax.random.normal(kr, (12, width), jnp.float32)
+    got = {}
+    for elems in (0, V * width):      # always scatter / always rebuild
+        monkeypatch.setattr(O, "SHADOW_SCATTER_ROW_ELEMS", elems)
+        got[elems] = O.adagrad_sparse_update(tbl, ids, rows, lr=0.05)
+        assert bool(ET.shadow_consistent(got[elems]))
+    for a, b in zip(got[0], got[V * width]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_packed_shadow_checkpoint_strip_rebuild():
+    tbl = _packed_table(jax.random.PRNGKey(21))
+    stripped = CKPT._strip_shadows({"table": tbl})
+    assert stripped["table"].shadow.shape == (0, DP // 256, 128)
+    rebuilt = CKPT._rebuild_shadows(stripped)["table"]
+    np.testing.assert_array_equal(np.asarray(rebuilt.shadow),
+                                  np.asarray(tbl.shadow))
+
+
+def test_packed_shadow_serving_scan_reads_bf16_rows():
+    from repro.serving.retrieval import bytes_per_query, topk_blocked
+    tbl = _packed_table(jax.random.PRNGKey(22))
+    emb = jax.random.normal(jax.random.PRNGKey(23), (3, DP), jnp.float32)
+    got = topk_blocked(emb, tbl.shadow, k=5, block_v=16)
+    want = topk_blocked(emb, ET.shadow_values(tbl.shadow), k=5, block_v=16)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert bytes_per_query(tbl.shadow, 8) == V * DP * 2 / 8
+
+
+def test_packed_shadow_cache_window_and_splice():
+    from repro.embedding.cache import CachedShadowedTable
+    master = np.asarray(jax.random.normal(jax.random.PRNGKey(24), (V, DP)),
+                        np.float32)
+    c = CachedShadowedTable(master, capacity_chunks=4, chunk_rows=8)
+    c.warm_up(None)
+    win = c.init_window()
+    ids = np.array([0, 9, 63, 40, 41])            # chunks 0, 1, 7, 5
+    plan, _ = c.prepare(0, np.unique(ids))
+    win = c.splice(win, plan)
+    rows = np.asarray(ET.shadow_values(win.shadow)[c.translate(ids)],
+                      np.float32)
+    np.testing.assert_array_equal(
+        rows, np.asarray(jnp.asarray(master[ids]).astype(jnp.bfloat16),
+                         np.float32))
+    c.release(0, dirty=False)

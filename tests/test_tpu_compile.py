@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.embedding import tables as ET
 from repro.kernels.jagged_attention import kernel as AK
 from repro.kernels.jagged_attention import ops as AO
 from repro.kernels.jagged_lookup import kernel as LK
@@ -68,21 +69,37 @@ def test_attention_fwd_bwd_compiles(one_chip, H, Dh):
              ((32, H), jnp.float32), *[(f.shape, f.dtype) for f in plan])
 
 
-@pytest.mark.parametrize("D", [D_MODEL, 128])                # large, tiny
-def test_fused_negatives_fwd_bwd_on_bf16_shadow_compiles(one_chip, D):
+def _compile_negatives(one_chip, D, shadow_dtype):
+    """The kernels on the gather source training gives them: the shadow as
+    ``shadow_of`` stores it (packed words at D_MODEL, an unpacked shadow
+    read as master rows at 128), or the fp32 master alone."""
     T, seg = 1024, 128
     n_seg = T // seg
     kw = dict(segment=seg, R=R, expansion=1, tau=1.0, interpret=False)
 
-    def step(out, pos, shadow, ids, valid, perms, g):
-        lse = NF.fwd_pallas(out, pos, shadow, ids, valid, perms, **kw)
-        return lse, NF.bwd_pallas(out, pos, shadow, ids, valid, perms, lse,
-                                  g, **kw)
+    def step(out, pos, master, ids, valid, perms, g):
+        shadow = (None if shadow_dtype is None
+                  else ET.shadow_of(master, shadow_dtype))
+        words, fdt = NF.gather_source(master, shadow, jnp.bfloat16)
+        lse = NF.fwd_pallas(out, pos, words, ids, valid, perms,
+                            fetch_dtype=fdt, **kw)
+        return lse, NF.bwd_pallas(out, pos, words, ids, valid, perms, lse,
+                                  g, fetch_dtype=fdt, **kw)
 
     per_seg = ((n_seg, seg), jnp.float32)
     _compile(one_chip, step, ((T, D), jnp.bfloat16), per_seg,
-             ((V, D), jnp.bfloat16), ((T * R,), jnp.int32), per_seg,
+             ((V, D), jnp.float32), ((T * R,), jnp.int32), per_seg,
              ((n_seg, 1, seg), jnp.int32), per_seg)
+
+
+@pytest.mark.parametrize("D", [D_MODEL, 128])                # large, tiny
+def test_fused_negatives_fwd_bwd_on_bf16_shadow_compiles(one_chip, D):
+    # D_MODEL gathers packed bf16 rows, 128 fp32 master rows
+    _compile_negatives(one_chip, D, jnp.bfloat16)
+
+
+def test_fused_negatives_fwd_bwd_on_fp32_master_compiles(one_chip):
+    _compile_negatives(one_chip, D_MODEL, None)
 
 
 def test_weighted_scatter_compiles(one_chip):
