@@ -50,7 +50,7 @@ def readings(prog, cell, seed):
     import harness
     import traffic
     steps = traffic.make_batches(cell.bench_dir, cell.mix, cell.model, seed,
-                                 0, harness.CHECK_STEPS)
+                                 0, harness.CHECK_STEPS, cell.shards)
     prog.seed = seed
     prog.feed.steps = steps
     prog.init_state()
@@ -71,9 +71,9 @@ def main() -> None:
 
     import compare
     import harness
-    import reference
 
     cell = harness.load_cell(ROOT, args.workload)
+    reference = harness.reference_module(cell)
     devs = harness.require_chips(cell.chips)
     harness.use_compile_cache(ROOT)
     seeds = [int(s) for s in args.seeds.split(",")]
@@ -89,7 +89,7 @@ def main() -> None:
                           "ref_change_norms": ref["change_norms"],
                           "device": devs[0].device_kind}), flush=True)
 
-    prog = harness.Program(cell.model, seeds[0])
+    prog = harness.Program(cell.model, seeds[0], devs)
     refs = {}
     for i, seed in enumerate(seeds):
         mine, batches = readings(prog, cell, seed)
@@ -112,7 +112,7 @@ def main() -> None:
             break
         if plant is not None:
             plant()
-        prog = harness.Program(model, seeds[0])
+        prog = harness.Program(model, seeds[0], devs)
         for seed, ref in refs.items():
             show(seed, side, readings(prog, cell, seed)[0], ref)
         prog.close()

@@ -8,7 +8,12 @@ of ``l`` tokens has ``l (l + 1) / 2`` causal query-key pairs.
 """
 from __future__ import annotations
 
+import os
 from typing import Dict, Sequence
+
+import traffic
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 
 BF16 = 2
 F32 = 4
@@ -25,10 +30,23 @@ def causal_pairs(lengths: Sequence[int]) -> int:
 
 def dense_matmul_params(model: Dict) -> int:
     """Weights of the dense model's matrix products (the non-embedding
-    parameters that cost 2 operations per token forward)."""
+    parameters that cost 2 operations per token forward). HSTU and
+    FuXi-alpha blocks are counted here; a configuration with a block of its
+    own names the module that counts it, ``"counts": "<module>"``
+    (``<bench>/<module>.py`` with a ``dense_matmul_params(model)``)."""
+    block = model["block"]
+    if block not in ("hstu", "fuxi"):
+        if "counts" not in model:
+            raise ValueError(f"no count of the dense weights of a {block!r} "
+                             f"block: the configuration names no 'counts' "
+                             f"module")
+        name = model["counts"]
+        return traffic.load_module(os.path.join(BENCH_DIR, f"{name}.py"),
+                                   f"bench_counts_{name}"
+                                   ).dense_matmul_params(model)
     d, H, dq = model["d_model"], model["num_heads"], model["qkv_dim"]
     per = d * 4 * H * dq + H * dq * d
-    if model["block"] == "fuxi":
+    if block == "fuxi":
         per += 3 * d * model["d_ff"]
     return model["num_layers"] * per
 

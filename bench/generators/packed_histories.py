@@ -20,6 +20,11 @@ table). Timestamps rise monotonically over ``time_span_s`` with exponential
 gaps, and a sequence's timestamps count from its first kept event, as the
 repo's loader does. Negatives are ``num_negatives`` uniform ids per token.
 
+A cell on a mesh of G chips trains G shards a step, one a chip: its step
+``b`` stacks the stream's packed steps ``G b .. G b + G - 1`` as ``(G, T)``
+arrays, so ``token_budget`` and ``max_seqs`` are per chip, and the step's
+lengths are every shard's.
+
 The arithmetic of the lengths, the Zipf ranks and the timestamps follows
 ``repro.data.synthetic.SyntheticKuaiRand``; the truncation replaces its
 clip, which put about 29% of all draws (at a = 1.1 over 2^18 rows) on the
@@ -92,16 +97,45 @@ def _user(mix: Dict, seed: int, k: int, n: int, full_events: int,
     return items, ts
 
 
-def batches(mix: Dict, model: Dict, seed: int, start: int,
-            count: int) -> List[Tuple[Dict[str, np.ndarray], List[int]]]:
-    """Steps ``start .. start + count - 1`` of the stream: each a
-    ``(batch, sequence_lengths)`` pair in the engine's batch layout."""
+def _packed(mix: Dict, seed: int, b: int, step: List[Tuple[int, int]],
+            events: np.ndarray, T: int, S: int, R: int, V: int
+            ) -> Tuple[Dict[str, np.ndarray], List[int]]:
+    """Packed step ``b`` of the stream as one shard, ``(1, T)`` arrays."""
+    ids = np.zeros((1, T), np.int32)
+    labels = np.zeros((1, T), np.int32)
+    ts = np.zeros((1, T), np.int32)
+    offsets = np.zeros((1, S + 1), np.int32)
+    cur, seq = 0, []
+    order = _rng(seed, 3, b).permutation(len(step))
+    for j, (k, n) in enumerate(step[i] for i in order):
+        items, t = _user(mix, seed, k, n, int(events[k]), V)
+        ids[0, cur:cur + n] = items[:-1]
+        labels[0, cur:cur + n] = items[1:]
+        ts[0, cur:cur + n] = t
+        cur += n
+        offsets[0, j + 1] = cur
+        seq.append(n)
+    offsets[0, len(seq) + 1:] = cur
+    rng = _rng(seed, 2, b)
+    neg = rng.integers(0, V, (1, T, R), dtype=np.int32)
+    key = rng.integers(0, 2 ** 31, (2,)).astype(np.uint32)
+    return ({"ids": ids, "labels": labels, "timestamps": ts,
+             "offsets": offsets, "neg_ids": neg, "rng": key}, seq)
+
+
+def batches(mix: Dict, model: Dict, seed: int, start: int, count: int,
+            shards: int = 1
+            ) -> List[Tuple[Dict[str, np.ndarray], List[int]]]:
+    """Steps ``start .. start + count - 1`` of a cell of ``shards`` chips:
+    each a ``(batch, sequence_lengths)`` pair in the engine's batch layout,
+    step ``b`` the packed steps ``shards * b ..`` stacked on the shard
+    axis, ``rng`` the first one's key, the lengths every shard's."""
     L = int(model["max_seq_len"])
     R = int(model["num_negatives"])
     V = int(model["vocab_size"])
     T = int(mix["token_budget"])
     S = int(mix["max_seqs"])
-    need = start + count
+    need = (start + count) * shards
     users = max(64, need * max(1, T // max(L, 1)) * 2)
     while True:
         events = user_events(mix, users)
@@ -111,25 +145,11 @@ def batches(mix: Dict, model: Dict, seed: int, start: int,
             break
         users *= 2
     out = []
-    for b in range(start, need):
-        ids = np.zeros((1, T), np.int32)
-        labels = np.zeros((1, T), np.int32)
-        ts = np.zeros((1, T), np.int32)
-        offsets = np.zeros((1, S + 1), np.int32)
-        cur, seq = 0, []
-        order = _rng(seed, 3, b).permutation(len(steps[b]))
-        for j, (k, n) in enumerate(steps[b][i] for i in order):
-            items, t = _user(mix, seed, k, n, int(events[k]), V)
-            ids[0, cur:cur + n] = items[:-1]
-            labels[0, cur:cur + n] = items[1:]
-            ts[0, cur:cur + n] = t
-            cur += n
-            offsets[0, j + 1] = cur
-            seq.append(n)
-        offsets[0, len(seq) + 1:] = cur
-        rng = _rng(seed, 2, b)
-        neg = rng.integers(0, V, (1, T, R), dtype=np.int32)
-        key = rng.integers(0, 2 ** 31, (2,)).astype(np.uint32)
-        out.append(({"ids": ids, "labels": labels, "timestamps": ts,
-                     "offsets": offsets, "neg_ids": neg, "rng": key}, seq))
+    for b in range(start, start + count):
+        parts = [_packed(mix, seed, p, steps[p], events, T, S, R, V)
+                 for p in range(b * shards, (b + 1) * shards)]
+        batch = {k: np.concatenate([p[0][k] for p in parts])
+                 for k in parts[0][0] if k != "rng"}
+        batch["rng"] = parts[0][0]["rng"]
+        out.append((batch, [n for p in parts for n in p[1]]))
     return out

@@ -5,6 +5,15 @@ traffic mix (``<bench>/traffic/<mix>.json``); each per-layer metric is read
 by ``<bench>/metrics/<name>.py``, whose ``read(run)`` returns a number or
 None. A new cell, configuration, mix or metric is new files and entries.
 
+A configuration may name a mesh, ``"mesh": {"data": D, "model": M}``, of
+as many chips as its cells ask for: the item table is then sharded over
+``model`` by Hierarchical Sparse Parallelism (the HSP lookup of
+``repro.core.hsp``), the dense weights replicated, and each chip trains one
+shard of every step, so a step holds D M shards of the mix's token budget;
+without it the cell runs on one chip and a step is one shard. It may also
+name the module of its plain reference, ``"reference": "<module>"``
+(``<bench>/<module>.py``, default ``reference``).
+
 A run: find the chips; make the steps from the seed; build the training
 engine (``GREngine``, Algorithm 1, tau = 1) with its state made on the
 device in one jitted call; drive its first three steps, which the
@@ -59,6 +68,7 @@ class Cell:
     end_to_end: List[Dict]
     per_layer: List[Dict]
     bench_dir: str
+    shards: int = 1         # shards of a step: one a chip of the mesh
 
 
 def _named(entries: List[Dict], name: str, what: str) -> Dict:
@@ -78,13 +88,33 @@ def load_cell(root: str, name: str) -> Cell:
     c = _named(spec["configs"], w["config"], "config")
     bench_dir = os.path.join(root, spec["paths"][0])
     model = load_json(os.path.join(root, c["file"]))
-    return Cell(name=name, chips=int(w["chips"]), model=model,
+    chips, shards = int(w["chips"]), 1
+    if "mesh" in model:
+        mesh = model["mesh"]
+        if sorted(mesh) != ["data", "model"] or not all(
+                isinstance(v, int) and v > 0 for v in mesh.values()):
+            raise SystemExit(f"bench: the mesh of {c['file']} is {mesh!r}, "
+                             f"not {{\"data\": D, \"model\": M}}")
+        shards = mesh["data"] * mesh["model"]
+        if shards != chips:
+            raise SystemExit(
+                f"bench: the mesh of {c['file']} holds {mesh['data']} x "
+                f"{mesh['model']} = {shards} chips; the cell {name} asks "
+                f"for {chips}")
+    return Cell(name=name, chips=chips, model=model,
                 mix=traffic.load_mix(bench_dir, w["traffic"]),
                 limits=load_json(os.path.join(bench_dir, "limits",
                                               f"{name}.json")),
                 end_to_end=_for_cell(spec["end_to_end"], name),
                 per_layer=_for_cell(spec["per_layer"], name),
-                bench_dir=bench_dir)
+                bench_dir=bench_dir, shards=shards)
+
+
+def reference_module(cell: Cell):
+    """The configuration's plain reference: ``<bench>/<module>.py``."""
+    name = cell.model.get("reference", "reference")
+    return traffic.load_module(os.path.join(cell.bench_dir, f"{name}.py"),
+                               f"bench_reference_{name}")
 
 
 def metric_reader(bench_dir: str, name: str):
@@ -163,21 +193,27 @@ def seed_key(seed: int):
 
 class Feed:
     """``data_fn`` of the engine: step ``i`` of the current run is
-    ``steps[base + i]``."""
+    ``steps[base + i]``, placed by ``place`` (on a mesh: each shard on its
+    chip)."""
 
     def __init__(self):
         self.steps: List = []
         self.base = 0
+        self.place = None
 
     def __call__(self, i: int):
-        return self.steps[self.base + i][0]
+        batch = self.steps[self.base + i][0]
+        return batch if self.place is None else self.place(batch)
 
 
 class Program:
     """The system under test: ``GREngine`` on the cell's configuration,
-    and the program-side readings of the checked steps."""
+    and the program-side readings of the checked steps. With a ``mesh`` in
+    the configuration, the engine runs over ``devices`` with the HSP
+    lookup, and the state and each step's batch are placed by the
+    program's own partition rules (``repro.launch.partition``)."""
 
-    def __init__(self, model: Dict, seed: int):
+    def __init__(self, model: Dict, seed: int, devices=None):
         import jax
         import jax.numpy as jnp
         from repro.models.model_zoo import GRBundle
@@ -188,10 +224,14 @@ class Program:
         self.model, self.seed = model, seed
         self.bundle = GRBundle(arch_config(model))
         self.feed = Feed()
+        loss_kwargs = dict(neg_mode=tr["neg_mode"],
+                           expansion=tr["expansion"])
+        self.mesh = None
+        if "mesh" in model:
+            self._on_mesh(devices, loss_kwargs)
         self.engine = GREngine(
             self.bundle, self.feed, seed=seed % (1 << 32),
-            loss_kwargs=dict(neg_mode=tr["neg_mode"],
-                             expansion=tr["expansion"]),
+            loss_kwargs=loss_kwargs,
             lr_dense=tr["lr_dense"], lr_sparse=tr["lr_sparse"],
             semi_async=tr["semi_async"], schedule=tr["schedule"],
             step_callback=self._on_step)
@@ -217,6 +257,7 @@ class Program:
                                    jax.tree.leaves(d0))]
             return out + [sqnorm(master - bundle.init_table(key))]
 
+        self._state_fn = make_state
         self._make_state = jax.jit(make_state, static_argnums=1)
         self._first_grads = jax.jit(first_grads)
         self._changes = jax.jit(changes)
@@ -225,11 +266,61 @@ class Program:
         self.leaves = ["/".join(str(getattr(k, "key", k)) for k in p)
                        for p, _ in paths] + ["table"]
 
+    def _on_mesh(self, devices, loss_kwargs: Dict) -> None:
+        """A ``("data", "model")`` mesh over ``devices``, the HSP lookup
+        bound into the loss, the plan behind the state's and the batches'
+        shardings, and the feed placing each step over the mesh."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import AxisType, Mesh
+        from repro.configs.shapes import ShapeConfig
+        from repro.core.hsp import make_hsp_lookup
+        from repro.launch import partition as PT
+
+        shape = (self.model["mesh"]["data"], self.model["mesh"]["model"])
+        self.mesh = Mesh(np.array(devices).reshape(shape), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+        loss_kwargs["lookup_fn"] = make_hsp_lookup(
+            self.mesh, group_axes=("model",), dp_axes=("data",),
+            compute_dtype=jnp.dtype(self.model["dtype"]))
+        self._shape = ShapeConfig("bench", self.model["max_seq_len"],
+                                  self.mesh.size, "train")
+        self._plan = PT.make_plan(self.bundle.cfg, self._shape, self.mesh)
+        self.feed.place = lambda batch: jax.device_put(
+            batch, self.batch_shardings(batch))
+
+    def batch_shardings(self, batch: Dict):
+        """Each array of a step placed over the mesh on its shard axis."""
+        import jax
+        from repro.launch import partition as PT
+        sds = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+               for k, v in batch.items()}
+        specs = PT.batch_specs(self.bundle.cfg, self._shape, self.mesh,
+                               self._plan, {"batch": sds})["batch"]
+        return PT.to_named(self.mesh, specs)
+
+    def state_shardings(self, slots: int):
+        """The state's shardings: the table over ``model``, the dense
+        weights replicated, the tau = 1 pending rows over the data axes."""
+        import jax
+        from repro.launch import partition as PT
+        sds = jax.eval_shape(self._make_state, seed_key(0), slots)
+        dense = PT.gr_param_specs(sds.dense, self.mesh, self._plan)
+        specs = PT.gr_state_specs(dense, PT.gr_table_spec(self.mesh,
+                                                          self._plan),
+                                  pend_spec=PT.gr_pend_spec(self.mesh, slots))
+        return PT.to_named(self.mesh, specs)
+
     def init_state(self) -> None:
+        import jax
         from repro.training.trainer import gr_pending_slots
         slots = gr_pending_slots(self.feed.steps[0][0],
                                  self.model["vocab_size"])
-        self.engine.state = self._make_state(seed_key(self.seed), slots)
+        make = self._make_state
+        if self.mesh is not None:
+            make = jax.jit(self._state_fn, static_argnums=1,
+                           out_shardings=self.state_shardings(slots))
+        self.engine.state = make(seed_key(self.seed), slots)
 
     def _on_step(self, i: int, rec: Dict, snap) -> None:
         self.marks.append(time.perf_counter())
@@ -285,7 +376,12 @@ class Run:
     host_offset_ns: float = 0.0     # trace clock = perf_counter ns + this
     trace: Optional[Dict] = None
     plane: Optional[Dict] = None    # the first chip's trace plane
+    planes: List[Dict] = field(default_factory=list)  # every chip's, in order
     trace_window: Optional[tuple] = None
+
+    def __post_init__(self):
+        if not self.planes and self.plane is not None:
+            self.planes = [self.plane]
 
 
 def window_steps(seconds: float, step_s: float) -> int:
@@ -303,12 +399,14 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     counter = CompileCounter()
     phases = [("start to chip", time.perf_counter())]
 
-    prog = Program(cell.model, seed)
+    prog = Program(cell.model, seed, devs)
     first = CHECK_STEPS + WARM_STEPS
     prog.feed.steps = traffic.make_batches(cell.bench_dir, cell.mix,
-                                           cell.model, seed, 0, first)
+                                           cell.model, seed, 0, first,
+                                           cell.shards)
     phases.append(("engine and steps", time.perf_counter()))
-    fill = [sum(s[1]) / cell.mix["token_budget"] for s in prog.feed.steps]
+    budget = cell.shards * cell.mix["token_budget"]
+    fill = [sum(s[1]) / budget for s in prog.feed.steps]
     prog.init_state()
     phases.append(("state", time.perf_counter()))
     mine = prog.checked_steps()
@@ -319,7 +417,8 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     step_s = (prog.marks[-1] - prog.marks[0]) / (len(prog.marks) - 1)
     n = window_steps(seconds, step_s)
     prog.feed.steps += traffic.make_batches(cell.bench_dir, cell.mix,
-                                            cell.model, seed, first, n)
+                                            cell.model, seed, first, n,
+                                            cell.shards)
     phases.append(("window steps", time.perf_counter()))
     log(f"[setup] {cell.name}: {n} window steps at ~{step_s:.3f} s a step "
         f"(warm); fill of the checked and warm steps "
@@ -351,7 +450,7 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     tokens = sum(r["tokens"] for r in recs)
     failed = sum(1 for r in recs if not math.isfinite(r["loss"]))
     log(f"[window] {n} steps, {tokens} tokens in {run.window_s:.4f} s; "
-        f"fill {tokens / (n * cell.mix['token_budget']):.4f}; "
+        f"fill {tokens / (n * budget):.4f}; "
         f"{counter.count} compilations inside the window")
     prog.close()
     del prog
@@ -371,9 +470,8 @@ def execute(root: str, workload: str, seed: int, seconds: float,
             v = metric_reader(cell.bench_dir, m["name"]).read(run)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
-        busy = [xplane.busy_ns(p, run.trace_window)
-                for p in xplane.device_planes(tr)[:cell.chips]]
-        device["busy_s"] = sum(busy) / max(len(busy), 1) * 1e-9
+        busy = [xplane.busy_ns(p, run.trace_window) for p in run.planes]
+        device["busy_s"] = sum(busy) / len(busy) * 1e-9
         device["window_s"] = (run.trace_window[1] - run.trace_window[0]) * 1e-9
         out["breakdown"] = breakdown(run)
     else:
@@ -384,11 +482,10 @@ def execute(root: str, workload: str, seed: int, seconds: float,
     out["metrics"] = metrics
     out["device"] = device
 
-    import reference
-    ref = reference.run(cell.model, seed,
-                        [s[0] for s in traffic.make_batches(
-                            cell.bench_dir, cell.mix, cell.model, seed, 0,
-                            CHECK_STEPS)])
+    ref = reference_module(cell).run(
+        cell.model, seed, [s[0] for s in traffic.make_batches(
+            cell.bench_dir, cell.mix, cell.model, seed, 0, CHECK_STEPS,
+            cell.shards)])
     found = compare.gaps(mine, ref)
     ok, checks = compare.judge(found, cell.limits)
     out["correct"] = bool(ok and failed == 0)
@@ -401,10 +498,11 @@ def attach_trace(run: Run, tr: Dict, w0: float) -> None:
     import xplane
     run.trace = tr
     run.trace_window = xplane.window(tr)
-    planes = xplane.device_planes(tr)
-    if run.trace_window is None or not planes:
-        raise RuntimeError("the trace holds no window marker or no device")
-    run.plane = planes[0]
+    planes = xplane.device_planes(tr)[:run.chips]
+    if run.trace_window is None or len(planes) < run.chips:
+        raise RuntimeError(f"the trace holds no window marker or fewer "
+                           f"than {run.chips} devices")
+    run.planes, run.plane = planes, planes[0]
     run.host_offset_ns = run.trace_window[0] - w0 * 1e9
 
 

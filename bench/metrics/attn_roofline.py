@@ -4,14 +4,18 @@ and A V over the step's causal pairs) and one backward (dV, dA, dQ and
 dK) per layer (bench/flops.py), at the larger of operations over peak
 bf16 and bytes over HBM bandwidth. The count of layers comes from the
 configuration, not from the kernels' calls, so that recomputation or a
-layer split over several calls reads as more time for the same work."""
+layer split over several calls reads as more time for the same work. The
+whole step's work is set against the sum of the cell's chips' kernel
+time."""
+import _chips
 import flops
 import xplane
 from _kernels import ATTENTION
 
 
 def read(run):
-    ns = xplane.kernel_ns(run.plane, run.trace_window, ATTENTION)
+    ns = _chips.total(run, lambda p: xplane.kernel_ns(p, run.trace_window,
+                                                       ATTENTION))
     if ns <= 0:
         return None
     layers = run.model["num_layers"]

@@ -203,10 +203,13 @@ def hidden(dense, model, x, offsets, ts, low):
     return _ln(x, dense["out_ln_w"], dense["out_ln_b"], eps)
 
 
-def loss(dense, stale, fresh, batch, model, low, half_batch, chunk=512):
-    ids, labels = batch["ids"][0], batch["labels"][0]
-    offsets, ts = batch["offsets"][0], batch["timestamps"][0]
-    neg = batch["neg_ids"][0]
+def shard_loss(dense, stale, fresh, batch, model, low, half_batch,
+               chunk=512):
+    """Sum of ``lse - pos`` over one shard's valid tokens (``(T,)`` ids,
+    ``(S + 1,)`` offsets, ``(T, R)`` negatives), and their count."""
+    ids, labels = batch["ids"], batch["labels"]
+    offsets, ts = batch["offsets"], batch["timestamps"]
+    neg = batch["neg_ids"]
     T = ids.shape[0]
     h = hidden(dense, model, stale[ids], offsets, ts, low)
     total = offsets[-1]
@@ -224,7 +227,29 @@ def loss(dense, stale, fresh, batch, model, low, half_batch, chunk=512):
                                 neg.reshape(T // c, c, -1),
                                 pos.reshape(T // c, c))).reshape(T)
     v = valid.astype(F32)
-    return jnp.sum((lse - pos) * v) / jnp.maximum(jnp.sum(v), 1.0)
+    return jnp.sum((lse - pos) * v), jnp.sum(v)
+
+
+SHARD_KEYS = ("ids", "labels", "offsets", "timestamps", "neg_ids")
+
+
+def loss(dense, stale, fresh, batch, model, low, half_batch):
+    """Mean of ``lse - pos`` over the valid tokens of every shard of the
+    step: the global step that data-parallel training computes, since
+    attention never crosses shards. Several shards go one at a time, each
+    recomputed in the backward pass, so that the memory stays one shard's;
+    one shard needs no recomputation."""
+    shards = {k: batch[k] for k in SHARD_KEYS}
+    if batch["ids"].shape[0] == 1:
+        s, n = shard_loss(dense, stale, fresh,
+                          {k: x[0] for k, x in shards.items()}, model, low,
+                          half_batch)
+    else:
+        one = jax.checkpoint(lambda b: shard_loss(dense, stale, fresh, b,
+                                                  model, low, half_batch))
+        s, n = jax.lax.map(one, shards)
+        s, n = jnp.sum(s), jnp.sum(n)
+    return s / jnp.maximum(n, 1.0)
 
 
 # -- training -----------------------------------------------------------------

@@ -27,9 +27,9 @@ TINY_MIX = {"generator": "packed_histories", "token_budget": 128,
             "time_span_s": 2592000}
 
 
-def make_root(path, model=None, block="hstu"):
+def make_root(path, model=None, block="hstu", chips=1):
     """A checkout-shaped directory: BENCHMARK.json with one tiny cell
-    ``tiny.mix`` and the benchmark's files."""
+    ``tiny.mix`` of ``chips`` chips and the benchmark's files."""
     shutil.copytree(BENCH, os.path.join(path, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__", "test_*",
                                                   "data"))
@@ -50,7 +50,7 @@ def make_root(path, model=None, block="hstu"):
                          "file": "bench/configs/tiny.json", "reduced": [],
                          "why": "test"}],
             "workloads": [{"name": "tiny.mix", "config": "tiny",
-                           "traffic": "mix", "chips": 1, "why": "test"}],
+                           "traffic": "mix", "chips": chips, "why": "test"}],
             "end_to_end": [
                 {"name": "train_tokens_per_s", "unit": "tokens/s",
                  "better": "higher", "bound": 0.05, "source": "host_clock"},

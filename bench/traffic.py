@@ -1,8 +1,8 @@
 """Traffic mixes: a mix is ``<bench>/traffic/<name>.json``, a file of
 parameters. Its ``generator`` names a module ``<bench>/generators/<g>.py``
-whose ``batches(mix, model, seed, start, count)`` returns the steps
-``start .. start + count - 1`` as ``(batch, sequence_lengths)`` pairs. The
-same seed gives the same steps."""
+whose ``batches(mix, model, seed, start, count, shards)`` returns the steps
+``start .. start + count - 1`` of a cell of ``shards`` chips as ``(batch,
+sequence_lengths)`` pairs. The same seed gives the same steps."""
 from __future__ import annotations
 
 import importlib.util
@@ -31,9 +31,9 @@ def load_mix(bench_dir: str, name: str) -> Dict:
 
 
 def make_batches(bench_dir: str, mix: Dict, model: Dict, seed: int,
-                 start: int, count: int
+                 start: int, count: int, shards: int = 1
                  ) -> List[Tuple[Dict[str, np.ndarray], List[int]]]:
     gen = load_module(
         os.path.join(bench_dir, "generators", f"{mix['generator']}.py"),
         f"bench_generator_{mix['generator']}")
-    return gen.batches(mix, model, seed, start, count)
+    return gen.batches(mix, model, seed, start, count, shards)

@@ -74,9 +74,17 @@ def base(name: str) -> str:
 
 
 def device_planes(tr: Dict) -> List[Dict]:
-    return [p for p in tr["planes"]
-            if p["name"].startswith("/device:") and
-            any(ln["name"] == OPS_LINE for ln in p["lines"])]
+    """The planes of the devices that ran ops, in device order
+    (``/device:TPU:<n>``)."""
+    planes = [p for p in tr["planes"]
+              if p["name"].startswith("/device:") and
+              any(ln["name"] == OPS_LINE for ln in p["lines"])]
+    return sorted(planes, key=lambda p: _ordinal(p["name"]))
+
+
+def _ordinal(name: str) -> float:
+    n = name.rsplit(":", 1)[-1]
+    return int(n) if n.isdigit() else float("inf")
 
 
 def line(plane: Dict, name: str) -> List[List]:
