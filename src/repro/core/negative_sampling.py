@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import hsp as H
 from repro.embedding import tables as ET
 from repro.kernels.neg_logits import fused_recall_lse
 from repro.kernels.neg_logits.fused import NEG_POOL
@@ -234,7 +235,8 @@ def fused_recall_lse_xla(out_emb: jax.Array, pos_logit: jax.Array,
                          key: Optional[jax.Array] = None,
                          valid: Optional[jax.Array] = None,
                          fetch_dtype=None,
-                         gather_table: Optional[jax.Array] = None
+                         gather_table: Optional[jax.Array] = None,
+                         owned: Optional[jax.Array] = None
                          ) -> jax.Array:
     """XLA twin of the fused megakernel (identical numerics, same
     per-segment shuffle): a remat'd segmented scan, so neither the forward
@@ -242,13 +244,17 @@ def fused_recall_lse_xla(out_emb: jax.Array, pos_logit: jax.Array,
     expanded logits — the backward re-gathers per segment exactly like the
     Pallas custom VJP. ``gather_table`` fetches rows from the persistent
     half-precision shadow (straight-through grad to ``table``), matching
-    the Pallas path's shadow gather."""
+    the Pallas path's shadow gather; ``owned`` masks the slots a chip
+    does not hold, as the kernel's does."""
     T, R = neg_ids.shape
     D = table.shape[1]
     inv_tau = 1.0 / tau
     o_p, pos_p, ids_p, valid_p, perms, n_seg = prepare_fused_inputs(
         out_emb, pos_logit, table, neg_ids, segment=segment,
         expansion=expansion, key=key, valid=valid)
+    own_p = (jnp.ones(ids_p.shape, bool) if owned is None else
+             jnp.concatenate([owned, jnp.zeros((ids_p.shape[0] - T, R),
+                                               bool)]))
 
     @partial(jax.checkpoint,
              policy=jax.checkpoint_policies.nothing_saveable)
@@ -257,6 +263,7 @@ def fused_recall_lse_xla(out_emb: jax.Array, pos_logit: jax.Array,
         idsb = jax.lax.dynamic_slice_in_dim(ids_p, si * segment, segment, 0)
         posb = jax.lax.dynamic_slice_in_dim(pos_p, si * segment, segment, 0)
         vb = jax.lax.dynamic_slice_in_dim(valid_p, si * segment, segment, 0)
+        ob = jax.lax.dynamic_slice_in_dim(own_p, si * segment, segment, 0)
         if gather_table is not None:
             rows = shadow_gather(table, gather_table, idsb.reshape(-1))
         else:
@@ -266,6 +273,8 @@ def fused_recall_lse_xla(out_emb: jax.Array, pos_logit: jax.Array,
         logits = jnp.einsum("td,trd->tr", o.astype(jnp.float32),
                             rows.reshape(segment, R, D).astype(jnp.float32)
                             ) * inv_tau
+        if owned is not None:
+            logits = jnp.where(ob, logits, NEG_POOL)
         cols = [posb[:, None], logits]
         if expansion > 1:
             masked = jnp.where(vb[:, None] > 0.0, logits, NEG_POOL)
@@ -292,8 +301,8 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
                                shadow: Optional[jax.Array] = None,
                                impl: Optional[str] = None,
                                scatter_impl: Optional[str] = None,
-                               interpret: Optional[bool] = None
-                               ) -> jax.Array:
+                               interpret: Optional[bool] = None,
+                               hsp=None) -> jax.Array:
     """Eq. 2 straight from ids: the production recall loss.
 
     ``impl``: "pallas" (fused megakernel; default on TPU), "xla" (remat'd
@@ -310,23 +319,73 @@ def fused_sampled_softmax_loss(out_emb: jax.Array, pos_emb: jax.Array,
     ``scatter_impl`` tunes the Pallas megakernel's backward-scatter
     schedule (kernels/autotune.py resolves the tuned.json default when
     None; ignored by the XLA impl).
+
+    ``hsp``: the loss runs on one chip of the mesh of this
+    ``repro.core.hsp.HSPLookup``, inside a ``shard_map``: ``table`` and
+    ``shadow`` are the chip's rows, the rest its shard of the batch (see
+    :func:`_owner_lse`). It returns the chip's part of the mean over every
+    chip's valid tokens.
     """
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     pos = jnp.sum(out_emb.astype(jnp.float32) * pos_emb.astype(jnp.float32),
                   axis=-1) / tau
     kw = dict(segment=segment, tau=tau, expansion=expansion, key=key,
-              valid=valid, fetch_dtype=fetch_dtype, gather_table=shadow)
-    if impl == "pallas":
-        lse = fused_recall_lse(out_emb, pos, table, neg_ids,
-                               scatter_impl=scatter_impl,
-                               interpret=interpret, **kw)
-    elif impl == "xla":
-        lse = fused_recall_lse_xla(out_emb, pos, table, neg_ids, **kw)
-    else:
+              fetch_dtype=fetch_dtype, gather_table=shadow)
+
+    def lse_of(o, pos, ids, valid, owned=None):
+        if impl == "pallas":
+            return fused_recall_lse(o, pos, table, ids, valid=valid,
+                                    scatter_impl=scatter_impl, owned=owned,
+                                    interpret=interpret, **kw)
+        if impl == "xla":
+            return fused_recall_lse_xla(o, pos, table, ids, valid=valid,
+                                        owned=owned, **kw)
         raise ValueError(f"unknown fused impl {impl!r}")
+
+    if hsp is not None:
+        lse = _owner_lse(hsp, lse_of, out_emb, pos, table.shape[0], neg_ids,
+                         valid)
+    else:
+        lse = lse_of(out_emb, pos, neg_ids, valid)
     nll = lse - pos
+    if hsp is not None:
+        # the mean over every chip's tokens
+        v = (jnp.ones(nll.shape, jnp.float32) if valid is None
+             else valid.astype(jnp.float32))
+        return jnp.sum(nll * v) / jnp.maximum(
+            H.psum(jnp.sum(v), hsp.batch_axes, "neg"), 1.0)
     if valid is not None:
         v = valid.astype(jnp.float32)
         return jnp.sum(nll * v) / jnp.maximum(jnp.sum(v), 1.0)
     return jnp.mean(nll)
+
+
+def _owner_lse(hsp, lse_of, out_emb, pos, rows, neg_ids, valid):
+    """Each token's Eq.-2 logsumexp on one chip of an HSP group, computed
+    by the owners of its negatives' rows.
+
+    The chip gathers its group's token vectors, ids and validity (the
+    vectors in fp32, so that the backward's reduce-scatter sums fp32
+    gradients), runs the fused kernel over every group token's slots with
+    the slots of rows it holds marked owned (ids made local to its rows),
+    and gathers the group's partial logsumexps, each an exact logsumexp
+    over the slots one chip holds. A token's logsumexp is that of its
+    positive logit and its group's partials. The backward returns each
+    token vector's gradient to its chip with a reduce-scatter (the
+    transpose of the gather), and the kernel's scatter leaves each row's
+    gradient on its owner."""
+    axes = hsp.group_axes
+    T = out_emb.shape[0]
+    o_g = H.all_gather(out_emb.astype(jnp.float32), axes, "neg",
+                       transposed=True)
+    ids_g = H.all_gather(neg_ids, axes, "neg")
+    valid_g = (None if valid is None else
+               H.all_gather(valid.astype(jnp.float32), axes, "neg"))
+    rel = ids_g - hsp.row_offset(rows)
+    owned = (rel >= 0) & (rel < rows)
+    none = jnp.full(o_g.shape[:1], NEG_POOL, jnp.float32)   # no positive
+    part = lse_of(o_g, none, jnp.where(owned, rel, 0), valid_g, owned)
+    parts = H.all_gather(part, axes, "neg", tiled=False, transposed=True)
+    mine = jax.lax.dynamic_slice_in_dim(parts, hsp.row_offset(T), T, axis=1)
+    return jax.nn.logsumexp(jnp.concatenate([pos[None], mine]), axis=0)

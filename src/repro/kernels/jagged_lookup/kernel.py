@@ -42,6 +42,7 @@ scatter walks its slots in chunks of :data:`SCATTER_SLOTS_PER_CALL`, one
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -213,13 +214,16 @@ def _wscatter_kernel(sids_ref, src_ref, w_ref, o_ref, prev_ref, out_ref,
 def weighted_runsum_scatter(o: jax.Array, weights: jax.Array,
                             sorted_ids: jax.Array, src: jax.Array,
                             vocab: int, *, scale: float = 1.0,
+                            live: Optional[jax.Array] = None,
                             interpret: bool = False) -> jax.Array:
     """Σ over sorted slots of ``weights[i] · o[src[i]] · scale`` per id.
 
     o (T, D) fp32; weights (n,) fp32 (zeroed for dropped slots);
     sorted_ids (n,) int32 ascending with dropped slots keyed ≥ vocab; src
     (n,) int32 source row per slot. Returns (vocab + 1, D) fp32 where row
-    ``vocab`` is the drop sink and rows never visited are zero.
+    ``vocab`` is the drop sink and rows never visited are zero. ``live``,
+    a traced count of the leading slots that land (the rest dropped),
+    stops the walk after the calls that hold them.
     """
     n = sorted_ids.shape[0]
     T, D = o.shape
@@ -265,5 +269,8 @@ def weighted_runsum_scatter(o: jax.Array, weights: jax.Array,
                     jax.lax.dynamic_slice_in_dim(srcs, at, chunk),
                     jax.lax.dynamic_slice_in_dim(ws, at, chunk), o, out)
 
-    return jax.lax.fori_loop(0, (n + pad) // chunk, body,
+    calls = (n + pad) // chunk
+    if live is not None:
+        calls = jnp.minimum((live + chunk - 1) // chunk, calls)
+    return jax.lax.fori_loop(0, calls, body,
                              jnp.zeros((vocab + 1, D), jnp.float32))

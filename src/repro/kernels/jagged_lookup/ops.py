@@ -103,7 +103,7 @@ def scatter_add_weighted_rows(weights: jax.Array, o: jax.Array,
                               ids: jax.Array, vocab: int, *,
                               scale: float = 1.0,
                               impl: Optional[str] = None,
-                              chunk: int = 128,
+                              chunk: int = 128, sparse: bool = False,
                               interpret: Optional[bool] = None) -> jax.Array:
     """Σ over (t, r) of ``weights[t, r] · o[t] · scale`` per id → (V, D).
 
@@ -116,6 +116,10 @@ def scatter_add_weighted_rows(weights: jax.Array, o: jax.Array,
     HBM (kernel on TPU; a token-chunked scan twin elsewhere whose live
     temporary is (chunk·R, D)). ``impl="two_pass"`` is the oracle: build
     all rows, then :func:`scatter_add_rows`.
+
+    ``sparse``: many slots are dropped (a chip's share of a group's
+    slots), so the kernel walks only the sorted slots that land, in as
+    many of its calls as they fill.
     """
     interpret_ = default_interpret() if interpret is None else interpret
     T, R = weights.shape
@@ -162,8 +166,10 @@ def scatter_add_weighted_rows(weights: jax.Array, o: jax.Array,
     src = (order // R).astype(jnp.int32)
     ws = (weights.reshape(-1)[order].astype(jnp.float32)
           * valid[order].astype(jnp.float32))
-    out = K.weighted_runsum_scatter(o.astype(jnp.float32), ws, sids, src,
-                                    vocab, scale=scale, interpret=False)
+    out = K.weighted_runsum_scatter(
+        o.astype(jnp.float32), ws, sids, src, vocab, scale=scale,
+        live=jnp.sum(valid, dtype=jnp.int32) if sparse else None,
+        interpret=False)
     return out[:vocab]
 
 

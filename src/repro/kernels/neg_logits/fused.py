@@ -257,9 +257,20 @@ def _cost(segment, R, D, Tp, expansion, tb, words):
 # forward: gather + dequant + share + logsumexp
 # --------------------------------------------------------------------------
 
+def _owned_logits(lt_ref, owned_refs):
+    """The segment's (seg, R) logits, the slots a chip does not hold (an
+    owned-slot mask given) set to the masking sentinel: their rows were
+    copied from a stand-in row, and they weigh nothing."""
+    logits = lt_ref[...].T
+    if owned_refs:
+        logits = jnp.where(owned_refs[0][...] > 0.0, logits, NEG_POOL)
+    return logits
+
+
 def _fwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, perm_ref,
-                lse_ref, lt_ref, slab, sem, *, segment, R, tb, expansion,
-                inv_tau, fetch_dtype):
+                *refs, segment, R, tb, expansion, inv_tau, fetch_dtype,
+                masked):
+    owned_refs, (lse_ref, lt_ref, slab, sem) = refs[:masked], refs[masked:]
     si, b = pl.program_id(0), pl.program_id(1)
     nb = segment // tb
     step = si * nb + b
@@ -270,7 +281,7 @@ def _fwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, perm_ref,
 
     @pl.when(b == nb - 1)
     def _finalize():
-        logits = lt_ref[...].T                              # (seg, R)
+        logits = _owned_logits(lt_ref, owned_refs)          # (seg, R)
         cols = [pos_ref[0], logits]                         # (seg, 1) pos
         cols += [aux for _, aux in _share_terms(logits, valid_ref[0],
                                                 perm_ref, expansion,
@@ -282,21 +293,26 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                ids_flat: jax.Array, valid2d: jax.Array, perms: jax.Array, *,
                segment: int, R: int, expansion: int, tau: float,
                fetch_dtype=None, tokens_per_step: Optional[int] = None,
+               owned: Optional[jax.Array] = None,
                interpret: bool = False) -> jax.Array:
     """out_emb (Tp, D) · ids_flat (Tp·R,) → per-token lse (n_seg, segment).
-    ``words`` and ``fetch_dtype`` are as :func:`gather_source` gives them."""
+    ``words`` and ``fetch_dtype`` are as :func:`gather_source` gives them.
+    ``owned`` (Tp, R), 1 or 0: the slots whose rows this chip holds; the
+    others (their ids any row of ``words``) count as absent."""
     Tp, D = out_emb.shape
     n_seg = Tp // segment
     tb = check_tokens_per_step(tokens_per_step, segment, R, D, words)
     col = pl.BlockSpec((1, segment, 1), lambda si, b, ids: (si, 0, 0))
     cost = _cost(segment, R, D, Tp, expansion, tb, words)
+    extra = () if owned is None else (owned.astype(jnp.float32),)
+    owned_spec = [pl.BlockSpec((segment, R), lambda si, b, ids: (si, 0))]
 
-    def call(o, pos3, ids, valid3, pm):
+    def call(o, pos3, ids, valid3, pm, *own):
         ns = o.shape[0] // segment
         return pl.pallas_call(
             functools.partial(_fwd_kernel, segment=segment, R=R, tb=tb,
                               expansion=expansion, inv_tau=1.0 / tau,
-                              fetch_dtype=fetch_dtype),
+                              fetch_dtype=fetch_dtype, masked=len(own)),
             name="neg_fused_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
@@ -307,7 +323,7 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                     col, col,
                     pl.BlockSpec((1, pm.shape[1], segment),
                                  lambda si, b, ids: (si, 0, 0)),
-                ],
+                ] + owned_spec[:len(own)],
                 out_specs=col,
                 scratch_shapes=[pltpu.VMEM((R, segment), jnp.float32),
                                 *_slab_scratch(words, tb, R)],
@@ -317,12 +333,12 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
             **autotune.pallas_cost(**{k: cost[k] for k in
                                       ("flops", "bytes_accessed",
                                        "transcendentals")}),
-        )(ids, o, words, pos3, valid3, pm)
+        )(ids, o, words, pos3, valid3, pm, *own)
 
     lse = _map_groups(call, n_seg, segment * R,
                       (out_emb.astype(jnp.float32),
                        pos_logit2d.reshape(n_seg, segment, 1), ids_flat,
-                       valid2d.reshape(n_seg, segment, 1), perms))
+                       valid2d.reshape(n_seg, segment, 1), perms) + extra)
     return lse.reshape(n_seg, segment)
 
 
@@ -335,9 +351,10 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
 # --------------------------------------------------------------------------
 
 def _bwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, lse_ref,
-                g_ref, perm_ref, w_ref, dout_ref, dpos_ref, lt_ref, wt_ref,
-                slab, sem, *, segment, R, tb, expansion, inv_tau,
-                fetch_dtype):
+                g_ref, perm_ref, *refs, segment, R, tb, expansion, inv_tau,
+                fetch_dtype, masked):
+    owned_refs = refs[:masked]
+    w_ref, dout_ref, dpos_ref, lt_ref, wt_ref, slab, sem = refs[masked:]
     si, j = pl.program_id(0), pl.program_id(1)
     nb = segment // tb
     step = si * 2 * nb + j
@@ -353,7 +370,7 @@ def _bwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, lse_ref,
 
     @pl.when(j == nb)
     def _weights():
-        logits = lt_ref[...].T                              # (seg, R)
+        logits = _owned_logits(lt_ref, owned_refs)          # (seg, R)
         pos, lse, g = pos_ref[0], lse_ref[0], g_ref[0]      # (seg, 1) each
         # d lse / d logit = softmax prob; scale by upstream g per consumer.
         w = g * jnp.exp(logits - lse)
@@ -391,23 +408,27 @@ def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                lse2d: jax.Array, g2d: jax.Array, *, segment: int, R: int,
                expansion: int, tau: float, fetch_dtype=None,
                tokens_per_step: Optional[int] = None,
+               owned: Optional[jax.Array] = None,
                interpret: bool = False):
     """→ (w (n_seg, seg, R) softmax weights·g, d_out (Tp, D) fp32,
          d_pos (n_seg, seg) fp32). Table grads are finished by the caller
-    via the fused weighted scatter (sparse (id, w·o) pairs)."""
+    via the fused weighted scatter (sparse (id, w·o) pairs). ``owned`` as
+    for :func:`fwd_pallas`: an absent slot's weight is zero."""
     Tp, D = out_emb.shape
     n_seg = Tp // segment
     tb = check_tokens_per_step(tokens_per_step, segment, R, D, words)
     col = pl.BlockSpec((1, segment, 1), lambda si, j, ids: (si, 0, 0))
     rowblk = pl.BlockSpec((segment, D), lambda si, j, ids: (si, 0))
     cost = _cost(segment, R, D, Tp, expansion, tb, words)
+    extra = () if owned is None else (owned.astype(jnp.float32),)
+    owned_spec = [pl.BlockSpec((segment, R), lambda si, j, ids: (si, 0))]
 
-    def call(o, pos3, ids, valid3, lse3, g3, pm):
+    def call(o, pos3, ids, valid3, lse3, g3, pm, *own):
         ns = o.shape[0] // segment
         return pl.pallas_call(
             functools.partial(_bwd_kernel, segment=segment, R=R, tb=tb,
                               expansion=expansion, inv_tau=1.0 / tau,
-                              fetch_dtype=fetch_dtype),
+                              fetch_dtype=fetch_dtype, masked=len(own)),
             name="neg_fused_bwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
@@ -418,7 +439,7 @@ def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                     col, col, col, col,
                     pl.BlockSpec((1, pm.shape[1], segment),
                                  lambda si, j, ids: (si, 0, 0)),
-                ],
+                ] + owned_spec[:len(own)],
                 out_specs=[
                     pl.BlockSpec((1, segment, R),
                                  lambda si, j, ids: (si, 0, 0)),
@@ -438,11 +459,11 @@ def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                 flops=2 * cost["flops"],
                 bytes_accessed=2 * cost["bytes_accessed"],
                 transcendentals=2 * cost["transcendentals"]),
-        )(ids, o, words, pos3, valid3, lse3, g3, pm)
+        )(ids, o, words, pos3, valid3, lse3, g3, pm, *own)
 
     col3 = lambda x: x.reshape(n_seg, segment, 1)
     w, dout, dpos = _map_groups(
         call, n_seg, segment * R,
         (out_emb.astype(jnp.float32), col3(pos_logit2d), ids_flat,
-         col3(valid2d), col3(lse2d), col3(g2d), perms))
+         col3(valid2d), col3(lse2d), col3(g2d), perms) + extra)
     return w, dout, dpos.reshape(n_seg, segment)

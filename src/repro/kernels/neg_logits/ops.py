@@ -126,6 +126,7 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
                      valid: Optional[jax.Array] = None, fetch_dtype=None,
                      gather_table: Optional[jax.Array] = None,
                      scatter_impl: Optional[str] = None,
+                     owned: Optional[jax.Array] = None,
                      interpret: Optional[bool] = None) -> jax.Array:
     """Per-token logsumexp over [pos | R negatives | (k−1)·R shared] (Eq. 2).
 
@@ -157,6 +158,12 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
     ``scatter_impl`` (``"fused"`` in-kernel grad-row generation vs the
     ``"two_pass"`` materialized oracle) defaults to the tuned.json entry,
     else ``"fused"``.
+
+    ``owned`` (T, R) bool, for one chip of an HSP group (``table`` its
+    rows, ``neg_ids`` local to them): the slots whose rows the chip holds.
+    The others count as absent — a logit of −∞, no gradient — so the lse
+    is this chip's part of each token's sum over its negatives, and the
+    scatter walks only the owned slots.
     """
     interpret_ = default_interpret() if interpret is None else interpret
     T, R = neg_ids.shape
@@ -188,6 +195,13 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
     valid2 = valid_p.reshape(n_seg, segment)
     pos2 = pos_p.reshape(n_seg, segment)
     ids_flat = ids_p.reshape(-1)
+    mask = None
+    if owned is not None:
+        mask = _pad_rows(owned, Tp - T)
+        # the scatter drops what the chip does not hold
+        scatter_ids = jnp.where(mask.reshape(-1), ids_flat, -1)
+    else:
+        scatter_ids = ids_flat
 
     @jax.custom_vjp
     def _lse(o, pos2d, tbl):
@@ -195,7 +209,7 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
         return F.fwd_pallas(o, pos2d, words, ids_flat, valid2,
                             perms, segment=segment, R=R,
                             expansion=expansion, tau=tau, fetch_dtype=fdt,
-                            interpret=interpret_)
+                            owned=mask, interpret=interpret_)
 
     def fwd(o, pos2d, tbl):
         lse = _lse(o, pos2d, tbl)
@@ -208,13 +222,13 @@ def fused_recall_lse(out_emb: jax.Array, pos_logit: jax.Array,
             o, pos2d, words, ids_flat, valid2, perms, lse,
             g.astype(jnp.float32), segment=segment, R=R,
             expansion=expansion, tau=tau, fetch_dtype=fdt,
-            interpret=interpret_)
+            owned=mask, interpret=interpret_)
         # sparse per-(token, slot) weights → weighted runsum-scatter; the
         # "fused" impl generates each w·o·τ⁻¹ grad row in sorted-run order
         # inside the kernel, so the (T·R, D) row buffer never exists.
         dtbl = scatter_add_weighted_rows(
-            w.reshape(Tp, R), o.astype(jnp.float32), ids_flat, V,
-            scale=inv_tau, impl=scatter_impl,
+            w.reshape(Tp, R), o.astype(jnp.float32), scatter_ids, V,
+            scale=inv_tau, impl=scatter_impl, sparse=owned is not None,
             interpret=interpret_).astype(tbl.dtype)
         return dout.astype(o.dtype), dpos, dtbl
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.configs.shapes import ShapeConfig
 from repro.core import negative_sampling as NS
+from repro.core.hsp import HSPShard
 from repro.embedding import tables as ET
 from repro.models import gr as GR
 from repro.models import transformer as TF
@@ -179,6 +180,13 @@ class GRBundle:
         cfg = self.cfg
         lookup = lookup_fn or (lambda t, i: jnp.take(t, i, axis=0)
                                .astype(jnp.dtype(cfg.dtype)))
+        # one chip of an HSP mesh (inside the per-chip stages' shard_map):
+        # ``table`` and ``shadow`` hold the chip's rows
+        hsp = lookup_fn.hsp if isinstance(lookup_fn, HSPShard) else None
+        if hsp is not None and neg_mode != "fused":
+            raise ValueError(f"neg_mode {neg_mode!r} reads every negative "
+                             "row on the chip; one chip of an HSP mesh "
+                             "holds its share of the rows (use 'fused')")
         if x_emb is not None:
             assert input_table is None, "x_emb replaces the input lookup"
             x = x_emb                                        # (G, cap, d)
@@ -210,7 +218,7 @@ class GRBundle:
                     valid=valid.reshape(-1), segment=neg_segment,
                     expansion=expansion, fetch_dtype=fetch_dtype,
                     shadow=shadow, impl=neg_impl,
-                    scatter_impl=neg_scatter_impl)
+                    scatter_impl=neg_scatter_impl, hsp=hsp)
             if neg_mode == "baseline":
                 # (G, cap, R, d)
                 neg_emb = jnp.take(table, batch["neg_ids"], axis=0)

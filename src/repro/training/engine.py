@@ -48,6 +48,7 @@ import numpy as np
 
 from jax.profiler import TraceAnnotation
 
+from repro.core.hsp import HSPLookup
 from repro.core.pipeline import (PipelineHooks, STAGES, SixStagePipeline,
                                  StageEvent,
                                  timeline_report as _timeline_report)
@@ -67,7 +68,22 @@ SCHEDULES = ("algorithm1", "flat")
 
 def _bundle_loss_fn(bundle, loss_kwargs: Optional[Dict[str, Any]]):
     lk = dict(loss_kwargs or {})
-    return lambda d, t, b, **kw: bundle.loss(d, t, b, **lk, **kw)
+    return lambda d, t, b, **kw: bundle.loss(d, t, b, **{**lk, **kw})
+
+
+def _hsp_for(loss_kwargs: Optional[Dict[str, Any]]) -> Optional[HSPLookup]:
+    """The HSP lookup whose mesh the stages run on chip by chip: an HSP
+    ``lookup_fn`` over several chips with the fused negatives, which the
+    owners of the rows compute. Any other loss keeps the whole-mesh
+    program (the HSP lookup's own shard_map, the rest partitioned by the
+    compiler), as one chip does. One rule for the flat oracle and the
+    pipelined engine."""
+    lk = dict(loss_kwargs or {})
+    lookup = lk.get("lookup_fn")
+    if (isinstance(lookup, HSPLookup) and lookup.spans_chips
+            and lk.get("neg_mode", "fused") == "fused"):
+        return lookup
+    return None
 
 
 def _input_gather_for(bundle, loss_kwargs: Optional[Dict[str, Any]]):
@@ -99,7 +115,7 @@ def make_gr_step_fn(bundle, *, loss_kwargs: Optional[Dict[str, Any]] = None,
     step = make_gr_train_step(_bundle_loss_fn(bundle, lk),
                               lr_dense=lr_dense, lr_sparse=lr_sparse,
                               semi_async=semi_async,
-                              input_gather=input_gather)
+                              input_gather=input_gather, hsp=_hsp_for(lk))
     return jax.jit(step) if jit else step
 
 
@@ -200,10 +216,13 @@ class GREngine:
         lk = dict(loss_kwargs or {})
         input_gather = _input_gather_for(bundle, lk)
         self._x_mode = semi_async and input_gather is not None
+        # on a multi-chip HSP mesh each chip finds the rows it lands from
+        # its own gradient: the host sorts no candidates
+        self._hsp = _hsp_for(lk)
         stages = make_gr_stages(_bundle_loss_fn(bundle, lk),
                                 lr_dense=lr_dense, lr_sparse=lr_sparse,
                                 semi_async=semi_async,
-                                input_gather=input_gather)
+                                input_gather=input_gather, hsp=self._hsp)
         self.stages = stages
         self._j_emb_fwd = jax.jit(stages.emb_fwd)
         self._j_dense = jax.jit(stages.dense_fwd_bwd)
@@ -310,6 +329,8 @@ class GREngine:
         if self.cache is not None:
             with TraceAnnotation("cache_prefetch", step=i):
                 return self._cache_prefetch(i, art)
+        if self._hsp is not None:
+            return {**art, "cand": (None, None)}
         vocab = self.bundle.cfg.vocab_size
         if self.state is not None:
             vocab = self.state.table.master.shape[0]
@@ -378,9 +399,15 @@ class GREngine:
 
     def _hk_dense_bwd(self, i: int, art):
         full = self._arts[i]
+        dout = full["dout"]
         with TraceAnnotation("loss_sync", step=i):
-            loss = float(full["dout"].loss)   # realize the dispatched fwd+bwd
-        tokens = int(np.asarray(full["np"]["offsets"])[:, -1].sum())
+            if dout.tokens is None:
+                loss = float(dout.loss)   # realize the dispatched fwd+bwd
+            else:
+                loss, tokens = jax.device_get((dout.loss, dout.tokens))
+                loss, tokens = float(loss), int(tokens)
+        if dout.tokens is None:
+            tokens = int(np.asarray(full["np"]["offsets"])[:, -1].sum())
         rec = {"step": i, "loss": loss, "tokens": tokens}
         if self.cache is not None:
             # per-step cache counters ride the record into the timeline

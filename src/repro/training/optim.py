@@ -97,7 +97,8 @@ SHADOW_SCATTER_ROW_ELEMS = 6144
 def adagrad_sparse_update(table: ShadowedTable, ids: jax.Array,
                           grad_rows: jax.Array, *, lr: float = 4e-3,
                           eps: float = 1e-10,
-                          interpret: Optional[bool] = None) -> ShadowedTable:
+                          interpret: Optional[bool] = None,
+                          aligned: bool = False) -> ShadowedTable:
     """Row-sparse Eq.-1 AdaGrad over (id, grad-row) pairs.
 
     ``ids`` (n,) int32 (< 0 = empty slot, duplicates allowed) and
@@ -115,7 +116,21 @@ def adagrad_sparse_update(table: ShadowedTable, ids: jax.Array,
 
     Numerics are identical to :func:`adagrad_update` on the touched rows
     (same fp32 ops in the same order); untouched rows are bit-unchanged.
+
+    ``aligned``: the pairs are the table's rows in order (``ids[i]`` is
+    ``i``, or < 0 for an empty slot; ``grad_rows`` has the table's shape),
+    as a chip's aggregated gradient of its HSP shard is. They land in one
+    elementwise pass, with no sort, run sum or scatter: a zero row adds 0
+    to its accumulator and moves by −0, so it stays bit-unchanged.
     """
+    if aligned:
+        g = jnp.where((ids >= 0)[:, None], grad_rows.astype(jnp.float32), 0.0)
+        accum = table.accum + g * g
+        master = table.master + (-lr * g * jax.lax.rsqrt(accum + eps))
+        shadow = table.shadow
+        if ET.live_shadow(table) is not None:
+            shadow = ET.shadow_of(master, ET.shadow_dtype(shadow))
+        return ShadowedTable(master=master, shadow=shadow, accum=accum)
     if ids.shape[0] == 0:
         return table
     from repro.kernels.jagged_lookup.ops import dedup_rows

@@ -41,8 +41,10 @@ from typing import Any, Callable, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.core import hsp as H
 from repro.core import semi_async as SA
 from repro.embedding import tables as ET
 from repro.training import optim as O
@@ -246,6 +248,9 @@ class GRDenseOut(NamedTuple):
     grad_table: jax.Array                # (V, D) grad w.r.t. the fresh master
     grad_x: Optional[jax.Array]          # cotangent w.r.t. prefetched rows
     grad_stale: Optional[jax.Array]      # (V, D) stale-master grad (inline)
+    # the step's valid tokens, counted on the chips (multi-chip HSP stages,
+    # whose batch is not read back to the host)
+    tokens: Optional[jax.Array] = None
 
 
 class GRStages(NamedTuple):
@@ -278,7 +283,8 @@ class GRStages(NamedTuple):
 def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
                    lr_dense: float = 4e-3, lr_sparse: float = 4e-3,
                    semi_async: bool = True,
-                   input_gather: Optional[Callable] = None) -> GRStages:
+                   input_gather: Optional[Callable] = None,
+                   hsp: Optional[H.HSPLookup] = None) -> GRStages:
     """Decompose the GR train step into Algorithm-1 stage functions.
 
     ``input_gather(master, batch) -> x`` is the standalone input-side
@@ -300,7 +306,14 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
     emb_fwd / the fused neg-kernel gather / the row-sparse AdaGrad in
     emb_bwd all operate on cache slots; writeback to the host-resident
     full table is chunk-sparse and deferred to eviction.
+
+    ``hsp``, an HSP lookup over several chips: the stages run each chip's
+    part of the step inside a ``shard_map`` over its mesh
+    (:func:`_hsp_stages`).
     """
+    if hsp is not None:
+        return _hsp_stages(loss_fn, hsp, lr_dense=lr_dense,
+                           lr_sparse=lr_sparse, semi_async=semi_async)
     x_mode = semi_async and input_gather is not None
 
     # Named scopes mark each layer's ops in the HLO metadata (the op_name
@@ -379,10 +392,136 @@ def make_gr_stages(loss_fn: Callable[..., jax.Array], *,
     return GRStages(emb_fwd, dense_fwd_bwd, emb_bwd, sparse_apply)
 
 
+def _replicated(x) -> P:
+    """The spec of an array every chip holds whole, as the partition plan
+    writes it (``repro.launch.partition.gr_param_specs``)."""
+    return P(*([None] * jnp.ndim(x)))
+
+
+def _hsp_stages(loss_fn: Callable[..., jax.Array], hsp: H.HSPLookup, *,
+                lr_dense: float, lr_sparse: float,
+                semi_async: bool) -> GRStages:
+    """The Algorithm-1 stages of a step on an HSP mesh of several chips.
+
+    Each stage is a ``shard_map`` whose body is one chip's work: every
+    kernel runs on the chip's shard of the batch and its rows of the
+    table, and every exchange is an explicit collective (``core.hsp``):
+
+    dense_fwd_bwd  the loss over the chip's shard (the HSP lookups and
+                   the owner-computes negatives exchange within its group)
+                   and its gradients; then the step's gradient exchange:
+                   the dense gradients and the loss summed over every
+                   chip, the table gradient of the chip's rows over the
+                   groups, so that every group holds the same aggregate
+                   (paper Eq. 1). The step's valid tokens ride out with
+                   the loss.
+    emb_bwd        AdamW, the τ=1 pending pairs and optionally the
+                   landing. A chip's pairs are its rows in order with its
+                   aggregated gradient (``pending_rows`` laid out like the
+                   rows, ``pending_ids`` their global ids), whatever the
+                   pending buffers' ``slots``.
+    sparse_apply   AdaGrad over the chip's rows, dense: a row the step did
+                   not touch has a zero gradient and stays bit-unchanged.
+
+    The input lookup stays in the dense stage (the HSP lookup is a
+    custom-VJP exchange); ``x`` is never given. The candidate sort of the
+    host ``unique`` stage is not used, so the host reads nothing of a
+    placed batch.
+    """
+    mesh, chip_lookup = hsp.mesh, hsp.on_chip()
+    rows_spec = hsp.table_spec
+    ids_spec = P(*hsp.table_spec[:1])
+    on_chips = partial(jax.shard_map, mesh=mesh, check_vma=False)
+
+    def batch_specs(batch):
+        return {k: P() if k == "rng" else hsp.ids_spec for k in batch}
+
+    def table_specs(table):
+        return jax.tree.map(lambda _: rows_spec, table)
+
+    def dense_fwd_bwd(dense, table: ET.ShadowedTable, batch,
+                      x=None, stale_master=None) -> GRDenseOut:
+        if x is not None:
+            raise ValueError("the HSP lookup keeps the input gather in the "
+                             "dense stage")
+
+        def chip(dense, master, shadow, stale, b):
+            with H.exchange_meter():
+                if semi_async:
+                    loss, (gd, gs, gf) = jax.value_and_grad(
+                        lambda d, ts, tf: loss_fn(
+                            d, tf, b, input_table=ts, shadow=shadow,
+                            lookup_fn=chip_lookup),
+                        argnums=(0, 1, 2))(dense, stale, master)
+                    gt = gs + gf
+                else:
+                    loss, (gd, gt) = jax.value_and_grad(
+                        lambda d, t: loss_fn(d, t, b, input_table=None,
+                                             shadow=shadow,
+                                             lookup_fn=chip_lookup),
+                        argnums=(0, 1))(dense, master)
+                gd = jax.tree.map(lambda g: g.astype(jnp.float32), gd)
+                loss, gd, tokens = H.psum(
+                    (loss, gd, jnp.sum(b["offsets"][:, -1])),
+                    hsp.batch_axes, "grad_exchange")
+                if hsp.dp_axes:
+                    gt = H.psum(gt.astype(jnp.float32), hsp.dp_axes,
+                                "grad_exchange")
+            return loss, gd, gt, tokens
+
+        stale = table.master if stale_master is None else stale_master
+        gd_specs = jax.tree.map(_replicated, dense)
+        loss, gd, gt, tokens = on_chips(
+            chip, in_specs=(gd_specs, rows_spec, rows_spec, rows_spec,
+                            batch_specs(batch)),
+            out_specs=(P(), gd_specs, rows_spec, P()))(
+                dense, table.master, table.shadow, stale, batch)
+        return GRDenseOut(loss, gd, gt, None, None, tokens)
+
+    def land(tbl: ET.ShadowedTable, ids, rows):
+        with jax.named_scope("adagrad"):
+            return O.adagrad_sparse_update(tbl, ids, rows, lr=lr_sparse,
+                                           aligned=True)
+
+    def emb_bwd(dense, dense_opt, table: ET.ShadowedTable,
+                dout: GRDenseOut, batch,
+                cand_sorted=None, cand_first=None, *,
+                apply_sparse: bool = True, slots: Optional[int] = None):
+
+        def chip(dense, dense_opt, tbl, gt, gd):
+            rows = gt.shape[0]
+            p_ids = hsp.row_offset(rows) + jnp.arange(rows, dtype=jnp.int32)
+            with jax.named_scope("adamw"):
+                new_dense, new_opt = O.adamw_update(
+                    gd, dense_opt, dense, lr=lr_dense, weight_decay=0.0)
+            new_table = land(tbl, p_ids, gt) if apply_sparse else tbl
+            return new_dense, new_opt, new_table, p_ids, gt
+
+        d_specs = jax.tree.map(_replicated, dense)
+        o_specs = jax.tree.map(_replicated, dense_opt)
+        t_specs = table_specs(table)
+        return on_chips(
+            chip, in_specs=(d_specs, o_specs, t_specs, rows_spec,
+                            jax.tree.map(_replicated, dout.grads_dense)),
+            out_specs=(d_specs, o_specs, t_specs, ids_spec, rows_spec))(
+                dense, dense_opt, table, dout.grad_table, dout.grads_dense)
+
+    def sparse_apply(table: ET.ShadowedTable, p_ids, p_rows):
+        t_specs = table_specs(table)
+        return on_chips(land, in_specs=(t_specs, ids_spec, rows_spec),
+                        out_specs=t_specs)(table, p_ids, p_rows)
+
+    def emb_fwd(stale_master, batch):
+        return None
+
+    return GRStages(emb_fwd, dense_fwd_bwd, emb_bwd, sparse_apply)
+
+
 def make_gr_train_step(loss_fn: Callable[..., jax.Array], *,
                        lr_dense: float = 4e-3, lr_sparse: float = 4e-3,
                        semi_async: bool = True,
-                       input_gather: Optional[Callable] = None):
+                       input_gather: Optional[Callable] = None,
+                       hsp: Optional[H.HSPLookup] = None):
     """loss_fn(dense_params, table, batch, *, input_table=None,
     shadow=None) → scalar (built from GRBundle.loss with the
     lookup/neg-sampling modes already bound; the default "fused" mode
@@ -406,7 +545,8 @@ def make_gr_train_step(loss_fn: Callable[..., jax.Array], *,
     and accumulator are rewritten at touched rows only.
     """
     st = make_gr_stages(loss_fn, lr_dense=lr_dense, lr_sparse=lr_sparse,
-                        semi_async=semi_async, input_gather=input_gather)
+                        semi_async=semi_async, input_gather=input_gather,
+                        hsp=hsp)
 
     def train_step(state: GRTrainState, batch: Batch):
         tbl = state.table

@@ -311,6 +311,28 @@ def test_shadow_landing_scatter_equals_rebuild(packed, monkeypatch):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("packed", [True, False])
+def test_aligned_landing_equals_the_sparse_pairs(packed):
+    """A gradient laid out like the rows (an HSP chip's aggregate: most
+    rows zero, empty slots < 0) lands in one pass with the bits that the
+    sorted run sum and scatter of its non-zero rows leave."""
+    tbl = (_packed_table(jax.random.PRNGKey(27)) if packed
+           else _table(jax.random.PRNGKey(27), jnp.bfloat16))
+    width = tbl.master.shape[1]
+    ki, kr = jax.random.split(jax.random.PRNGKey(28))
+    touched = jax.random.bernoulli(ki, 0.3, (V,))
+    grad = jax.random.normal(kr, (V, width), jnp.float32) * touched[:, None]
+    ids = jnp.where(jnp.arange(V) % 5 == 4, -1, jnp.arange(V, dtype=jnp.int32))
+    got = O.adagrad_sparse_update(tbl, ids, grad, lr=0.05, aligned=True)
+    keep = np.flatnonzero((np.asarray(ids) >= 0) & np.asarray(touched))
+    want = O.adagrad_sparse_update(tbl, jnp.asarray(keep, jnp.int32),
+                                   grad[keep], lr=0.05)
+    assert 0 < keep.size < V
+    assert bool(ET.shadow_consistent(got))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_packed_shadow_checkpoint_strip_rebuild():
     tbl = _packed_table(jax.random.PRNGKey(21))
     stripped = CKPT._strip_shadows({"table": tbl})
