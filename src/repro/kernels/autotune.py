@@ -5,8 +5,9 @@ sampling, sorted-runsum scatter) expose schedule knobs —
 ``rows_per_step`` for the lookup gather, ``pairs_per_step`` for the
 attention work-list, the backward-scatter ``scatter_impl`` — and this
 module owns everything around picking their values (the negative kernel's
-token block is no knob: :func:`neg_tokens_per_step` sizes it from the
-shapes):
+token block and the weighted scatter's slot block are no knobs:
+:func:`neg_tokens_per_step` and :func:`wscatter_slots_per_block` size them
+from the shapes):
 
 * **candidate enumeration** from divisibility/alignment constraints and a
   coarse VMEM budget (``enumerate_candidates``);
@@ -43,7 +44,8 @@ from jax.experimental import pallas as pl
 
 __all__ = [
     "DEFAULTS", "CANDIDATES", "shape_bucket", "knob_valid",
-    "enumerate_candidates", "neg_tokens_per_step", "estimate_cost",
+    "enumerate_candidates", "neg_tokens_per_step",
+    "wscatter_slots_per_block", "estimate_cost",
     "rank_candidates", "pallas_cost", "TunedStore", "default_path", "resolve",
     "measure", "sweep",
 ]
@@ -142,6 +144,12 @@ def _vmem_bytes(kernel: str, dims: Mapping[str, Any],
     if kernel == "lookup_gather":
         rps = int(config.get("rows_per_step", 1))
         return 4 * (4 * rps * D)
+    if kernel == "lookup_wscatter":
+        slots = int(config.get("slots_per_block", 8))
+        # the double-buffered source-row slab + the fp32 destination tiles
+        # whose write-backs are in flight
+        return (2 * slots * D * int(dims.get("itemsize", 4))
+                + WSCATTER_TILES_IN_FLIGHT * 8 * D * 4)
     return 0
 
 
@@ -179,6 +187,24 @@ def neg_tokens_per_step(dims: Mapping[str, Any]) -> int:
             if seg % tb == 0 and _vmem_bytes(
                 "neg_fused", dims, {"tokens_per_step": tb}) <= VMEM_BUDGET]
     return max(fits, default=1)
+
+
+# the weighted table-gradient scatter: at most this many sorted slots a grid
+# step, and this many 8-row destination tiles held in VMEM while their
+# write-backs are in flight
+WSCATTER_MAX_SLOTS = 512
+WSCATTER_TILES_IN_FLIGHT = 16
+
+
+def wscatter_slots_per_block(dims: Mapping[str, Any]) -> int:
+    """Sorted slots a grid step of the weighted scatter walks: the largest
+    power of two from 8 to :data:`WSCATTER_MAX_SLOTS` whose double-buffered
+    source-row slab, with the tiles in flight, fits :data:`VMEM_BUDGET`."""
+    slots = WSCATTER_MAX_SLOTS
+    while slots > 8 and _vmem_bytes(
+            "lookup_wscatter", dims, {"slots_per_block": slots}) > VMEM_BUDGET:
+        slots //= 2
+    return slots
 
 
 # ---------------------------------------------------------------------------

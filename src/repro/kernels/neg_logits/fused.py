@@ -32,7 +32,7 @@ blocks of ``tb`` tokens (``tokens_per_step``: by default the largest block
 whose slab fits the VMEM budget, ``autotune.neg_tokens_per_step``). Each
 grid step starts the ``tb·R`` row copies of the *next* block into the
 other half of a double-buffered ``(2, tb·R, 1, W)`` slab (one DMA
-semaphore per half), waits on the current half, then computes each
+semaphore per half, ``row_dma``), waits on the current half, computes each
 token's ``(R, D)`` rows against its broadcast ``(1, D)`` vector, reduced
 over lanes to an ``(R, 1)`` logit column. Columns land in a slot-major
 ``(R, segment)`` logit scratch that is transposed once per segment.
@@ -60,7 +60,6 @@ batch is processed in groups of whole segments of at most
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -70,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.embedding import tables as ET
 from repro.kernels import autotune
+from repro.kernels.row_dma import gather_block, slab_scratch
 
 # Sentinel for masked (invalid-token) pool logits: large-negative instead of
 # -inf so logsumexp arithmetic stays NaN-free even if a whole row masks out.
@@ -147,45 +147,6 @@ def check_tokens_per_step(tokens_per_step: Optional[int], segment: int,
     return tb
 
 
-def _start_rows(ids_ref, words_ref, slab, sem, block, slot, n):
-    """Start the ``n`` row copies of token block ``block`` into slab half
-    ``slot``, all signalling that half's semaphore."""
-    unroll = math.gcd(n, 8)
-
-    def body(i, carry):
-        for u in range(unroll):
-            k = i * unroll + u
-            pltpu.make_async_copy(words_ref.at[pl.ds(ids_ref[block * n + k],
-                                                     1)],
-                                  slab.at[slot, pl.ds(k, 1)],
-                                  sem.at[slot]).start()
-        return carry
-
-    jax.lax.fori_loop(0, n // unroll, body, 0)
-
-
-def _gather_block(ids_ref, words_ref, slab, sem, step, last, block_of):
-    """Double-buffered slab gather for grid step ``step`` (counted over the
-    whole call) of a sweep whose token block at step s is ``block_of(s)``:
-    prime the first block, start the next one into the other half, wait
-    for this one. Returns the slab half that holds this step's rows."""
-    n = slab.shape[1]
-    slot = step % 2
-
-    @pl.when(step == 0)
-    def _prime():
-        _start_rows(ids_ref, words_ref, slab, sem, block_of(step), slot, n)
-
-    @pl.when(step < last)
-    def _prefetch():
-        _start_rows(ids_ref, words_ref, slab, sem, block_of(step + 1),
-                    1 - slot, n)
-
-    # one wait for the whole half: its byte count is the sum of the rows'
-    pltpu.make_async_copy(slab.at[slot], slab.at[slot], sem.at[slot]).wait()
-    return slot
-
-
 def _token_parts(slab, slot, g, R, fetch_dtype):
     words = slab[slot, pl.ds(g * R, R)]                      # (R, 1, W)
     return _row_parts(words.reshape(R, words.shape[-1]), fetch_dtype)
@@ -240,11 +201,6 @@ def _map_groups(call, n_seg: int, seg_r: int, args):
     return jax.tree.map(lambda y: y.reshape(-1, *y.shape[2:]), outs)
 
 
-def _slab_scratch(words, tb, R):
-    return [pltpu.VMEM((2, tb * R, 1, words.shape[2]), words.dtype),
-            pltpu.SemaphoreType.DMA((2,))]
-
-
 def _cost(segment, R, D, Tp, expansion, tb, words):
     return autotune.estimate_cost(
         "neg_fused",
@@ -274,8 +230,8 @@ def _fwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, perm_ref,
     si, b = pl.program_id(0), pl.program_id(1)
     nb = segment // tb
     step = si * nb + b
-    slot = _gather_block(ids_ref, words_ref, slab, sem, step,
-                         pl.num_programs(0) * nb - 1, lambda s: s)
+    slot = gather_block(ids_ref, words_ref, slab, sem, step,
+                        pl.num_programs(0) * nb - 1, lambda s: s)
     _block_logits(o_ref, slab, slot, lt_ref, b, tb=tb, R=R, inv_tau=inv_tau,
                   fetch_dtype=fetch_dtype)
 
@@ -326,7 +282,8 @@ def fwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                 ] + owned_spec[:len(own)],
                 out_specs=col,
                 scratch_shapes=[pltpu.VMEM((R, segment), jnp.float32),
-                                *_slab_scratch(words, tb, R)],
+                                *slab_scratch(tb * R, words.shape[2],
+                                              words.dtype)],
             ),
             out_shape=jax.ShapeDtypeStruct((ns, segment, 1), jnp.float32),
             interpret=interpret,
@@ -358,7 +315,7 @@ def _bwd_kernel(ids_ref, o_ref, words_ref, pos_ref, valid_ref, lse_ref,
     si, j = pl.program_id(0), pl.program_id(1)
     nb = segment // tb
     step = si * 2 * nb + j
-    slot = _gather_block(
+    slot = gather_block(
         ids_ref, words_ref, slab, sem, step, pl.num_programs(0) * 2 * nb - 1,
         lambda s: (s // (2 * nb)) * nb + s % nb)
     b = j % nb
@@ -448,7 +405,8 @@ def bwd_pallas(out_emb: jax.Array, pos_logit2d: jax.Array, words: jax.Array,
                 ],
                 scratch_shapes=[pltpu.VMEM((R, segment), jnp.float32),
                                 pltpu.VMEM((R, segment), jnp.float32),
-                                *_slab_scratch(words, tb, R)],
+                                *slab_scratch(tb * R, words.shape[2],
+                                              words.dtype)],
             ),
             out_shape=[jax.ShapeDtypeStruct((ns, segment, R), jnp.float32),
                        jax.ShapeDtypeStruct((ns * segment, D), jnp.float32),

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import RABConfig
+from repro.kernels import autotune
 from repro.kernels.jagged_attention import (jagged_attention,
                                             jagged_attention_ref)
 from repro.kernels.jagged_lookup import (jagged_lookup, jagged_lookup_ref,
@@ -180,6 +181,61 @@ def test_weighted_scatter_kernel_matches_scatter_add(monkeypatch,
     np.testing.assert_allclose(np.asarray(got[:V]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     assert not np.asarray(got[V]).any()       # the drop sink stays zero
+
+
+def _sequential_scatter(o, w, sids, src, vocab, scale, walked):
+    """float32 loop: ``w · (o[src] · scale)`` added to each row (row
+    ``vocab`` the drop sink) in sorted-slot order, over ``walked`` slots."""
+    acc = np.zeros((vocab + 1, o.shape[1]), np.float32)
+    scale = np.float32(scale)
+    for i in range(min(walked, len(sids))):
+        d = min(int(sids[i]), vocab)
+        acc[d] = acc[d] + w[i] * (o[src[i]] * scale)
+    return acc
+
+
+@pytest.mark.parametrize("slots_per_call,max_slots,in_flight,case", [
+    (8, 8, 16, "random"),            # every block its own call
+    (16, 8, 16, "random"),           # runs and tiles straddle blocks, calls
+    (1 << 15, 8, 16, "random"),      # many blocks in one call
+    (1 << 15, 8, 2, "random"),       # tile buffers reused two tiles on
+    (1 << 15, 32, 16, "top_id"),     # rows V−1 and the sink share a tile
+    (1 << 15, 8, 16, "dropped_block"),   # blocks of dropped slots only
+    (1 << 15, 32, 16, "ragged"),     # 185 slots: not a multiple of S
+    (16, 8, 16, "live"),             # the walk stops after live's calls
+])
+def test_weighted_scatter_bitwise_matches_sequential_loop(
+        monkeypatch, slots_per_call, max_slots, in_flight, case):
+    monkeypatch.setattr(LK, "SCATTER_SLOTS_PER_CALL", slots_per_call)
+    monkeypatch.setattr(autotune, "WSCATTER_MAX_SLOTS", max_slots)
+    monkeypatch.setattr(autotune, "WSCATTER_TILES_IN_FLIGHT", in_flight)
+    T, R, D, V = 37, 5, 16, 203          # 26 destination tiles
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    w = np.asarray(jax.random.normal(ks[0], (T * R,)))
+    o = np.asarray(jax.random.normal(ks[1], (T, D)))
+    ids = np.array(jax.random.randint(ks[2], (T * R,), -2, V + 3))
+    if case == "top_id":
+        ids[::7] = V - 1
+    elif case in ("dropped_block", "live"):
+        ids[::5] = V
+        ids[1::5] = 2 ** 30
+        ids[2::5] = -1
+    # dropped slots keyed at or above V, and weighted, so that the sink
+    # shows which of them the walk visited
+    key = np.where(ids < 0, 2 ** 30, ids).astype(np.int32)
+    order = np.argsort(key, kind="stable")
+    sids, src, ws = key[order], (order // R).astype(np.int32), w[order]
+    live, walked = None, T * R
+    if case == "live":
+        n_live = int(np.sum(sids < V))
+        live = jnp.int32(n_live)
+        walked = -(-n_live // slots_per_call) * slots_per_call
+        assert walked < T * R              # the walk stops short
+    got = LK.weighted_runsum_scatter(jnp.asarray(o), jnp.asarray(ws),
+                                     jnp.asarray(sids), jnp.asarray(src), V,
+                                     scale=0.7, live=live, interpret=True)
+    want = _sequential_scatter(o, ws, sids, src, V, 0.7, walked)
+    assert np.array_equal(np.asarray(got), want)
 
 
 def test_fused_neg_segment_groups_bitwise(monkeypatch):

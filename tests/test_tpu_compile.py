@@ -102,13 +102,39 @@ def test_fused_negatives_fwd_bwd_on_fp32_master_compiles(one_chip):
     _compile_negatives(one_chip, D_MODEL, None)
 
 
-def test_weighted_scatter_compiles(one_chip):
-    T = 1024
+@pytest.mark.parametrize("T,sparse", [(1024, False),
+                                      (16_384, True)])  # one chip, the mesh
+def test_weighted_scatter_compiles(one_chip, T, sparse):
+    # the mesh's chip walks its group's 16,384 tokens' slots up to ``live``
     _compile(one_chip,
-             lambda o, w, s, src: LK.weighted_runsum_scatter(
-                 o, w, s, src, V, interpret=False),
+             lambda o, w, s, src, live: LK.weighted_runsum_scatter(
+                 o, w, s, src, V, scale=0.5,
+                 live=live if sparse else None, interpret=False),
              ((T, D_MODEL), jnp.float32), ((T * R,), jnp.float32),
-             ((T * R,), jnp.int32), ((T * R,), jnp.int32))
+             ((T * R,), jnp.int32), ((T * R,), jnp.int32), ((), jnp.int32))
+
+
+def test_weighted_scatter_counters_after_fused_negatives_backward(one_chip):
+    from repro.kernels import autotune
+    from repro.kernels.neg_logits import fused_recall_lse
+    from repro.obs import KERNEL_METRICS
+    src_bytes = KERNEL_METRICS.gauge("wscatter_src_bytes_per_slot")
+    block = KERNEL_METRICS.gauge("wscatter_slots_per_block")
+    src_bytes.set(0)
+    block.set(0)
+    T = 1024
+
+    def loss(o, pos, table, ids):
+        return jnp.sum(fused_recall_lse(o, pos, table, ids, segment=128,
+                                        interpret=False))
+
+    jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+          (((T, D_MODEL), jnp.float32), ((T,), jnp.float32),
+           ((V, D_MODEL), jnp.float32), ((T, R), jnp.int32))])
+    slots = autotune.wscatter_slots_per_block({"D": D_MODEL, "itemsize": 4})
+    assert src_bytes.value == 4 * D_MODEL          # a row, not its tile
+    assert block.value == slots and 128 <= slots <= 512
 
 
 def test_runsum_compiles(one_chip):
